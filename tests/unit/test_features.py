@@ -201,6 +201,31 @@ def test_genome_cache_key_shared_across_consumers(tmp_path):
     assert _genome_resident_worthwhile(tiny, fasta, sharding=standard_genome_sharding())
 
 
+def test_single_device_plan_keeps_the_genome_on_one_device(tmp_path):
+    """A dp=1 plan on a multi-device host (this harness forces 8) must not
+    replicate the genome over the other devices: a program fed an argument
+    committed to N devices runs on all N, and XLA cannot auto-partition
+    the Mosaic forest kernel — the first four-chip run died there."""
+    import jax
+
+    from variantcalling_tpu.featurize import (device_genome,
+                                              standard_genome_sharding)
+    from variantcalling_tpu.io.fasta import FastaReader
+    from variantcalling_tpu.parallel import shard_score
+
+    assert len(jax.local_devices()) > 1
+    fa = tmp_path / "tiny.fa"
+    fa.write_text(">chr1\n" + "ACGT" * 500 + "\n")
+    plan1 = shard_score.MeshPlan(1, "1", "test")
+    sh = standard_genome_sharding(shard_score.mesh_for(plan1))
+    assert sh is None and standard_genome_sharding() is None
+    genome = device_genome(FastaReader(str(fa)), sharding=sh)
+    assert len(genome.blocks.sharding.device_set) == 1
+    plan4 = shard_score.MeshPlan(4, "4", "test")
+    sh4 = standard_genome_sharding(shard_score.mesh_for(plan4))
+    assert sh4.is_fully_replicated and len(sh4.device_set) == 4
+
+
 def test_flow_signature_matches_scan_reference(rng):
     """The closed-form flow signature must agree with the sequential flow
     scan on flow count AND zero-pattern comparison for random haplotype

@@ -21,12 +21,18 @@ from variantcalling_tpu.engine import EngineError
 from variantcalling_tpu.models import forest as fmod
 
 STRATEGIES = ("gather", "gemm", "wide", "pallas")
+#: the strategies the jit engine can run on this (CPU) backend through the
+#: pipeline — the pallas kernel compiles for TPUs only; here it runs through
+#: the Pallas interpreter, which a test asks for by argument
+#: (``interpret=True``), never the product path
+CPU_PIPELINE_STRATEGIES = ("gather", "gemm", "wide")
 
 
 def _margins(forest, x, n_features, strategies=STRATEGIES):
     xj = jnp.asarray(x)
     return {s: np.asarray(jax.jit(
-        fmod.make_margin_predictor(forest, n_features, strategy=s))(xj))
+        fmod.make_margin_predictor(forest, n_features, strategy=s,
+                                   interpret=True))(xj))
         for s in strategies}
 
 
@@ -86,7 +92,8 @@ def test_wide_matches_native_engine_bits(rng):
     x = rng.uniform(0, 50, (2048, 12)).astype(np.float32)
     native_scores = nf(x)
     for strat in ("wide", "pallas"):
-        m = np.asarray(fmod.make_margin_predictor(f, 12, strategy=strat)(jnp.asarray(x)))
+        m = np.asarray(fmod.make_margin_predictor(
+            f, 12, strategy=strat, interpret=True)(jnp.asarray(x)))
         assert fmod.finalize_margin(m, f).tobytes() == native_scores.tobytes()
 
 
@@ -151,7 +158,8 @@ def test_edge_batch_sizes_all_strategies(rng):
         ref = np.asarray(fmod.predict_margin(f, x)) if n else \
             np.zeros(0, np.float32)
         for strat in STRATEGIES:
-            m = np.asarray(fmod.make_margin_predictor(f, 12, strategy=strat)(x))
+            m = np.asarray(fmod.make_margin_predictor(
+                f, 12, strategy=strat, interpret=True)(x))
             assert m.shape == (n,) and m.tobytes() == ref.tobytes(), (strat, n)
 
 
@@ -190,7 +198,7 @@ def test_env_override_selects_strategy(rng, monkeypatch):
     ref = np.asarray(fmod.predict_margin(f, x))
     for strat in STRATEGIES:
         monkeypatch.setenv(fmod.FOREST_STRATEGY_ENV, strat)
-        fn = fmod.make_margin_predictor(f, 12)  # env-driven, no pin
+        fn = fmod.make_margin_predictor(f, 12, interpret=True)  # env-driven, no pin
         assert fmod.last_strategy == strat
         assert np.asarray(fn(x)).tobytes() == ref.tobytes()
     monkeypatch.delenv(fmod.FOREST_STRATEGY_ENV)
@@ -238,11 +246,40 @@ def test_explicit_pallas_on_missing_routing_fails_loudly(monkeypatch):
     monkeypatch.setenv(fmod.FOREST_STRATEGY_ENV, "pallas")
     with pytest.raises(EngineError, match="explicitly requested"):
         fmod.make_margin_predictor(forest, 3)
-    # auto mode keeps the documented fallback chain instead
+    # auto never resolves to pallas for a default_left forest
     monkeypatch.setenv(fmod.FOREST_STRATEGY_ENV, "auto")
+    assert fmod.resolve_strategy(forest, 3, backend="tpu") == "wide"
     fn = fmod.make_margin_predictor(forest, 3)
     assert fmod.last_strategy == "gather"  # cpu auto
     assert fn is not None
+
+
+def test_auto_resolved_strategy_that_cannot_build_raises(rng, monkeypatch):
+    """``auto`` resolves ONCE and the resolved program builds or the call
+    dies — there is no pallas -> wide -> gemm -> gather chain that would
+    let a run stamped with one strategy be scored by another (and let a
+    benchmark row labelled pallas quietly time wide)."""
+    from variantcalling_tpu.synthetic import synthetic_forest
+
+    f = synthetic_forest(rng, n_trees=3, depth=4, n_features=12)
+    # what auto resolves to on a TPU; on this CPU backend the Mosaic
+    # kernel cannot compile, which stands in for any build failure
+    monkeypatch.setattr(fmod, "resolve_strategy", lambda *a, **k: "pallas")
+    before = fmod.last_strategy
+    with pytest.raises(EngineError, match="auto-resolved.*no fallback chain"):
+        fmod.make_margin_predictor(f, 12)
+    assert fmod.last_strategy == before
+
+
+def test_explicit_pallas_on_cpu_backend_fails_loudly(rng):
+    """The product path never selects Pallas interpret mode: without the
+    test-only ``interpret=True`` argument the kernel must compile with
+    Mosaic, and on a CPU backend that is a loud configuration error."""
+    from variantcalling_tpu.synthetic import synthetic_forest
+
+    f = synthetic_forest(rng, n_trees=3, depth=4, n_features=12)
+    with pytest.raises(EngineError, match="explicitly requested"):
+        fmod.make_margin_predictor(f, 12, strategy="pallas")
 
 
 def test_invalid_strategy_env_fails_even_on_native_engine(rng, monkeypatch):
@@ -346,7 +383,7 @@ def test_formatted_tree_score_bytes_identical_across_strategies_12k(wide_parity_
     jit_eng = engine_mod.EngineDecision("jit", "jit", "test")
 
     scores = {}
-    for strat in STRATEGIES:
+    for strat in CPU_PIPELINE_STRATEGIES:
         scores[strat] = fused_featurize_score(w["model"], hf, "TGCA",
                                               engine=jit_eng, strategy=strat)
     native = _native_cpu_featurize_score(w["model"], hf, "TGCA", table, fasta)

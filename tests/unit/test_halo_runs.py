@@ -12,8 +12,7 @@ from variantcalling_tpu.ops import runs as rops
 # (make_mesh(n_data=8)). conftest forces 8 virtual CPU devices, so these
 # RUN in the suite; environments that cannot force a device count (or
 # that strip XLA_FLAGS) skip with the reason instead of erroring in mesh
-# construction. The historical jax.lax.axis_size failure on jax 0.4.37
-# is FIXED (halo_exchange_1d takes the static n_shards), not skipped.
+# construction.
 # LAZY (a fixture, not an import-time skipif): jax.local_devices()
 # initializes the backend, and collection must never pay that.
 

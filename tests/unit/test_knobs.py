@@ -48,11 +48,11 @@ def test_env_beats_default(monkeypatch):
 def test_empty_means_unset_except_str(monkeypatch):
     monkeypatch.setenv("VCTPU_IO_RETRIES", "")
     assert knobs.get_int("VCTPU_IO_RETRIES") == 2
-    # str knobs keep the empty string (VCTPU_COMPILE_CACHE="" disables)
-    monkeypatch.setenv("VCTPU_COMPILE_CACHE", "")
-    assert knobs.get_str("VCTPU_COMPILE_CACHE") == ""
-    monkeypatch.delenv("VCTPU_COMPILE_CACHE")
-    assert knobs.get_str("VCTPU_COMPILE_CACHE") is None
+    # str knobs keep the empty string as a value
+    monkeypatch.setenv("VCTPU_COORDINATOR", "")
+    assert knobs.get_str("VCTPU_COORDINATOR") == ""
+    monkeypatch.delenv("VCTPU_COORDINATOR")
+    assert knobs.get_str("VCTPU_COORDINATOR") is None
 
 
 def test_bool_spellings(monkeypatch):

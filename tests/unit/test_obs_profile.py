@@ -411,7 +411,24 @@ def test_record_scoring_cost_emits_once_per_run(tmp_path):
     assert ca[0]["flops"] > 0
     assert ca[0]["flops_per_variant"] == pytest.approx(
         ca[0]["flops"] / 256, rel=0.01)
-    assert ca[0]["roofline_vps_v5e"] > 0
+    # the CPU this test runs on is not in the published-peaks table: no
+    # roofline figure may be derived for it from another chip's peak
+    assert "roofline_vps" not in ca[0] and "device_kind" not in ca[0]
+
+
+def test_device_peaks_table_is_keyed_by_device_kind(monkeypatch):
+    """One table, keyed by what the device calls itself; a device that is
+    not listed gets None (and so no utilization/roofline field)."""
+    import jax
+
+    class _Dev:
+        device_kind = "TPU v5 lite"
+
+    assert profile_mod.device_peaks() is None  # cpu
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    peaks = profile_mod.device_peaks()
+    assert peaks == {"device_kind": "TPU v5 lite", "flops_bf16": 197e12,
+                     "hbm_bytes_per_s": 819e9}
 
 
 def test_jit_streaming_run_records_cost_analysis(stream_world, tmp_path,

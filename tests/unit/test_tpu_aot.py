@@ -1,0 +1,204 @@
+"""What the chip would be asked to compile, checked without a chip.
+
+``jax.experimental.topologies.get_topology_desc(platform="tpu",
+topology_name="v5e:2x2")`` describes four ``TPU v5 lite`` devices to the
+installed libtpu, and ``jit(...).lower(<specs placed on them>).compile()``
+runs the real XLA:TPU + Mosaic compilers against them. So "does every
+program ``auto`` can pick on a TPU compile for a v5e — at dp=1 and inside
+``shard_program`` at dp=4" is a tier-1 CPU test; the chip is only needed
+for "does it run and is it right" (``chip_smoke.py``).
+
+Plus the bring-up contracts that sit next to it: where the compile cache
+goes, what an explicit ``--backend tpu`` means on a machine without one.
+"""
+
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from variantcalling_tpu.models import forest as fmod
+
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+ROWS = 4096
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """(single-device sharding, dp=4 mesh, dp-sharded sharding) on a
+    described v5e 2x2 host — capability probe: skipped where the
+    installed jaxlib/libtpu cannot describe a TPU topology."""
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+    from variantcalling_tpu.parallel.mesh import DATA_AXIS, make_mesh
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure here is "no TPU compiler"
+        pytest.skip(f"capability probe: no TPU topology description ({e})")
+    assert [d.device_kind for d in topo.devices] == ["TPU v5 lite"] * 4
+    mesh = make_mesh(n_data=4, n_model=1, devices=topo.devices)
+    return (SingleDeviceSharding(topo.devices[0]), mesh,
+            NamedSharding(mesh, P(DATA_AXIS)))
+
+
+def _compile_dp1_and_dp4(fn, n_features, v5e):
+    from variantcalling_tpu.parallel import shard_score
+
+    single, mesh, dp_sharded = v5e
+    for program, sharding in (
+            (fn, single),
+            (shard_score.shard_program(fn, mesh, n_data_args=1), dp_sharded)):
+        spec = jax.ShapeDtypeStruct((ROWS, n_features), jnp.float32,
+                                    sharding=sharding)
+        compiled = jax.jit(program).lower(spec).compile()
+        assert compiled is not None
+
+
+def _tpu_strategy_program(strategy, forest, n_features):
+    if strategy == "pallas":
+        # the registry entry would warm the kernel up on THIS (CPU)
+        # backend; build the same kernel directly, Mosaic-bound
+        from variantcalling_tpu.models.forest_pallas import \
+            make_wide_pallas_margin_predictor
+
+        return make_wide_pallas_margin_predictor(
+            fmod.to_gemm(forest, n_features), interpret=False)
+    return fmod._build_margin_program(strategy, forest, n_features)
+
+
+def test_every_strategy_auto_can_pick_on_a_tpu_compiles_for_v5e(v5e, rng):
+    """The test that would have caught both bring-up blockers without a
+    chip: the Mosaic block-shape refusal (pallas) and the unvarying loop
+    carries inside shard_map (every strategy at dp > 1)."""
+    from tests.unit.test_xgb_ingest import _two_tree_model
+    from variantcalling_tpu.models.xgb import from_xgboost_json
+    from variantcalling_tpu.synthetic import synthetic_forest
+
+    bench_shape = synthetic_forest(rng, n_trees=40, depth=6, n_features=12)
+    widest_gemm = synthetic_forest(rng, n_trees=4, depth=10, n_features=12)
+    too_wide = synthetic_forest(rng, n_trees=2, depth=11, n_features=12)
+    missing_routing = from_xgboost_json(_two_tree_model())
+    assert fmod.max_tree_leaves(widest_gemm) == fmod.GEMM_MAX_LEAVES
+    cases = [(bench_shape, 12), (widest_gemm, 12), (too_wide, 12),
+             (missing_routing, 3)]
+    picked = set()
+    for forest, n_features in cases:
+        strategy = fmod.resolve_strategy(forest, n_features, backend="tpu")
+        picked.add(strategy)
+        _compile_dp1_and_dp4(
+            _tpu_strategy_program(strategy, forest, n_features), n_features, v5e)
+    # the cases above cover everything auto can return on a TPU
+    assert picked == {"pallas", "gather", "wide"}
+
+
+def test_dan_program_compiles_for_v5e(v5e):
+    from variantcalling_tpu.featurize import BASE_FEATURES
+    from variantcalling_tpu.models import dan as dan_mod
+    from variantcalling_tpu.synthetic import synthetic_dan
+
+    names = list(BASE_FEATURES)
+    model = synthetic_dan(np.random.default_rng(0), names, embed_dim=16,
+                          hidden=256, n_layers=2)  # the served width
+    _compile_dp1_and_dp4(dan_mod.make_score_predictor(model, names),
+                         len(names), v5e)
+
+
+# ---------------------------------------------------------------------------
+# compile-cache placement
+# ---------------------------------------------------------------------------
+
+_CACHE_PROBE = """
+import json, os, sys
+sys.path.insert(0, {repo!r})
+if {jax_first}:
+    import jax
+from variantcalling_tpu.utils import compile_cache
+assert compile_cache.enable_persistent_cache()
+import jax
+print(json.dumps({{"dir": jax.config.jax_compilation_cache_dir,
+                   "cache_dir": compile_cache.cache_dir(),
+                   "default": compile_cache.DEFAULT_DIR}}))
+"""
+
+
+def _cache_probe(env_dir, jax_first):
+    import json
+
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "PYTHONPATH")}
+    env["JAX_PLATFORMS"] = "cpu"
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    p = subprocess.run(
+        [sys.executable, "-c",
+         _CACHE_PROBE.format(repo=_REPO, jax_first=jax_first)],
+        env=env, capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr[-2000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("jax_first", [True, False])
+def test_compile_cache_follows_the_environment(tmp_path, jax_first):
+    """JAX_COMPILATION_CACHE_DIR set -> that directory, whether or not
+    jax was imported before the entry point enabled the cache (the CLI
+    imports the tool module, and with it jax, first)."""
+    want = str(tmp_path / "placed_from_outside")
+    got = _cache_probe(want, jax_first)
+    assert got["dir"] == want and got["cache_dir"] == want
+    assert not os.path.exists(want) or os.listdir(want) == []
+
+
+@pytest.mark.parametrize("jax_first", [True, False])
+def test_compile_cache_defaults_to_one_fixed_in_checkout_dir(jax_first):
+    got = _cache_probe(None, jax_first)
+    assert got["dir"] == got["default"] == got["cache_dir"]
+    assert got["default"] == os.path.join(_REPO, ".jax_cache")  # never ~ or a temp name
+
+
+def test_compile_cache_never_names_a_dir_when_the_environment_does(monkeypatch, tmp_path):
+    from variantcalling_tpu.utils import compile_cache
+
+    calls = []
+    monkeypatch.setattr(compile_cache, "_ENABLED", False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append(name))
+    assert compile_cache.enable_persistent_cache()
+    assert "jax_compilation_cache_dir" not in calls
+
+
+# ---------------------------------------------------------------------------
+# an explicit --backend is a requirement
+# ---------------------------------------------------------------------------
+
+
+def test_explicit_backend_tpu_without_a_tpu_exits_2(tmp_path, capsys):
+    """``--backend tpu`` on a CPU-only process exits 2 before touching
+    any input — it never carries on through the host engine."""
+    from variantcalling_tpu.__main__ import main
+    from variantcalling_tpu.serve import cli as serve_cli
+
+    out = tmp_path / "out.vcf"
+    rc = main(["filter_variants_pipeline", "--input_file", "absent.vcf",
+               "--model_file", "absent.pkl", "--model_name", "m",
+               "--reference_file", "absent.fa", "--output_file", str(out),
+               "--backend", "tpu"])
+    assert rc == 2 and not out.exists()
+    assert serve_cli.run(["--backend", "tpu", "--port", "0"]) == 2
+
+
+def test_pin_backend_none_takes_what_jax_initialized():
+    from variantcalling_tpu import engine
+
+    engine.pin_backend(None)
+    engine.pin_backend("cpu")
+    with pytest.raises(engine.EngineError, match="--backend tpu"):
+        engine.pin_backend("tpu")

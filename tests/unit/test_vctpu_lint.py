@@ -313,7 +313,7 @@ def test_vct006_monotonic_sleep_and_nonlibrary_exempt():
         t0 = time.perf_counter()
         '''
     assert codes(src, path="bench.py") == []
-    assert codes(src, path="tools/tpu_probe.py") == []
+    assert codes(src, path="tools/podrun.py") == []
     # the obs subsystem and trace.py ARE the timing layer
     assert codes(src, path="variantcalling_tpu/obs/__init__.py") == []
     assert codes(src, path="variantcalling_tpu/utils/trace.py") == []
@@ -548,7 +548,7 @@ def test_vct008_scoped_to_pipelines_and_suppressible():
 def test_vct009_psum_over_margins_in_shard_map_body_flagged():
     fs = run("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(x, margins):
             return jax.lax.psum(margins, "dp")
@@ -575,7 +575,7 @@ def test_vct009_jnp_sum_over_scores_in_shard_program_body_flagged():
     # method form (VCT003 also fires on the tree/margin vocabulary —
     # both codes own this line; select isolates the shard_map rule)
     assert codes("""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(tree_margins):
             return tree_margins.sum(axis=1)
@@ -606,7 +606,7 @@ def test_vct009_resolves_aliased_bodies():
     # an aliased lambda body is still a body
     assert codes("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         fn = lambda margins: jax.lax.psum(margins, "dp")
         prog = shard_map(fn, mesh=None, in_specs=(), out_specs=())
@@ -615,7 +615,7 @@ def test_vct009_resolves_aliased_bodies():
     # stays unscanned even when an unrelated alias of it exists
     assert codes("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(x):
             return x
@@ -635,7 +635,7 @@ def test_vct009_sanctioned_and_unrelated_sums_pass():
     assert codes("""
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(x, counts):
             m = sequential_tree_sum(x)
@@ -649,7 +649,7 @@ def test_vct009_sanctioned_and_unrelated_sums_pass():
     # a lambda body is still a body
     assert codes("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         prog = shard_map(lambda margins: jax.lax.psum(margins, "dp"),
                          mesh=None, in_specs=(), out_specs=())
@@ -659,7 +659,7 @@ def test_vct009_sanctioned_and_unrelated_sums_pass():
 def test_vct009_suppressible():
     assert codes("""
         import jax
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         def body(margins):
             return jax.lax.psum(margins, "dp")  # vctpu-lint: disable=VCT009 — test fixture

@@ -124,8 +124,8 @@ def main(argv: list[str] | None = None) -> int:
 
             init_from_env()
         # per-file CLI invocations must not re-pay jit compiles: persist XLA
-        # executables across processes (~/.cache/vctpu/xla, VCTPU_COMPILE_CACHE
-        # overrides, empty disables)
+        # executables across processes (JAX_COMPILATION_CACHE_DIR, else the
+        # fixed in-checkout directory — utils/compile_cache.py)
         from variantcalling_tpu.utils.compile_cache import enable_persistent_cache
 
         enable_persistent_cache()

@@ -72,6 +72,33 @@ _LOCK = threading.Lock()
 _RESOLVED: EngineDecision | None = None
 
 
+def pin_backend(requested: str | None) -> None:
+    """Honor a CLI ``--backend``. ``None`` (no flag) takes whatever
+    platform JAX initializes — the output header names the engine that
+    scored. ``cpu`` pins JAX to the CPU platform (effective before the
+    backend initializes). ``tpu`` is a requirement, not a hint: the run
+    must find ``jax.default_backend() == "tpu"`` or it dies with
+    EngineError (exit 2) instead of carrying on through the host engine.
+    """
+    import jax
+
+    if requested == "cpu":
+        jax.config.update("jax_platforms", "cpu")
+    elif requested == "tpu":
+        try:
+            found = jax.default_backend()
+        except RuntimeError as e:  # backend initialization failed
+            raise EngineError(
+                f"--backend tpu was requested but JAX could not initialize "
+                f"a backend: {e}") from e
+        if found != "tpu":
+            raise EngineError(
+                f"--backend tpu was requested but JAX initialized the "
+                f"{found!r} platform — no TPU is visible to this process "
+                "(is another process holding the chip, or JAX_PLATFORMS "
+                "set?). Drop the flag to run on what JAX finds.")
+
+
 def _requested() -> str:
     req = knobs.get_str(ENGINE_ENV)
     if knobs.get_bool(REQUIRE_ENV):
@@ -94,13 +121,12 @@ def _auto_wants_native() -> bool:
     path and accelerators stay on XLA."""
     if not knobs.get_bool("VCTPU_NATIVE_FOREST"):
         return False
-    try:
-        import jax
+    # a backend that fails to initialize raises from here: on a machine
+    # that should have an accelerator that is the finding, not a reason
+    # to pick an engine
+    import jax
 
-        return jax.default_backend() == "cpu" and len(jax.local_devices()) == 1
-    except Exception as e:  # noqa: BLE001 — backend probe failure: stay on jit
-        degrade.record("engine.backend_probe", e, fallback="auto resolves to jit")
-        return False
+    return jax.default_backend() == "cpu" and len(jax.local_devices()) == 1
 
 
 def resolve() -> EngineDecision:

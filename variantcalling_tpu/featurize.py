@@ -240,11 +240,13 @@ def globalize_positions(table: VariantTable, genome: DeviceGenome,
     ``radius`` past the end still resolve idx-wise into the N gap, exactly
     like the host path.
     """
-    import pandas as pd
-
-    chrom = pd.Series(np.asarray(table.chrom))
-    off = chrom.map(genome.offsets).to_numpy(dtype=np.float64)  # NaN = unknown
-    clen = chrom.map(genome.lengths).to_numpy(dtype=np.float64)
+    # per-contig lookup through the parser's integer contig codes: one
+    # dict probe per contig, not one string conversion per record
+    codes, uniques, _ = _contig_runs(table, len(table))
+    off = np.asarray([genome.offsets.get(c, np.nan) for c in uniques],
+                     dtype=np.float64)[codes]  # NaN = unknown contig
+    clen = np.asarray([genome.lengths.get(c, np.nan) for c in uniques],
+                      dtype=np.float64)[codes]
     pos0 = table.pos.astype(np.int64) - 1
     gpos = pos0 + np.nan_to_num(off, nan=0).astype(np.int64)
     bad = np.isnan(off) | (pos0 < 0) | (pos0 >= np.nan_to_num(clen, nan=-1) + radius)
@@ -663,29 +665,31 @@ def host_featurize(
 
 def standard_genome_sharding(mesh=None):
     """The ONE sharding every consumer passes to device_genome: replicated
-    over ``mesh`` when the caller resolved a run scoring mesh (the
-    filter pipeline's >1-device mesh plan), else the process-default
-    policy (replicate over the full (dp, mp) local mesh on multi-device
-    processes, None single-device). Mesh-plan callers route their
-    possibly-None mesh through here unconditionally — a single-device
-    plan falls through to the SAME default policy as every no-arg
-    consumer, so the cache key cannot split on who uploaded first.
+    over ``mesh`` when the caller resolved a >1-device run scoring mesh
+    (the filter pipeline's mesh plan), else None — the genome lives on
+    the process's default device and the program that reads it runs
+    there, on one device. Mesh-plan callers route their possibly-None
+    mesh through here unconditionally, so a single-device plan and every
+    no-arg consumer agree on the cache key.
+
+    A single-device plan must NOT replicate over the other local devices:
+    a program fed one argument committed to N devices runs on all N
+    (N-fold redundant work for the jnp strategies), and XLA refuses to
+    auto-partition a Mosaic kernel at all — on a four-chip host the
+    explicit ``VCTPU_MESH_DEVICES=1`` run and the recovery ladder's dp=1
+    restart died with "Mosaic kernels cannot be automatically
+    partitioned" until this returned None for them.
 
     All genome-cache keys include the sharding, so consumers that chose
     shardings independently would split the cache — and the small-job
     guard (_genome_resident_worthwhile) would answer differently
-    depending on which consumer ran first (round-2 VERDICT weak #6).
-    Routing through this helper makes the key identical by construction;
-    mesh-plan callers must pass the SAME resolved mesh everywhere
-    (FilterContext does).
+    depending on which consumer ran first. Routing through this helper
+    makes the key identical by construction; mesh-plan callers must pass
+    the SAME resolved mesh everywhere (FilterContext does).
     """
-    from variantcalling_tpu.parallel.mesh import make_mesh, replicated
+    from variantcalling_tpu.parallel.mesh import replicated
 
-    if mesh is not None:
-        return replicated(mesh)
-    if len(jax.local_devices()) <= 1:
-        return None
-    return replicated(make_mesh(n_model=1))
+    return replicated(mesh) if mesh is not None else None
 
 
 def featurize(

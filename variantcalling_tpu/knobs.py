@@ -28,8 +28,7 @@ knob):
 
 Booleans accept ``1/true/yes/on`` and ``0/false/no/off`` (case
 insensitive); a set-but-empty variable means "unset" except for ``str``
-knobs, where the empty string is meaningful (``VCTPU_COMPILE_CACHE=""``
-disables the cache).
+knobs, which keep the empty string as a value.
 """
 
 from __future__ import annotations
@@ -214,9 +213,6 @@ REGISTRY: dict[str, Knob] = {k.name: k for k in (
        "chunk-result cache size bound in MiB (LRU eviction; bounds the "
        "on-disk store and the serve daemon's in-memory warm index "
        "separately)", positive=True),
-    _k("VCTPU_COMPILE_CACHE", "str", None,
-       "persistent XLA compilation cache dir; empty string disables; "
-       "default ~/.cache/vctpu/xla"),
     _k("VCTPU_GENOME_CACHE", "bool", True,
        "persist the encoded genome as a .venc sidecar and memmap hits"),
     _k("VCTPU_GENOME_CACHE_DIR", "str", "",
@@ -361,11 +357,6 @@ REGISTRY: dict[str, Knob] = {k.name: k for k in (
        "run_tests.sh: run the opt-in simulated multi-host stage (the "
        "2-process local launcher end-to-end on the cpu backend plus the "
        "multi-process system tests — docs/scaleout.md)"),
-    _k("VCTPU_PROBE_INTERVAL", "int", 1800,
-       "tools/tpu_probe.py polling interval in seconds", positive=True),
-    _k("VCTPU_PROBE_HOURS", "float", 11.5,
-       "tools/tpu_probe.py total probe-loop duration in hours",
-       minimum=0.0),
 )}
 
 

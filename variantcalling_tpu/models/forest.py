@@ -57,13 +57,15 @@ def sequential_tree_sum(per_tree: jnp.ndarray) -> jnp.ndarray:
     that produces bit-exact per-tree leaf values produces bit-identical
     margins (the round-5 multihost byte-parity fix, see predict_margin).
     """
-    n, t = per_tree.shape
+    t = per_tree.shape[1]
 
     def acc_body(ti, acc):
         return acc + per_tree[:, ti]
 
-    return jax.lax.fori_loop(0, t, acc_body,
-                             jnp.zeros(n, dtype=per_tree.dtype))
+    # the zero carry is derived from the input so it carries the input's
+    # varying mesh axes: inside shard_map a plain jnp.zeros is unvarying
+    # and the loop's carry types would not match
+    return jax.lax.fori_loop(0, t, acc_body, jnp.zeros_like(per_tree[:, 0]))
 
 
 def _packed_node_table(forest: FlatForest) -> np.ndarray:
@@ -142,7 +144,8 @@ def predict_margin(forest: FlatForest, x: jnp.ndarray) -> jnp.ndarray:
                         unpack_i32(rows[..., 3]))
         return jnp.where(f == LEAF, idx, nxt)
 
-    idx0 = jnp.zeros((n, t), dtype=jnp.int32)
+    # derived from x for the same reason as sequential_tree_sum's carry
+    idx0 = jnp.zeros_like(x, dtype=jnp.int32, shape=(n, t))
     idx = jax.lax.fori_loop(0, forest.max_depth, body, idx0)
     leaf_vals = ptab[toff + idx][..., 4]  # (N, T)
     return sequential_tree_sum(leaf_vals)
@@ -324,7 +327,7 @@ def predict_margin_gemm(gf: GemmForest, x: jnp.ndarray) -> jnp.ndarray:
         s = jnp.dot(onehot, value, precision=jax.lax.Precision.HIGHEST)  # (N,)
         return acc + s, None
 
-    total, _ = jax.lax.scan(per_tree, jnp.zeros(x.shape[0], dtype=jnp.float32), tables)
+    total, _ = jax.lax.scan(per_tree, jnp.zeros_like(x[:, 0], dtype=jnp.float32), tables)
     return total
 
 
@@ -526,10 +529,9 @@ def predict_score_wide(wf: WideGemmForest, x: jnp.ndarray) -> jnp.ndarray:
                             wf.n_trees, wf.base_score)
 
 
-#: Strategy chosen by the most recent make_predictor/make_margin_predictor
-#: call — bench logs it so a silent pallas->wide (or wide->gather) fallback
-#: is visible in the captured perf evidence instead of invisibly changing
-#: what was measured.
+#: Strategy built by the most recent make_predictor/make_margin_predictor
+#: call ("native-cpp" when the C++ engine scored) — bench and the obs cost
+#: attribution label their rows with it.
 last_strategy: str = "none"
 
 #: explicit strategy override: {auto,gather,gemm,wide,pallas}
@@ -558,16 +560,6 @@ def validate_strategy_env() -> None:
     requested_strategy()
     _int_env(WIDE_CHUNK_ENV)
     _int_env(WIDE_BLOCK_ENV)
-
-
-def _backend() -> str:
-    try:
-        return jax.default_backend()
-    except Exception as e:  # backend init failure must not break program construction
-        from variantcalling_tpu.utils import degrade
-
-        degrade.record("forest.backend_probe", e, fallback='backend="cpu"')
-        return "cpu"
 
 
 def max_tree_leaves(forest: FlatForest) -> int:
@@ -602,7 +594,7 @@ def resolve_strategy(forest: FlatForest, n_features: int | None = None,
     if req != "auto":
         resolved, why = req, "explicitly requested"
     else:
-        backend = backend or _backend()
+        backend = backend or jax.default_backend()
         if backend == "cpu":
             resolved, why = "gather", "auto: cpu backend keeps the gather walk"
         elif max_tree_leaves(forest) > GEMM_MAX_LEAVES:
@@ -619,12 +611,12 @@ def resolve_strategy(forest: FlatForest, n_features: int | None = None,
 
 
 def _build_margin_program(strategy: str, forest: FlatForest,
-                          n_features: int | None):
+                          n_features: int | None, interpret: bool = False):
     """fn(x) -> canonical-order margin for one concrete strategy.
 
     Raises on anything the strategy cannot serve (pallas lowering gaps,
-    bad env values) — the CALLER decides whether that is a loud failure
-    (explicitly requested strategy) or an auto fallback.
+    bad env values); :func:`make_margin_predictor` turns that into an
+    EngineError.
     """
     if strategy == "gather":
         return lambda x: predict_margin(forest, x)
@@ -638,31 +630,28 @@ def _build_margin_program(strategy: str, forest: FlatForest,
         from variantcalling_tpu.models.forest_pallas import \
             make_wide_pallas_margin_predictor
 
-        fn = make_wide_pallas_margin_predictor(gf)
-        # lowering failures only surface at the first call — warm up HERE
-        # so a gap is attributable to construction, not to a random caller
+        fn = make_wide_pallas_margin_predictor(gf, interpret=interpret)
+        # Mosaic lowering failures only surface at the first compile — do
+        # it HERE so a gap is a construction-time EngineError, not an
+        # exception from inside some chunk's dispatch
         n_feat = gf.a.shape[1]
         jax.block_until_ready(jax.jit(fn)(jnp.zeros((1, n_feat), jnp.float32)))
         return fn
     raise ValueError(f"unknown forest strategy {strategy!r}")
 
 
-#: auto-mode fallback order after the resolved strategy fails to build
-_AUTO_FALLBACK = ("wide", "gemm", "gather")
-
-
 def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
-                          strategy: str | None = None):
+                          strategy: str | None = None,
+                          interpret: bool = False):
     """jittable fn(x) -> canonical-order margin, by strategy.
 
-    ``strategy=None`` reads ``VCTPU_FOREST_STRATEGY`` (default ``auto``).
-    An EXPLICITLY requested strategy (argument or env, not ``auto``) that
-    cannot build FAILS LOUDLY with EngineError (exit-2 style at the CLI) —
-    the PR-2 contract: a pinned configuration is honored or the run dies,
-    never silently degraded (the old ``make_predictor`` swallowed pallas
-    lowering failures with a bare except). Auto mode keeps the documented
-    fallback chain (pallas -> wide -> gemm -> gather), each hop recorded
-    in :data:`last_strategy`.
+    ``strategy=None`` reads ``VCTPU_FOREST_STRATEGY`` (default ``auto``,
+    resolved once through :func:`resolve_strategy`). The resolved
+    strategy builds or the call raises EngineError (exit-2 style at the
+    CLI) — requested explicitly or chosen by ``auto``, a configuration is
+    honored or the run dies, never silently scored by another program.
+    ``interpret=True`` runs the pallas kernel through the Pallas
+    interpreter (tests on a CPU backend ask for it; no product path does).
 
     Every strategy returns the SAME bits: bit-exact per-tree leaf margins
     reduced in canonical tree order (:func:`sequential_tree_sum` /
@@ -673,48 +662,23 @@ def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
     from variantcalling_tpu.engine import EngineError
 
     req = strategy if strategy is not None else requested_strategy()
-    explicit = req != "auto"
-    if explicit and req not in FOREST_STRATEGIES:
+    if req != "auto" and req not in FOREST_STRATEGIES:
         raise EngineError(
             f"forest strategy {req!r} is not one of "
             f"{'/'.join(FOREST_STRATEGIES[1:])}")
-    resolved = req if explicit else resolve_strategy(forest, n_features)
+    resolved = resolve_strategy(forest, n_features) if req == "auto" else req
     try:
-        fn = _build_margin_program(resolved, forest, n_features)
-    except Exception as e:  # noqa: BLE001 — fate decided by explicitness
-        if explicit:
-            raise EngineError(
-                f"forest strategy '{resolved}' was explicitly requested "
-                f"({FOREST_STRATEGY_ENV} or a pinned run configuration) but "
-                f"cannot serve this forest/backend: {type(e).__name__}: {e}. "
-                "Refusing to silently fall back — rerun with "
-                f"{FOREST_STRATEGY_ENV}=auto to opt into fallback, or "
-                "VCTPU_PALLAS=0 if the pallas kernel cannot serve this "
-                "forest (the filter pipeline pins auto's resolution, so "
-                "re-running auto repeats this choice). "
-                "See docs/models.md.") from e
-        from variantcalling_tpu.utils import degrade
-
-        degrade.record("forest.auto_fallback", e,
-                       fallback=f"auto-resolved strategy {resolved!r} cannot "
-                       "build; walking the fallback chain", warn=True)
-        fn = None
-        for fb in _AUTO_FALLBACK:
-            if fb == resolved:
-                continue
-            try:
-                fn = _build_margin_program(fb, forest, n_features)
-                resolved = fb
-                break
-            except Exception as fb_err:  # noqa: BLE001 — keep walking the chain
-                from variantcalling_tpu.utils import degrade
-
-                degrade.record("forest.auto_fallback", fb_err,
-                               fallback=f"strategy {fb!r} also failed; "
-                               "trying next in chain", warn=True)
-                continue
-        if fn is None:
-            raise
+        fn = _build_margin_program(resolved, forest, n_features, interpret)
+    except Exception as e:  # noqa: BLE001 — any build failure is a config error
+        how = "auto-resolved" if req == "auto" else \
+            f"explicitly requested ({FOREST_STRATEGY_ENV} or a pinned run " \
+            "configuration)"
+        raise EngineError(
+            f"forest strategy '{resolved}' was {how} but cannot serve this "
+            f"forest/backend: {type(e).__name__}: {e}. There is no fallback "
+            f"chain — pick a strategy that can with {FOREST_STRATEGY_ENV} "
+            "(gather serves every forest on every backend). "
+            "See docs/models.md.") from e
     last_strategy = resolved  # vctpu-lint: disable=VCT010 — run-scoped diagnostic; GIL-atomic store, the strategy is pinned per run so every writer agrees
     return fn
 

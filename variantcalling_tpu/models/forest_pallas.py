@@ -1,41 +1,41 @@
-"""Pallas TPU kernel for GEMM-forest inference.
+"""Pallas TPU kernel for wide-block GEMM-forest inference.
 
-The jnp formulation (models/forest.predict_score_gemm) scans trees with
-three matmuls per step; each step's (N, I) decision and (N, L) routing
+The jnp formulation (``models/forest.predict_pertree_margin_wide``) scans
+tree blocks with three matmuls per step; each step's decision and routing
 intermediates round-trip through HBM unless XLA happens to fuse them.
-This kernel keeps the WHOLE per-tree chain in VMEM:
+This kernel keeps the whole per-block chain in VMEM.
 
-    grid = (variant tiles, trees); per step the (TILE_N, F) feature tile
-    and tree t's tables sit in VMEM, and
+Layout: variants ride the LANE axis. Every operand is the transpose of
+the ``models/forest.to_wide`` encoding, so per (tree block, variant tile)
+grid step
 
-        xf    = x @ a[t]          (MXU, HIGHEST precision feature pick)
-        d     = xf <= thr[t]      (VPU)
-        match = d @ m2[t] + c[t]  (MXU; exact small ints)
-        hit   = match == plen[t]  (VPU)
-        out  += hit @ value[t]    (MXU accumulate into the output block)
+    xf    = a[b]^T  @ x^T        (G*I, TILE_N)   MXU, fp32 contraction
+    d     = xf <= thr[b]         (G*I, TILE_N)   VPU
+    match = m2[b]^T @ d          (G*L, TILE_N)   MXU, exact small ints in bf16
+    hit   = match == plen - c    (G*L, TILE_N)   VPU
+    out   = vsel[b] @ hit        (G,   TILE_N)   MXU, fp32 contraction
 
-    Only the (TILE_N, 1) score block ever leaves VMEM — per-tree
-    intermediates never touch HBM. Trees iterate innermost, so the output
-    block revisits and accumulates (TPU grids run sequentially).
+and the (G, TILE_N) per-tree margin block is the only thing written back
+— a lane-dense store. ``vsel`` is the block-structured leaf-value
+selector (row g holds tree g's leaf values in its own L columns, zeros
+elsewhere): exactly one hit per (tree, variant) survives, every other
+term is an exact +0.0, so the per-tree margin is the exact f32 leaf
+value. The canonical-order tree reduction runs OUTSIDE the kernel through
+the one shared ``forest.sequential_tree_sum``, so margins are
+bit-identical to the gather walk, the jnp GEMM paths and the native C++
+engine (checked on the chip by ``chip_smoke.py``).
 
-Two kernels live here:
-
-- the original per-tree kernel (``make_gemm_pallas_predictor``): grid
-  (variant tiles, trees), output block accumulates the margin across the
-  sequential tree-innermost grid — kept for reference/fallback;
-- the WIDE-BLOCK kernel (``make_wide_pallas_margin_predictor``): grid
-  (variant tiles, tree blocks) over the block-diagonal wide encoding
-  (``models/forest.to_wide``). Each step computes G trees per MXU pass
-  and emits a (TILE_N, G) per-tree margin block; the canonical-order tree
-  reduction runs OUTSIDE the kernel through the one shared
-  ``forest.sequential_tree_sum``, so margins are bit-identical to the
-  gather walk, the jnp GEMM paths and the native C++ engine.
+Mosaic block shapes: the last two dimensions of every block are either
+the full array extent or multiples of (8, 128); the tree-block index is a
+leading squeezed dimension, and G*I, G*L, G and F are zero-padded to
+tile multiples on the host (padded decision rows meet zero routing
+columns, padded leaves carry plen - c = -1 and never match).
 
 Integration: the ``pallas`` entry of the models/forest strategy registry
-(``VCTPU_FOREST_STRATEGY``; auto prefers it on TPU, VCTPU_PALLAS=0 opts
-out) builds the wide-block kernel; CPU tests run the same kernels in
-interpreter mode. Forests with missing-value routing (default_left) use
-the jnp paths — NaN-bearing inputs need the extra mask matmul.
+(``VCTPU_FOREST_STRATEGY``). Forests with missing-value routing
+(default_left) use the jnp paths — NaN-bearing inputs need the extra mask
+matmul. ``interpret=True`` runs the same kernel through the Pallas
+interpreter; only tests ask for it.
 """
 
 from __future__ import annotations
@@ -48,97 +48,60 @@ import jax.numpy as jnp
 TILE_N = 512
 
 
-def _tree_step_kernel(x_ref, a_ref, thr_ref, m2_ref, c_ref, plen_ref, val_ref, out_ref):
-    from jax.experimental import pallas as pl
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
 
-    x = x_ref[:]  # (TILE_N, F)
-    a = a_ref[0]  # (F, I)
+def _wide_block_kernel(xt_ref, at_ref, thr_ref, m2t_ref, q_ref, vsel_ref,
+                       out_ref):
+    """One (variant tile, tree block) step; see the module docstring."""
     # feature pick must keep f32 values exact (thresholds compare tightly)
-    xf = jax.lax.dot_general(x, a, (((1,), (0,)), ((), ())),
-                             precision=jax.lax.Precision.HIGHEST,
-                             preferred_element_type=jnp.float32)
-    d = (xf <= thr_ref[0][None, :]).astype(jnp.float32)  # (TILE_N, I)
-    # routing operands are exact small integers — default precision is safe
-    match = jax.lax.dot_general(d, m2_ref[0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    match = match + c_ref[0][None, :]
-    hit = (match == plen_ref[0][None, :]).astype(jnp.float32)  # (TILE_N, L)
-    s = jax.lax.dot_general(hit, val_ref[0][:, None], (((1,), (0,)), ((), ())),
-                            precision=jax.lax.Precision.HIGHEST,
-                            preferred_element_type=jnp.float32)  # (TILE_N, 1)
-    out_ref[:] += s
+    xf = jnp.dot(at_ref[...], xt_ref[...],
+                 precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
+    d = (xf <= thr_ref[...]).astype(jnp.bfloat16)
+    # block-diagonal routing: 0/1 decisions against -1/0/+1 path entries,
+    # exact in bf16 with f32 accumulation
+    match = jnp.dot(m2t_ref[...], d, preferred_element_type=jnp.float32)
+    hit = (match == q_ref[...]).astype(jnp.float32)
+    out_ref[...] = jnp.dot(vsel_ref[...], hit,
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
 
 
-def _margin_pallas(tables, x, interpret: bool) -> jnp.ndarray:
-    """Summed per-tree margins for a PADDED (N, F) f32 matrix."""
-    from jax.experimental import pallas as pl
-
-    a, thr, m2, c, plen, value = tables
-    t, f, i = a.shape
-    l = m2.shape[2]
-    n = x.shape[0]
-    assert n % TILE_N == 0
-
-    out = pl.pallas_call(
-        _tree_step_kernel,
-        grid=(n // TILE_N, t),
-        in_specs=[
-            pl.BlockSpec((TILE_N, f), lambda bi, ti: (bi, 0)),
-            pl.BlockSpec((1, f, i), lambda bi, ti: (ti, 0, 0)),
-            pl.BlockSpec((1, i), lambda bi, ti: (ti, 0)),
-            pl.BlockSpec((1, i, l), lambda bi, ti: (ti, 0, 0)),
-            pl.BlockSpec((1, l), lambda bi, ti: (ti, 0)),
-            pl.BlockSpec((1, l), lambda bi, ti: (ti, 0)),
-            pl.BlockSpec((1, l), lambda bi, ti: (ti, 0)),
-        ],
-        out_specs=pl.BlockSpec((TILE_N, 1), lambda bi, ti: (bi, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        interpret=interpret,
-    )(x, a, thr, m2, c, plen, value)
-    return out[:, 0]
-
-
-def _wide_block_kernel(x_ref, a_ref, thr_ref, m2_ref, c_ref, plen_ref,
-                       val_ref, out_ref):
-    """One (variant tile, tree block) step of the WIDE strategy: the whole
-    per-block chain — wide feature pick, compare, block-diagonal routing,
-    per-tree leaf pick — stays in VMEM; only the (TILE_N, G) per-tree
-    margin block leaves. No cross-step accumulation: each grid step owns
-    its output block, and the canonical-order tree reduction happens
-    OUTSIDE the kernel through the shared forest.sequential_tree_sum."""
-    x = x_ref[:]  # (TILE_N, F)
-    a = a_ref[0]  # (F, G*I)
-    # feature pick must keep f32 values exact (thresholds compare tightly)
-    xf = jax.lax.dot_general(x, a, (((1,), (0,)), ((), ())),
-                             precision=jax.lax.Precision.HIGHEST,
-                             preferred_element_type=jnp.float32)
-    d = (xf <= thr_ref[0][None, :]).astype(jnp.float32)  # (TILE_N, G*I)
-    # block-diagonal routing: operands are exact small integers
-    match = jax.lax.dot_general(d, m2_ref[0], (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    match = match + c_ref[0][None, :]
-    hit = (match == plen_ref[0][None, :]).astype(jnp.float32)  # (TILE_N, G*L)
-    val = val_ref[0]  # (G, L)
-    g, l = val.shape
-    # per-tree leaf pick on the VPU: exactly one hit per (variant, tree),
-    # every other term is an exact +0.0 — bit-exact in any reduction order
-    out_ref[:] = jnp.sum(hit.reshape(x.shape[0], g, l) * val[None, :, :],
-                         axis=2)
+def _kernel_tables(wf):
+    """Host-side transpose + tile padding of a WideGemmForest into the
+    kernel's operands (numpy, once per predictor build)."""
+    b, f, gi = wf.a.shape
+    gl = wf.m2.shape[2]
+    g = wf.tree_block
+    l = gl // g
+    fp, gip, glp, gp = (_round_up(f, 8), _round_up(gi, 128),
+                        _round_up(gl, 128), _round_up(g, 8))
+    at = np.zeros((b, gip, fp), np.float32)
+    at[:, :gi, :f] = wf.a.transpose(0, 2, 1)
+    thr = np.zeros((b, gip, 1), np.float32)
+    thr[:, :gi, 0] = wf.thr
+    m2t = np.zeros((b, glp, gip), np.float32)
+    m2t[:, :gl, :gi] = wf.m2.transpose(0, 2, 1)
+    q = np.full((b, glp, 1), -1.0, np.float32)
+    q[:, :gl, 0] = wf.plen - wf.c
+    vsel = np.zeros((b, gp, glp), np.float32)
+    for k in range(g):
+        vsel[:, k, k * l:(k + 1) * l] = wf.value[:, k]
+    return at, thr, m2t.astype(jnp.bfloat16), q, vsel
 
 
 def make_wide_pallas_margin_predictor(gf, tree_block: int | None = None,
-                                      interpret: bool | None = None):
+                                      interpret: bool = False):
     """fn(x) -> canonical-order margin for a GemmForest, running the
     wide-block kernel (grid over (variant tile, tree block); all of a
     block's operands VMEM-resident).
 
     Raises ValueError for forests the kernel does not cover (missing-value
-    routing); the auto strategy falls back to the jnp wide path, an
-    explicit ``pallas`` request fails loudly (models/forest registry).
+    routing); ``forest.resolve_strategy`` never auto-selects it for them
+    and an explicit ``pallas`` request fails loudly (models/forest
+    registry).
     """
     from jax.experimental import pallas as pl
 
@@ -146,95 +109,57 @@ def make_wide_pallas_margin_predictor(gf, tree_block: int | None = None,
 
     if gf.dleft is not None:
         raise ValueError("pallas forest kernel does not implement default_left routing")
-    if interpret is None:
-        try:
-            interpret = jax.default_backend() != "tpu"
-        except Exception as e:  # noqa: BLE001
-            from variantcalling_tpu.utils import degrade
-
-            degrade.record("forest_pallas.backend_probe", e,
-                           fallback="interpret=True")
-            interpret = True
     wf = forest_mod.to_wide(gf, tree_block)
-    b, f, gi = wf.a.shape
-    gl = wf.m2.shape[2]
+    tables = _kernel_tables(wf)
+    b, gip, fp = tables[0].shape
+    glp = tables[2].shape[1]
+    gp = tables[4].shape[1]
+    f = wf.a.shape[1]
     g = wf.tree_block
-    tables = (
-        jnp.asarray(wf.a),
-        jnp.asarray(wf.thr),
-        jnp.asarray(wf.m2),
-        jnp.asarray(wf.c),
-        jnp.asarray(wf.plen),
-        jnp.asarray(wf.value),
-    )
     n_trees = wf.n_trees
 
+    def table_spec(*shape):
+        return pl.BlockSpec((None, *shape), lambda ni, bi: (bi, 0, 0))
+
     def predict(x):
         n = x.shape[0]
         if n == 0:  # a zero-size grid cannot dispatch
             return jnp.zeros((0,), jnp.float32)
-        pad = (-n) % TILE_N
-        xp = jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0)))
+        n_pad = _round_up(n, TILE_N)
+        xt = jnp.pad(x.astype(jnp.float32).T, ((0, fp - f), (0, n_pad - n)))
         per_tree = pl.pallas_call(
             _wide_block_kernel,
-            grid=(xp.shape[0] // TILE_N, b),
+            grid=(n_pad // TILE_N, b),
             in_specs=[
-                pl.BlockSpec((TILE_N, f), lambda bi, ti: (bi, 0)),
-                pl.BlockSpec((1, f, gi), lambda bi, ti: (ti, 0, 0)),
-                pl.BlockSpec((1, gi), lambda bi, ti: (ti, 0)),
-                pl.BlockSpec((1, gi, gl), lambda bi, ti: (ti, 0, 0)),
-                pl.BlockSpec((1, gl), lambda bi, ti: (ti, 0)),
-                pl.BlockSpec((1, gl), lambda bi, ti: (ti, 0)),
-                pl.BlockSpec((1, g, gl // g), lambda bi, ti: (ti, 0, 0)),
+                pl.BlockSpec((fp, TILE_N), lambda ni, bi: (0, ni)),
+                table_spec(gip, fp),
+                table_spec(gip, 1),
+                table_spec(glp, gip),
+                table_spec(glp, 1),
+                table_spec(gp, glp),
             ],
-            out_specs=pl.BlockSpec((TILE_N, g), lambda bi, ti: (bi, ti)),
-            out_shape=jax.ShapeDtypeStruct((xp.shape[0], b * g), jnp.float32),
+            out_specs=pl.BlockSpec((None, gp, TILE_N),
+                                   lambda ni, bi: (bi, 0, ni)),
+            # inside shard_map the output varies over the same mesh axes
+            # as the input shard
+            out_shape=jax.ShapeDtypeStruct((b, gp, n_pad), jnp.float32,
+                                           vma=jax.typeof(xt).vma),
+            # XLA's cost analysis cannot see inside the custom call: count
+            # the three matmuls per grid step from the block shapes, and
+            # the x tile + one block's tables in, one margin block out
+            cost_estimate=pl.CostEstimate(
+                flops=2 * n_pad * b * (gip * fp + glp * gip + gp * glp),
+                transcendentals=0,
+                bytes_accessed=(n_pad // TILE_N) * b * (
+                    4 * fp * TILE_N + 4 * gip * (fp + 1)
+                    + 2 * glp * gip + 4 * glp + 4 * gp * glp
+                    + 4 * gp * TILE_N)),
             interpret=interpret,
-        )(xp, *tables)
-        return forest_mod.sequential_tree_sum(per_tree[:, :n_trees])[:n]
-
-    return predict
-
-
-def make_gemm_pallas_predictor(gf, interpret: bool | None = None):
-    """fn(x) -> scores for a GemmForest, running the pallas kernel.
-
-    Raises ValueError for forests the kernel does not cover (missing-value
-    routing); callers fall back to the jnp GEMM path.
-    """
-    if gf.dleft is not None:
-        raise ValueError("pallas forest kernel does not implement default_left routing")
-    if interpret is None:
-        try:
-            interpret = jax.default_backend() != "tpu"
-        except Exception as e:  # noqa: BLE001
-            from variantcalling_tpu.utils import degrade
-
-            degrade.record("forest_pallas.backend_probe", e,
-                           fallback="interpret=True")
-            interpret = True
-    tables = (
-        jnp.asarray(gf.a),
-        jnp.asarray(gf.thr),
-        jnp.asarray(gf.m2),
-        jnp.asarray(gf.c),
-        jnp.asarray(gf.plen),
-        jnp.asarray(gf.value),
-    )
-    n_trees = gf.m2.shape[0]
-    agg, base = gf.aggregation, gf.base_score
-
-    def predict(x):
-        n = x.shape[0]
-        if n == 0:  # a zero-size grid cannot dispatch
-            return jnp.zeros((0,), jnp.float32)
-        pad = (-n) % TILE_N
-        xp = jnp.pad(x.astype(jnp.float32), ((0, pad), (0, 0)))
-        total = _margin_pallas(tables, xp, interpret)[:n]
-        if agg == "mean":
-            return total / n_trees
-        if agg == "logit_sum":
-            return jax.nn.sigmoid(total + base)
-        raise ValueError(f"unknown aggregation {agg!r}")
+            name="forest_wide_block",
+        )(xt, *(jnp.asarray(t) for t in tables))
+        # (B, Gp, Np) -> (N, T): drop sublane padding, padded trees and
+        # padded variants before the shared canonical-order reduction
+        per_tree = per_tree[:, :g, :n].reshape(b * g, n)[:n_trees].T
+        return forest_mod.sequential_tree_sum(per_tree)
 
     return predict

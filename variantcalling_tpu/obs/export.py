@@ -529,10 +529,13 @@ def render_bottleneck(b: dict) -> str:
                      "VCTPU_OBS=1 + profiling for wait attribution)")
     ca = b.get("cost_analysis")
     if ca and ca.get("flops_per_variant"):
-        lines.append(f"scoring program ({ca.get('strategy')}): "
-                     f"{ca['flops_per_variant']:.0f} FLOP/variant measured by "
-                     f"XLA cost_analysis; v5e roofline "
-                     f"{ca.get('roofline_vps_v5e', 0)} v/s")
+        line = (f"scoring program ({ca.get('strategy')}): "
+                f"{ca['flops_per_variant']:.0f} FLOP/variant counted by "
+                "XLA cost_analysis")
+        if ca.get("roofline_vps"):
+            line += (f"; {ca['device_kind']} compute roofline "
+                     f"{ca['roofline_vps']} v/s")
+        lines.append(line)
     res = b.get("resources")
     if res:
         lines.append(f"watermarks: rss {res.get('rss_peak_mb')} MB peak, "

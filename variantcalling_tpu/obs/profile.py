@@ -300,9 +300,26 @@ class ResourceSampler(threading.Thread):
 # runtime MFU / roofline attribution (XLA cost_analysis)
 # ---------------------------------------------------------------------------
 
-#: v5e peak bf16 throughput — the MFU denominator bench.py uses; kept in
-#: one place so the run-time and bench-time numbers cannot disagree
-TPU_PEAK_FLOPS = 197e12
+#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind`` —
+#: the ONE table every utilization/roofline figure divides by (bench.py
+#: reads it too). A device that is not listed gets no such figure: a
+#: number derived from another chip's peak is not a measurement of this
+#: one. Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 16 GB HBM at 819 GB/s per chip; the chip reports itself as
+#: "TPU v5 lite".
+DEVICE_PEAKS: dict[str, dict[str, float]] = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_peaks() -> dict | None:
+    """``{"device_kind", "flops_bf16", "hbm_bytes_per_s"}`` for the device
+    this process runs on, or None when it has no published entry."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    peaks = DEVICE_PEAKS.get(kind)
+    return dict(peaks, device_kind=kind) if peaks else None
 
 
 def xla_cost_analysis(jitted, *args) -> dict | None:
@@ -358,7 +375,10 @@ def record_scoring_cost(strategy: str, jitted, args, n_variants: int) -> None:
     if n_variants > 0 and cost["flops"] > 0:
         fpv = cost["flops"] / n_variants
         fields["flops_per_variant"] = round(fpv, 1)
-        # the v5e roofline this program could reach at 100% MXU duty —
-        # docs/perf_notes.md divides measured v/s by this for run MFU
-        fields["roofline_vps_v5e"] = round(TPU_PEAK_FLOPS / fpv)
+        peaks = device_peaks()
+        if peaks:
+            # the compute roofline this program could reach at 100% MXU
+            # duty on THIS device (no field on a device without peaks)
+            fields["device_kind"] = peaks["device_kind"]
+            fields["roofline_vps"] = round(peaks["flops_bf16"] / fpv)
     obs.event("profile", "cost_analysis", **fields)

@@ -51,14 +51,7 @@ def depth_histogram(depth: jnp.ndarray, mask: jnp.ndarray | None = None,
     keeps histogramming at matmul rate), or None to pick by backend.
     """
     if method is None:
-        try:
-            method = "bincount" if jax.default_backend() == "cpu" else "matmul"
-        except Exception as e:  # noqa: BLE001 — backend probe must not break tracing
-            from variantcalling_tpu.utils import degrade
-
-            degrade.record("coverage.backend_probe", e,
-                           fallback='method="bincount"')
-            method = "bincount"
+        method = "bincount" if jax.default_backend() == "cpu" else "matmul"
     clipped = jnp.clip(depth, 0, max_depth)
     n_bins = max_depth + 1
     if mask is not None:

@@ -20,7 +20,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from variantcalling_tpu.parallel.mesh import DATA_AXIS, pad_to_multiple
@@ -36,19 +35,12 @@ def halo_exchange_1d(block: jnp.ndarray, halo_left: int, halo_right: int,
     delivers zeros to devices with no source, so non-zero fills overwrite
     by shard index.
 
-    ``n_shards`` must be the STATIC mesh-axis size (the ppermute
-    permutation is a Python list, not a traced value). Callers that know
-    their mesh pass it explicitly — ``jax.lax.axis_size`` only exists on
-    newer jax releases (0.4.37 lacks it), and a ``psum(1)`` substitute
-    would be traced, so the explicit parameter is the portable spelling.
+    ``n_shards`` is the STATIC mesh-axis size (the ppermute permutation
+    is a Python list, not a traced value); None reads it from the
+    enclosing ``shard_map`` with ``jax.lax.axis_size``.
     """
     if n_shards is None:
-        axis_size = getattr(jax.lax, "axis_size", None)
-        if axis_size is None:
-            raise TypeError(
-                "halo_exchange_1d needs n_shards= on this jax version "
-                "(jax.lax.axis_size is unavailable); pass the mesh axis size")
-        n_shards = axis_size(axis_name)
+        n_shards = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     parts = [block]
     if halo_left:
@@ -105,8 +97,8 @@ def sharded_run_lengths(codes: np.ndarray, mesh: Mesh, halo: int = 256,
         lengths = rops.run_lengths(ext)[1:-halo] if halo else rops.run_lengths(ext)[1:]
         return starts, lengths
 
-    fn = shard_map(body, mesh=mesh, in_specs=P(DATA_AXIS),
-                   out_specs=(P(DATA_AXIS), P(DATA_AXIS)))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P(DATA_AXIS),
+                       out_specs=(P(DATA_AXIS), P(DATA_AXIS)))
     with mesh:
         starts, lengths = jax.jit(fn)(jnp.asarray(padded))
     starts = np.asarray(starts)[:n]

@@ -131,13 +131,7 @@ def resolve_plan(engine_name: str) -> MeshPlan:
                 "(XLA_FLAGS=--xla_force_host_platform_device_count=N). "
                 "See docs/streaming_executor.md 'Mesh-sharded scoring'.")
         return MeshPlan(req, requested, "explicitly requested")
-    try:
-        backend = jax.default_backend()
-    except Exception as e:  # backend init failure: single device, recorded
-        from variantcalling_tpu.utils import degrade
-
-        degrade.record("shard_score.backend_probe", e, fallback="devices=1")
-        return MeshPlan(1, requested, "auto: backend probe failed")
+    backend = jax.default_backend()
     if backend == "cpu":
         return MeshPlan(1, requested,
                         "auto: cpu backend scores single-device "
@@ -178,14 +172,13 @@ def shard_program(fn, mesh, n_data_args: int, replicated_leading: int = 0):
     per-tree margins reduce inside each device's program through the one
     sanctioned ``forest.sequential_tree_sum`` (vctpu-lint VCT009 flags
     any cross-device margin reduction introduced here later)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from variantcalling_tpu.parallel.mesh import DATA_AXIS
 
     dp = P(DATA_AXIS)
     in_specs = tuple([P()] * replicated_leading + [dp] * n_data_args)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=dp)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=dp)
 
 
 def resolve_megabatch_rows(devices: int) -> int:

@@ -50,8 +50,10 @@ def get_parser() -> argparse.ArgumentParser:
                     help="write the shutdown report JSON at exit")
     ap.add_argument("--obs-log", default=None,
                     help="force an obs run stream at this path")
-    ap.add_argument("--backend", default="cpu", choices=["tpu", "cpu"],
-                    help="execution backend (serve pins it at startup)")
+    ap.add_argument("--backend", default=None, choices=["tpu", "cpu"],
+                    help="cpu pins JAX to the CPU platform; tpu requires "
+                         "a TPU or exits 2; default: whatever JAX "
+                         "initializes (serve resolves it once at startup)")
     ap.add_argument("--fabric", action="store_true",
                     help="run the fabric ROUTER tier: the scatter-gather "
                          "front door over the backends named by "
@@ -106,10 +108,13 @@ def run(argv: list[str]) -> int:
                                   if a.strip()]
                         if args.backends is not None else None)
     else:
-        import jax
+        from variantcalling_tpu import engine
 
-        if args.backend == "cpu":
-            jax.config.update("jax_platforms", "cpu")
+        try:
+            engine.pin_backend(args.backend)
+        except EngineError as e:
+            logger.error("%s", e)
+            return 2
         if args.fabric_backend:
             from variantcalling_tpu.serve.backend import Backend as _Cls
         else:

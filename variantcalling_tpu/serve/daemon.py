@@ -88,7 +88,7 @@ def _filter_namespace(body: dict, output_file: str | None) -> argparse.Namespace
         flow_order=body.get("flow_order", "TGCA"),
         is_mutect=bool(body.get("is_mutect")),
         annotate_intervals=list(body.get("annotate_intervals") or []),
-        limit_to_contig=body.get("limit_to_contig"), backend="cpu",
+        limit_to_contig=body.get("limit_to_contig"),
     )
     return ns
 
