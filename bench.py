@@ -2111,8 +2111,6 @@ def _phase_attribution(log_path: str) -> dict | None:
                               "other_pct") if k in s} | (
                                   {"vps": s["vps"]} if "vps" in s else {})
                       for name, s in b["stages"].items()}}
-    if "cost_analysis" in b:
-        out["cost_analysis"] = b["cost_analysis"]
     if "resources" in b:
         out["resources"] = b["resources"]
     return out

@@ -217,11 +217,12 @@ def test_schema_validator_rejects_drift():
 # ---------------------------------------------------------------------------
 
 
-def test_trace_depth_is_per_thread_regression():
+def test_trace_depth_is_per_thread_regression(tmp_path):
     """Spans recorded from a worker thread while the main thread is
     nested must NOT inherit the main thread's depth (the old process-
-    global ``_depth`` interleaved and corrupted both)."""
-    trace.TRACER.clear()
+    global ``_depth`` interleaved and corrupted both). The table they
+    land in is the RUN's (``trace.spans()``), not a process global."""
+    run, _ = _open_run(tmp_path)
     start = threading.Barrier(2, timeout=30)
     mid = threading.Barrier(2, timeout=30)
 
@@ -238,20 +239,24 @@ def test_trace_depth_is_per_thread_regression():
         mid.wait()
     t.join(timeout=30)
     assert not t.is_alive()
-    spans = {s.name: s for s in trace.TRACER.spans}
+    spans = {s.name: s for s in trace.spans()}
     assert spans["m-outer"].depth == 0
     # old code: w-outer closed at depth >= 1 (main held the shared depth)
     assert spans["w-outer"].depth == 0
     assert spans["w-inner"].depth == 1
     assert spans["w-inner"].thread == "obs-test-worker"
     assert spans["m-outer"].thread == "MainThread"
+    assert spans["w-inner"].parent == "w-outer"
+    assert spans["w-outer"].parent is None  # never the main thread's span
     rep = trace.report()
     assert "[thread obs-test-worker]" in rep
-    trace.TRACER.clear()
+    obs.end_run(run, "ok")
+    # the table went with the run: nothing outlives it
+    assert trace.spans() == [] and trace.report() == "stage timings:"
 
 
-def test_trace_many_threads_never_negative_depth():
-    trace.TRACER.clear()
+def test_trace_many_threads_never_negative_depth(tmp_path):
+    run, _ = _open_run(tmp_path)
 
     def churn():
         for _ in range(50):
@@ -264,10 +269,11 @@ def test_trace_many_threads_never_negative_depth():
         t.start()
     for t in ts:
         t.join()
-    assert len(trace.TRACER.spans) == 6 * 50 * 2
-    assert all(s.depth in (0, 1) for s in trace.TRACER.spans)
-    assert all(s.seconds >= 0 for s in trace.TRACER.spans)
-    trace.TRACER.clear()
+    spans = trace.spans()
+    obs.end_run(run, "ok")
+    assert len(spans) == 6 * 50 * 2
+    assert all(s.depth in (0, 1) for s in spans)
+    assert all(s.seconds >= 0 for s in spans)
 
 
 # ---------------------------------------------------------------------------
@@ -384,8 +390,7 @@ def test_filter_output_byte_identical_with_obs(stream_world, tmp_path,
             monkeypatch.delenv("VCTPU_THREADS", raising=False)
         monkeypatch.setenv("VCTPU_OBS", "1" if obs_on else "0")
         # the acceptance criterion covers obs v2: byte parity holds with
-        # the attribution profiler ON (per-stage stats, sampler, runtime
-        # cost_analysis on the jit engine)
+        # the attribution profiler ON (per-stage stats, sampler)
         monkeypatch.setenv("VCTPU_OBS_PROFILE", "1")
         try:
             rc = fvp_run([
@@ -412,14 +417,12 @@ def test_filter_output_byte_identical_with_obs(stream_world, tmp_path,
     values = {e["name"]: e["value"] for e in resolves}
     assert values.get("engine", engine) == engine
     # obs v2: profiling was enabled, so the attribution landed too —
-    # per-stage profile events on the streaming executor, the resource
-    # watermark on every run, and compiler-measured FLOPs on jit runs
+    # per-stage profile events on the streaming executor and the
+    # resource watermark on every run
     profile_names = {e["name"] for e in events if e["kind"] == "profile"}
     assert "resources" in profile_names
     if threads is None:  # streaming: the executor fed the profiler
         assert {"stage", "pipeline"} <= profile_names
-    if engine == "jit":
-        assert "cost_analysis" in profile_names
 
 
 # ---------------------------------------------------------------------------

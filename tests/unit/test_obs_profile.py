@@ -1,7 +1,7 @@
 """obs v2 (ISSUE 6 tentpole): performance-attribution profiler —
 fixed-bucket histogram percentiles vs numpy, per-stage work/wait
 attribution on a real streaming run, the `vctpu obs bottleneck` roll-up,
-runtime cost_analysis, the resource-watermark sampler, multi-rank log
+the resource-watermark sampler, multi-rank log
 merging, the atexit/SIGTERM flush, and the `vctpu obs diff` sentry."""
 
 from __future__ import annotations
@@ -390,30 +390,8 @@ def test_serial_pipeline_also_profiles(stream_world, tmp_path, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# runtime cost_analysis (measured MFU attribution)
+# the published-peaks table
 # ---------------------------------------------------------------------------
-
-
-def test_record_scoring_cost_emits_once_per_run(tmp_path):
-    import jax
-    import jax.numpy as jnp
-
-    run, path = _open_run(tmp_path)
-    fn = jax.jit(lambda x: (x @ x.T).sum())
-    x = jnp.ones((256, 32), dtype=jnp.float32)
-    profile_mod.record_scoring_cost("wide", fn, (x,), 256)
-    profile_mod.record_scoring_cost("wide", fn, (x,), 256)  # deduped
-    obs.end_run(run, "ok")
-    ca = [e for e in _events(path)
-          if e["kind"] == "profile" and e["name"] == "cost_analysis"]
-    assert len(ca) == 1
-    assert ca[0]["strategy"] == "wide"
-    assert ca[0]["flops"] > 0
-    assert ca[0]["flops_per_variant"] == pytest.approx(
-        ca[0]["flops"] / 256, rel=0.01)
-    # the CPU this test runs on is not in the published-peaks table: no
-    # roofline figure may be derived for it from another chip's peak
-    assert "roofline_vps" not in ca[0] and "device_kind" not in ca[0]
 
 
 def test_device_peaks_table_is_keyed_by_device_kind(monkeypatch):
@@ -429,34 +407,6 @@ def test_device_peaks_table_is_keyed_by_device_kind(monkeypatch):
     peaks = profile_mod.device_peaks()
     assert peaks == {"device_kind": "TPU v5 lite", "flops_bf16": 197e12,
                      "hbm_bytes_per_s": 819e9}
-
-
-def test_jit_streaming_run_records_cost_analysis(stream_world, tmp_path,
-                                                 monkeypatch):
-    """The filter pipeline's fused program reports compiler-measured
-    FLOPs per strategy when the jit engine scores."""
-    from variantcalling_tpu import engine as engine_mod
-    from variantcalling_tpu.pipelines.filter_variants import run_streaming
-
-    w = stream_world
-    if not pytest.importorskip("variantcalling_tpu.native").available():
-        pytest.skip("streaming (chunked ingest) needs the native engine")
-    saved = engine_mod._RESOLVED
-    engine_mod.reset_for_tests()
-    monkeypatch.setenv("VCTPU_ENGINE", "jit")
-    run, path = _open_run(tmp_path, name="jit.jsonl")
-    try:
-        out = str(tmp_path / "out_jit.vcf")
-        stats = run_streaming(_stream_args(w, out), w["model"], w["fasta"],
-                              {}, None)
-    finally:
-        engine_mod._RESOLVED = saved
-    assert stats is not None
-    obs.end_run(run, "ok")
-    ca = [e for e in _events(path)
-          if e["kind"] == "profile" and e["name"] == "cost_analysis"]
-    assert len(ca) == 1  # once per run, NOT once per chunk
-    assert ca[0]["flops"] > 0 and ca[0]["strategy"] != "native-cpp"
 
 
 def test_jaxprof_hook_captures_device_trace(tmp_path, monkeypatch):

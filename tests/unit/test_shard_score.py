@@ -245,9 +245,16 @@ def test_serial_io_mesh_layout_attribution_not_double_counted(mesh_world,
               if ln.strip()]
     b = export_mod.bottleneck(events)
     stages = b["stages"]
-    # the score.dN family merged at device capacity
-    assert stages["score"]["devices"] == 2
-    assert stages["score"]["work_s"] > 0
+    # the dispatch wall is ONE row (score_stage, the dispatching thread's
+    # trace.stage span); the score.dN family, merged at device capacity,
+    # and the dispatch's own parts are listed under it, never beside it
+    assert "score" not in stages and "dispatch_wait" not in stages
+    parts = stages["score_stage"]["children"]
+    assert parts["score"]["devices"] == 2
+    assert parts["score"]["work_s"] > 0
+    assert parts["score"]["work_s"] == pytest.approx(
+        2 * stages["score_stage"]["work_s"], rel=1e-3)
+    assert {"dispatch_feed", "dispatch_enqueue", "dispatch_wait"} <= set(parts)
     # ingest carries the reader's own parse work plus feed QUEUE-WAIT on
     # the scoring chain — wait_in (and its per-item count) only exist on
     # the pooled-source rule, so these are the regression tripwires: the

@@ -161,6 +161,20 @@ def main() -> int:
                   wait_in_s=0.1, wait_out_s=0.0, items=1, records=128)
         obs.event("profile", "pipeline", wall_s=0.6, records=128,
                   stages=["score_stage"], bytes_in=1024, bytes_out=2048)
+        # the span primitive's full record: a stage inside a stage under
+        # a chunk's trace scope with a StageProfiler bound — explicit
+        # start, parent, trace_id, and the attribution row with its parent
+        from variantcalling_tpu.obs import profile as profile_mod
+
+        prof = profile_mod.StageProfiler()
+        with obs.bind_profiler(prof), obs.trace_scope("t999"):
+            with trace.stage("score_stage", records=128):
+                with trace.stage("dispatch_wait"):
+                    pass
+        prof.emit(wall_s=0.01, records=128)
+        # one backend compile seen by the jax.monitoring listener
+        obs._on_jax_duration(obs.JAX_BACKEND_COMPILE_EVENT, 0.25,
+                             fun_name="schema_check_probe")
         # causal-tracing producers (the live-telemetry plane): one chunk
         # DAG — ingest root, a fan-in score dispatch, the sequenced
         # commit — plus a recovery event carrying the trace linkage and
@@ -224,6 +238,25 @@ def main() -> int:
                          "run_end"):
             if required not in kinds:
                 errors.append(f"stream is missing a {required!r} event")
+        # the primitive's span: start/parent/trace_id on the event, the
+        # parent on the attribution row, the compile event's fields
+        inner = [e for e in parsed if e["kind"] == "span"
+                 and e["name"] == "dispatch_wait"]
+        if not inner or inner[0].get("parent") != "score_stage" \
+                or inner[0].get("trace_id") != "t999" \
+                or "start" not in inner[0]:
+            errors.append("the nested trace.stage span lacks start / "
+                          f"parent / trace_id: {inner}")
+        rows = {e.get("stage"): e for e in parsed if e["kind"] == "profile"
+                and e["name"] == "stage"}
+        if rows.get("dispatch_wait", {}).get("parent") != "score_stage":
+            errors.append("the child span's profile/stage row names no "
+                          "parent")
+        compiles = [e for e in parsed if e["kind"] == "profile"
+                    and e["name"] == "backend_compile"]
+        if not compiles or not {"span", "thread", "dur"} <= set(compiles[0]):
+            errors.append("no profile/backend_compile event with span / "
+                          "thread / dur")
         # causal-trace integrity: the recovery event's trace_id must
         # resolve to emitted trace spans, the fan-in span must list its
         # member trace and parent, and the rolling-window quantiles must

@@ -26,6 +26,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from variantcalling_tpu import knobs
+from variantcalling_tpu.utils.trace import stage
 
 MISSING = "."
 
@@ -1389,12 +1390,11 @@ class VcfChunkReader:
         buf_np, lazy_buf = raw
         if self.profiler is None:
             return self._parse_chunk(buf_np, lazy_buf)
-        t0 = time.perf_counter()  # vctpu-lint: disable=VCT006 — obs per-worker attribution
-        table = self._parse_chunk(buf_np, lazy_buf)
-        worker = threading.current_thread().name.rsplit("-", 1)[-1]
-        self.profiler.stage(f"parse.{worker}").add_work(
-            time.perf_counter() - t0,  # vctpu-lint: disable=VCT006 — obs per-worker attribution
-            bytes_in=len(buf_np), records=len(table))
+        # the pooled worker's ``parse.w<idx>`` row (the run's profiler is
+        # this reader's), and the span on the profiler trace's clock
+        with stage("parse", bytes_in=len(buf_np)) as sp:
+            table = self._parse_chunk(buf_np, lazy_buf)
+            sp.set(records=len(table))
         return table
 
     def _raw_mm(self):

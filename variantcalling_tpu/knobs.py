@@ -292,7 +292,7 @@ REGISTRY: dict[str, Knob] = {k.name: k for k in (
        "obs run-log path override; default <output_file>.obs.jsonl"),
     _k("VCTPU_OBS_PROFILE", "bool", True,
        "obs v2 attribution when VCTPU_OBS=1: per-stage work/wait "
-       "profile, RSS/CPU watermark sampler, runtime cost_analysis "
+       "profile, RSS/CPU watermark sampler "
        "(docs/observability.md)"),
     _k("VCTPU_OBS_SAMPLE_S", "float", 0.05,
        "resource-watermark sampler interval in seconds", minimum=0.001),

@@ -35,8 +35,8 @@ Knobs: ``VCTPU_OBS=1`` enables recording; ``VCTPU_OBS_PATH`` overrides
 the sidecar path (default: ``<output_file>.obs.jsonl`` next to the
 pipeline output); ``VCTPU_OBS_PROFILE`` (default on) adds the obs v2
 performance-attribution layer (:mod:`~variantcalling_tpu.obs.profile`:
-per-stage work/wait attribution, RSS/CPU watermark sampler, runtime
-cost_analysis); ``VCTPU_OBS_CPUPROF=1`` starts the obs v3 continuous
+per-stage work/wait attribution, RSS/CPU watermark sampler);
+``VCTPU_OBS_CPUPROF=1`` starts the obs v3 continuous
 CPU sampling profiler (:mod:`~variantcalling_tpu.obs.sampler`:
 whole-process stack samples + per-thread CPU clocks folded into a
 ``sample`` event stream at ``VCTPU_OBS_CPUPROF_HZ`` — ``vctpu obs
@@ -70,11 +70,13 @@ stream as ``in-flight``).
 from __future__ import annotations
 
 import atexit
+import collections
 import itertools
 import json
 import os
 import re
 import signal
+import sys
 import threading
 import time
 
@@ -90,6 +92,9 @@ SNAPSHOT_ENV = "VCTPU_OBS_SNAPSHOT_S"
 WINDOW_ENV = "VCTPU_OBS_WINDOW_S"
 MAX_MB_ENV = "VCTPU_OBS_MAX_MB"
 PROM_FILE_ENV = "VCTPU_OBS_PROM_FILE"
+
+#: the most ``trace.stage`` spans a run keeps for ``trace.report()``
+SPAN_TABLE_MAX = 8192
 
 #: flush the stream every this many events (plus manifest and run end) —
 #: a crash loses at most one flush window, without per-event fsync cost
@@ -136,9 +141,14 @@ class ObsRun:
         #: (``VCTPU_OBS_CPUPROF``, obs/sampler.py), owned the same way
         self.cpu_sampler = None
         self.jaxprof_dir: str | None = None
-        #: (strategy, kind) pairs whose cost_analysis already emitted —
-        #: the per-chunk scoring loop must pay the lower+compile ONCE
-        self.cost_recorded: set = set()
+        #: the StageProfiler of the pipeline run in flight (bound by
+        #: :func:`bind_profiler`): a ``trace.stage`` site deep in the
+        #: dispatch finds its attribution row here without a parameter
+        self.profiler = None
+        #: the run's closed ``trace.stage`` spans in close order
+        #: (``trace.report()``); bounded, so a daemon-long run keeps the
+        #: newest and never grows
+        self.spans: collections.deque = collections.deque(maxlen=SPAN_TABLE_MAX)
         #: causal-tracing state (docs/observability.md "Causal chunk
         #: tracing"): run-scoped id counters plus the per-trace cursor —
         #: trace id -> last span id, so the next stage span of a chunk
@@ -173,13 +183,18 @@ class ObsRun:
         self._t0_wall = time.time()
         self._t0_mono = time.perf_counter()
 
+    def now(self) -> float:
+        """The stream's ``t`` clock: seconds since the run opened (a span's
+        explicit ``start`` is this, taken outside the lock)."""
+        return time.perf_counter() - self._t0_mono
+
     def _emit(self, kind: str, name: str, fields: dict, flush: bool = False) -> None:
         pid = os.getpid()
         tid = threading.get_ident()
         flushed = False
         with self._lock:
             # timestamped INSIDE the lock: file order == seq order == ts order
-            t = time.perf_counter() - self._t0_mono
+            t = self.now()
             event = dict(fields)  # extras first; the envelope wins on collision
             event.update(v=SCHEMA_VERSION, seq=self._seq,
                          ts=round(self._t0_wall + t, 6), t=round(t, 6),
@@ -269,7 +284,7 @@ class ObsRun:
     def close(self, status: str) -> None:
         self._closing = True  # run_end must be the stream's last event
         with self._lock:
-            dur = time.perf_counter() - self._t0_mono
+            dur = self.now()
         snap = self.metrics.snapshot()
         self._emit("metrics", "final", snap)
         self._emit("run_end", self.tool, {"status": status,
@@ -326,6 +341,11 @@ def start_run(tool: str, default_path: str | None = None,
         _ACTIVE = True
         _TRACING = run.tracing
         _register_flush_handlers()
+        if _register_jax_listener():
+            # declared up front: a snapshot that reads 0 says "JAX compiled
+            # nothing in this run", an absent counter "nobody was counting"
+            for name in JAX_COUNTERS:
+                run.metrics.counter(name)
         if knobs.get_bool(profile_mod().PROFILE_ENV):
             # RSS/CPU watermark sampler (obs v2): daemon thread, stopped
             # (and its watermark event emitted) by end_run
@@ -427,6 +447,70 @@ def _stop_jaxprof(run: ObsRun) -> None:
                        "incomplete")
 
 
+# -- JAX's own compile / cache / trace durations ----------------------------
+#
+# ONE ``jax.monitoring`` duration listener for the life of the process,
+# registered by the first ``start_run`` that finds jax imported (obs never
+# imports it: a launcher that must stay off the chip opens runs too). It
+# checks ``_ACTIVE`` first, so outside a run it costs one bool check per
+# JAX event. JAX 0.9.0 times ``compile_or_get_cached`` as a whole under
+# the backend-compile event, so a persistent-cache hit fires BOTH events
+# on the compiling thread, retrieval first: the thread-local flag tells a
+# load from a compile. A compile is counted whether or not the cache keeps
+# it (the cache's own miss event skips compiles under
+# ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS``).
+
+JAX_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+JAX_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+JAX_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+JAX_COUNTERS = ("jax.backend_compiles", "jax.backend_compile_s",
+                "jax.cache_loads", "jax.cache_load_s", "jax.trace_s")
+
+_JAX_LISTENER_REGISTERED = False
+_JAX_TLS = threading.local()
+
+
+def _on_jax_duration(event: str, duration: float, **kw) -> None:
+    if not _ACTIVE:
+        return
+    run = _RUN
+    if run is None:
+        return
+    if event == JAX_TRACE_EVENT:
+        run.metrics.counter("jax.trace_s").add(duration)
+    elif event == JAX_CACHE_RETRIEVAL_EVENT:
+        _JAX_TLS.loaded = True  # vctpu-lint: disable=VCT010 — threading.local IS a per-thread cell (the obs/metrics pattern); no cross-thread visibility exists
+        run.metrics.counter("jax.cache_loads").add(1)
+        run.metrics.counter("jax.cache_load_s").add(duration)
+    elif event == JAX_BACKEND_COMPILE_EVENT:
+        if getattr(_JAX_TLS, "loaded", False):
+            _JAX_TLS.loaded = False  # vctpu-lint: disable=VCT010 — threading.local IS a per-thread cell (the obs/metrics pattern); no cross-thread visibility exists
+            return
+        run.metrics.counter("jax.backend_compiles").add(1)
+        run.metrics.counter("jax.backend_compile_s").add(duration)
+        # "which step recompiled": the innermost trace.stage open on the
+        # compiling thread
+        from variantcalling_tpu.utils import trace as trace_mod
+
+        run._emit("profile", "backend_compile", {
+            "span": trace_mod.current_span() or "",
+            "thread": threading.current_thread().name,
+            "dur": round(duration, 6),
+            "fun_name": str(kw.get("fun_name", ""))})
+
+
+def _register_jax_listener() -> bool:
+    """Idempotent; retried by later ``start_run``s until jax is there.
+    True once the listener is in place."""
+    global _JAX_LISTENER_REGISTERED
+    jax = sys.modules.get("jax")
+    if not _JAX_LISTENER_REGISTERED and jax is not None:
+        _JAX_LISTENER_REGISTERED = True
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+    return _JAX_LISTENER_REGISTERED
+
+
 # -- abnormal-exit flush (satellite: no silently truncated streams) --------
 
 _ATEXIT_REGISTERED = False
@@ -511,14 +595,17 @@ def event(kind: str, name: str, **fields) -> None:
 
 
 def span(name: str, dur: float, thread: str, depth: int = 0, **fields) -> None:
-    """Record one closed wall-clock span (called by ``utils.trace`` and
-    the stage executor). ``dur`` in seconds."""
+    """Record one closed wall-clock span measured by the caller (the
+    stage executor's generic stages and queue waits; everything else goes
+    through ``utils.trace.stage``). ``dur`` in seconds; ``start`` is the
+    close stamp less ``dur``, taken outside the stream's lock."""
     if not _ACTIVE:
         return
     run = _RUN
     if run is not None:
-        run._emit("span", name, dict(fields, dur=round(dur, 6),
-                                     thread=thread, depth=depth))
+        run._emit("span", name, dict(fields, start=round(max(0.0, run.now() - dur), 6),
+                                     dur=round(dur, 6), thread=thread,
+                                     depth=depth))
 
 
 # -- causal chunk tracing (docs/observability.md "Causal chunk tracing") ---
@@ -662,5 +749,29 @@ def histogram(name: str):
 
 
 def current() -> ObsRun | None:
-    """The open run (tests/manifest introspection)."""
+    """The open run (``trace.stage``, tests, manifest introspection)."""
     return _RUN
+
+
+class bind_profiler:
+    """Context manager: hang a pipeline run's StageProfiler on the open
+    obs run for the pipeline's duration (restores what was bound), so
+    ``trace.stage`` sites find their attribution rows without a
+    parameter. No-op without a run or a profiler."""
+
+    __slots__ = ("prof", "_run", "_prev")
+
+    def __init__(self, prof):
+        self.prof = prof
+
+    def __enter__(self):
+        self._run = _RUN if self.prof is not None else None
+        if self._run is not None:
+            self._prev = self._run.profiler
+            self._run.profiler = self.prof
+        return self.prof
+
+    def __exit__(self, *exc):
+        if self._run is not None:
+            self._run.profiler = self._prev
+        return False

@@ -419,6 +419,8 @@ def bottleneck(events: list[dict]) -> dict:
                 s["_workers"].add(m.group(2) + m.group(3))
                 if m.group(2) == "d":
                     s["_device_family"] = True
+            if e.get("parent"):
+                s["parent"] = e["parent"]
             s["work_s"] += float(e.get("work_s", 0.0))
             s["wait_in_s"] += float(e.get("wait_in_s", 0.0))
             s["wait_out_s"] += float(e.get("wait_out_s", 0.0))
@@ -468,6 +470,20 @@ def bottleneck(events: list[dict]) -> dict:
     def _norm_work(s: dict) -> float:
         return s["work_s"] / s.get("workers", 1)
 
+    # a row with a ``parent`` (a trace.stage span opened inside another:
+    # host_featurize inside score_stage) is a PART of its parent's work:
+    # it is listed under the parent and never ranked beside it, so no
+    # share is counted twice. A part whose parent left no row ranks alone.
+    for name in [n for n, s in stages.items() if s.get("parent") in stages
+                 and s["parent"] != n]:
+        part = stages.pop(name)
+        stages[part.pop("parent")].setdefault("children", {})[name] = part
+    for s in stages.values():
+        s.pop("parent", None)
+        if "children" in s:
+            s["children"] = dict(sorted(s["children"].items(),
+                                        key=lambda kv: -_norm_work(kv[1])))
+
     limiting = max(stages, key=lambda n: _norm_work(stages[n])) \
         if stages else None
     out = {
@@ -480,10 +496,6 @@ def bottleneck(events: list[dict]) -> dict:
         "stages": dict(sorted(stages.items(),
                               key=lambda kv: -_norm_work(kv[1]))),
     }
-    cost = [e for e in events if e.get("kind") == "profile"
-            and e.get("name") == "cost_analysis"]
-    if cost:
-        out["cost_analysis"] = _args_of(cost[-1])
     res = [e for e in events if e.get("kind") == "profile"
            and e.get("name") == "resources"]
     if res:
@@ -524,18 +536,15 @@ def render_bottleneck(b: dict) -> str:
                 f"{s['wait_in_pct']:>8.1f} {s['wait_out_pct']:>9.1f} "
                 f"{s['other_pct']:>6.1f} {s['work_s']:>9.3f} "
                 f"{s.get('vps', '-'):>10}  {' '.join(byt)}")
+            for part, c in s.get("children", {}).items():
+                # the parts of this stage's work, by the spans opened
+                # inside it; what they leave is the stage's own time
+                of = 100.0 * c["work_s"] / s["work_s"] if s["work_s"] else 0.0
+                lines.append(f"    - {part}: {c['work_s']:.3f}s "
+                             f"({of:.1f}% of {name}'s work)")
     if b["source"] == "spans":
         lines.append("(span fallback: work attribution only — rerun with "
                      "VCTPU_OBS=1 + profiling for wait attribution)")
-    ca = b.get("cost_analysis")
-    if ca and ca.get("flops_per_variant"):
-        line = (f"scoring program ({ca.get('strategy')}): "
-                f"{ca['flops_per_variant']:.0f} FLOP/variant counted by "
-                "XLA cost_analysis")
-        if ca.get("roofline_vps"):
-            line += (f"; {ca['device_kind']} compute roofline "
-                     f"{ca['roofline_vps']} v/s")
-        lines.append(line)
     res = b.get("resources")
     if res:
         lines.append(f"watermarks: rss {res.get('rss_peak_mb')} MB peak, "

@@ -22,12 +22,10 @@ this is that layer:
   host-CPU utilization every ``VCTPU_OBS_SAMPLE_S`` seconds into run
   gauges (``proc.rss_mb`` / ``proc.cpu_pct``, peaks kept by the gauge),
   with a final ``profile``/``resources`` watermark event.
-- :func:`xla_cost_analysis` / :func:`record_scoring_cost` — runtime
-  MFU/roofline attribution: FLOPs from the XLA compiler's
-  ``cost_analysis`` on the *compiled* scoring program (replacing
-  bench.py's analytic projection with the compiler's own count),
-  emitted as a ``profile``/``cost_analysis`` event per run with the
-  resolved strategy.
+- :func:`xla_cost_analysis` — FLOPs from the XLA compiler's
+  ``cost_analysis`` on a *compiled* program, for ``bench.py``'s rows. No
+  run calls it: a pipeline run asks the compiler for nothing that an
+  untraced run would not.
 
 Everything here is gated on ``enabled()`` — obs recording must be on
 (``VCTPU_OBS=1``) AND profiling not opted out (``VCTPU_OBS_PROFILE``,
@@ -80,11 +78,15 @@ class StageStats:
     so the fractions still sum to ~100% of wall.
     """
 
-    __slots__ = ("name", "work_s", "wait_in_s", "wait_out_s",
+    __slots__ = ("name", "parent", "work_s", "wait_in_s", "wait_out_s",
                  "items", "records", "bytes_in", "bytes_out")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, parent: str | None = None):
         self.name = name
+        #: the enclosing ``trace.stage`` span's name, for a row that is a
+        #: PART of another row's work (``host_featurize.w3`` inside
+        #: ``score_stage``): a reader that adds rows up skips it
+        self.parent = parent
         self.work_s = 0.0
         self.wait_in_s = 0.0
         self.wait_out_s = 0.0
@@ -117,6 +119,8 @@ class StageStats:
             "wait_out_s": round(self.wait_out_s, 6),
             "items": self.items,
         }
+        if self.parent is not None:
+            out["parent"] = self.parent
         if self.records:
             out["records"] = self.records
             if self.work_s > 0:
@@ -139,11 +143,11 @@ class StageProfiler:
         self._stages: dict[str, StageStats] = {}
         self._lock = threading.Lock()
 
-    def stage(self, name: str) -> StageStats:
+    def stage(self, name: str, parent: str | None = None) -> StageStats:
         s = self._stages.get(name)
         if s is None:
             with self._lock:
-                s = self._stages.setdefault(name, StageStats(name))
+                s = self._stages.setdefault(name, StageStats(name, parent))
         return s
 
     def set_records(self, n: int) -> None:
@@ -155,7 +159,8 @@ class StageProfiler:
         merged family's records (and its reported standalone v/s) k-fold
         in ``vctpu obs bottleneck``."""
         for name, s in self._stages.items():
-            if not s.records and not _WORKER_STAGE_RE.search(name):
+            if not s.records and s.parent is None \
+                    and not _WORKER_STAGE_RE.search(name):
                 s.records = n
 
     def emit(self, wall_s: float, records: int | None = None) -> None:
@@ -345,40 +350,3 @@ def xla_cost_analysis(jitted, *args) -> dict | None:
         degrade.record("obs.cost_analysis", e,
                        fallback="no runtime FLOP attribution for this run")
         return None
-
-
-def record_scoring_cost(strategy: str, jitted, args, n_variants: int) -> None:
-    """Emit the run's ``profile``/``cost_analysis`` event: measured (not
-    projected) FLOPs per variant for the compiled scoring program that
-    actually ran, named by the resolved forest strategy.
-
-    Emitted ONCE per (run, strategy): the streaming executor scores per
-    chunk, and a per-chunk lower+compile would wreck the <2% overhead
-    budget — the first chunk's shapes stand for the run (steady-state
-    chunks share one bucketed shape by design). ``args`` are one chunk's
-    call arguments — shapes only are read.
-    """
-    if not enabled():
-        return
-    run = obs.current()
-    if run is None or (strategy, "cost") in run.cost_recorded:
-        return
-    run.cost_recorded.add((strategy, "cost"))
-    import jax
-
-    shapes = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-    cost = xla_cost_analysis(jitted, *shapes)
-    if cost is None:
-        return
-    fields = dict(cost, strategy=strategy, n=int(n_variants))
-    if n_variants > 0 and cost["flops"] > 0:
-        fpv = cost["flops"] / n_variants
-        fields["flops_per_variant"] = round(fpv, 1)
-        peaks = device_peaks()
-        if peaks:
-            # the compute roofline this program could reach at 100% MXU
-            # duty on THIS device (no field on a device without peaks)
-            fields["device_kind"] = peaks["device_kind"]
-            fields["roofline_vps"] = round(peaks["flops_bf16"] / fpv)
-    obs.event("profile", "cost_analysis", **fields)

@@ -12,7 +12,8 @@ The validator is hand-rolled over that artifact (no jsonschema
 dependency — the container doesn't ship one): type names are the small
 closed set ``int``/``number``/``string``/``object``/``array``/``bool``.
 Unknown kinds and extra fields are allowed (forward compatibility);
-missing/mistyped REQUIRED fields are errors.
+missing/mistyped REQUIRED fields are errors, and so is a mistyped
+``optional`` field where it is present.
 """
 
 from __future__ import annotations
@@ -72,6 +73,9 @@ def validate_event(event: dict) -> list[str]:
             if field not in event:
                 errors.append(f"{kind} event missing field {field!r}")
             elif not _type_ok(event[field], type_name):
+                errors.append(f"{kind} field {field!r} is not a {type_name}")
+        for field, type_name in kind_spec.get("optional", {}).items():
+            if field in event and not _type_ok(event[field], type_name):
                 errors.append(f"{kind} field {field!r} is not a {type_name}")
     return errors
 

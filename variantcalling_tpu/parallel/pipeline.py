@@ -487,7 +487,10 @@ class StagePipeline:
         return self.profiler if obs.profile_mod().enabled() else None
 
     def _record_stage_work(self, name: str, dt: float, seq: int, prof) -> None:
-        """One stage item closed: span + latency histogram + attribution."""
+        """One stage item closed: span + latency histogram + attribution.
+        A stage callable that measures itself through ``trace.stage``
+        (attribute ``self_timed = True``: the filter's score, render and
+        compress stages) is not recorded a second time here."""
         obs.span(name, dt, threading.current_thread().name, chunk=seq)
         obs.histogram(f"stage.{name}.s").observe(dt)
         if prof is not None:
@@ -524,7 +527,7 @@ class StagePipeline:
         recovery ladder sees the same unit in both modes."""
         faults.check("pipeline.stage")
         faults.check("pipeline.stage_hang")
-        if not obs.active():
+        if not obs.active() or getattr(fn, "self_timed", False):
             return fn(item)
         t0 = time.perf_counter()  # vctpu-lint: disable=VCT006 — obs span timing
         out = fn(item)
@@ -651,7 +654,7 @@ class StagePipeline:
             tests/unit/test_streaming_faults.py)."""
             faults.check("pipeline.stage")
             faults.check("pipeline.stage_hang")
-            if not obs.active():
+            if not obs.active() or getattr(fn, "self_timed", False):
                 return fn(item)
             t0 = time.perf_counter()  # vctpu-lint: disable=VCT006 — obs span timing
             out = fn(item)

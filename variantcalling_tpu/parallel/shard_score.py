@@ -260,14 +260,12 @@ def megabatch_stream(prepped, ctx, profiler=None):
     quarantine) passes through as the same marker, after flushing the
     pending group so canonical chunk order is preserved.
     """
-    import threading
-    import time as _time
-
     from variantcalling_tpu.engine import EngineError
     from variantcalling_tpu.parallel.pipeline import (StageTimeoutError,
                                                       record_quarantine,
                                                       retry_chunk)
     from variantcalling_tpu.utils import faults
+    from variantcalling_tpu.utils.trace import stage
 
     devices = ctx.mesh_plan.devices
     state = {"target": resolve_megabatch_rows(devices)}
@@ -277,12 +275,12 @@ def megabatch_stream(prepped, ctx, profiler=None):
         # injection point: the OOM/shrink/degrade ladder is proven
         # against this (tests/unit/test_streaming_faults.py)
         faults.check("xla.dispatch_oom")
-        t0 = _time.perf_counter()  # vctpu-lint: disable=VCT006 — obs score-dispatch attribution
-        scored = ctx.score_packed(group)
-        dt = _time.perf_counter() - t0  # vctpu-lint: disable=VCT006 — obs score-dispatch attribution
-        if obs.active():
-            obs.span("score_stage", dt, threading.current_thread().name)
-            obs.histogram("stage.score_stage.s").observe(dt)
+        # one measurement: the dispatch's span and histogram (trace.stage,
+        # parent of the feed/enqueue/wait spans inside), the fan-in causal
+        # span and the per-device rows below
+        with stage("score_stage", rows=rows, chunks=len(group)) as sp:
+            scored = ctx.score_packed(group)
+        dt = sp.seconds
         if obs.tracing():
             # megabatch FAN-IN: one dispatch span, MANY chunk parents —
             # the event lists every member trace id and parents to each
@@ -302,7 +300,7 @@ def megabatch_stream(prepped, ctx, profiler=None):
                 # lockstep data-parallel shards: each device works the
                 # dispatch wall on its share of the rows; the family
                 # merges to one `score xN` row at N-device capacity
-                profiler.stage(f"score.d{d}").add_work(
+                profiler.stage(f"score.d{d}", parent="score_stage").add_work(
                     dt, records=share + (rows - share * devices
                                          if d == devices - 1 else 0))
         return scored

@@ -27,6 +27,7 @@ from variantcalling_tpu import engine as engine_mod
 from variantcalling_tpu import knobs, logger, obs
 from variantcalling_tpu.engine import EngineError
 from variantcalling_tpu.utils import degrade
+from variantcalling_tpu.utils.trace import note, stage, timed
 from variantcalling_tpu.featurize import host_featurize
 from variantcalling_tpu.io import bed as bedio
 from variantcalling_tpu.io.fasta import FastaReader
@@ -226,6 +227,11 @@ def _is_cg_insertion(table: VariantTable, windows: np.ndarray, center: int) -> n
 # valid for the cache lifetime. Bounded FIFO so a long-lived process scoring
 # many models does not accumulate compiled programs forever.
 _PREDICTOR_CACHE: dict[tuple, tuple[object, object]] = {}
+
+#: ``jax.named_scope`` names of the fused program's three parts
+SCOPE_WINDOW_GATHER = "vctpu_window_gather"
+SCOPE_WINDOW_FEATURES = "vctpu_window_features"
+SCOPE_MODEL = "vctpu_model"
 _PREDICTOR_CACHE_MAX = 8
 
 
@@ -235,7 +241,21 @@ _PREDICTOR_CACHE_MAX = 8
 _PREDICTOR_CACHE_LOCK = threading.Lock()
 
 
+def _cache_get(key: tuple, model):
+    """The cached program for ``key`` if it was built over this very
+    ``model`` object, else None (counter ``predictor.reuses``)."""
+    hit = _PREDICTOR_CACHE.get(key)
+    if hit is not None and hit[0] is model:
+        obs.counter("predictor.reuses").add(1)
+        return hit[1]
+    return None
+
+
 def _cache_put(key: tuple, value: tuple) -> None:
+    """Keep a program just built (counter ``predictor.builds``; the
+    enclosing ``fused_program`` span learns ``built``)."""
+    obs.counter("predictor.builds").add(1)
+    note(built=True)
     with _PREDICTOR_CACHE_LOCK:
         while len(_PREDICTOR_CACHE) >= _PREDICTOR_CACHE_MAX:
             _PREDICTOR_CACHE.pop(next(iter(_PREDICTOR_CACHE)))
@@ -281,9 +301,9 @@ def _raw_predictor(model, feature_names: list[str], strategy: str | None = None)
 def _predictor_for(model, feature_names: list[str], strategy: str | None = None,
                    mesh=None):
     key = ("x", id(model), tuple(feature_names), _strategy_token(strategy), mesh)
-    hit = _PREDICTOR_CACHE.get(key)
-    if hit is not None and hit[0] is model:
-        return hit[1]
+    hit = _cache_get(key, model)
+    if hit is not None:
+        return hit
     program, finalize = _raw_predictor(model, feature_names, strategy=strategy)
     if mesh is not None:
         # data-parallel mesh plan (>1 device): the SAME program body runs
@@ -296,6 +316,14 @@ def _predictor_for(model, feature_names: list[str], strategy: str | None = None,
     pair = (jax.jit(program), finalize)
     _cache_put(key, (model, pair))
     return pair
+
+
+def _host_names(feature_names: list[str]) -> list[str]:
+    """The columns the host sends to the fused program, in its argument
+    order: every feature the device does not compute from the window."""
+    from variantcalling_tpu.featurize import DEVICE_FEATURES
+
+    return [f for f in feature_names if f not in DEVICE_FEATURES]
 
 
 def _fused_program(model, feature_names: list[str], flow_order: str,
@@ -316,14 +344,14 @@ def _fused_program(model, feature_names: list[str], flow_order: str,
     position — windows are gathered on device, so per-run transfer is
     4 bytes a variant instead of the 41-byte window row.
     """
-    from variantcalling_tpu.featurize import (CENTER, DEVICE_FEATURES,
-                                              device_feature_dict, windows_from_packed)
+    from variantcalling_tpu.featurize import (CENTER, device_feature_dict,
+                                              windows_from_packed)
 
     key = ("fused", id(model), tuple(feature_names), flow_order,
            genome_resident, _strategy_token(strategy), mesh)
-    hit = _PREDICTOR_CACHE.get(key)
-    if hit is not None and hit[0] is model:
-        return hit[1]
+    hit = _cache_get(key, model)
+    if hit is not None:
+        return hit
 
     # This is the JIT engine's program: featurize + forest inference fused
     # into one device program (engine contract, docs/robustness.md — the
@@ -332,27 +360,35 @@ def _fused_program(model, feature_names: list[str], flow_order: str,
     # FlatForest programs return margins and `finalize` (shared with the
     # native engine) produces the final score bits on the host.
     predictor, finalize = _raw_predictor(model, feature_names, strategy=strategy)
-    host_names = [f for f in feature_names if f not in DEVICE_FEATURES]
+    host_names = _host_names(feature_names)
     host_idx = {f: i for i, f in enumerate(host_names)}
 
+    # The three parts carry names of their own into the compiled program
+    # (jax.named_scope is metadata: no operation, byte or program moves),
+    # so a device trace groups operations by part whatever their shapes.
     def body(windows, host_cols, is_indel, indel_nuc, ref_code, alt_code, is_snp):
-        dev = device_feature_dict(windows, is_indel.astype(bool),
-                                  indel_nuc.astype(jnp.int32),
-                                  ref_code.astype(jnp.int32),
-                                  alt_code.astype(jnp.int32),
-                                  is_snp.astype(bool),
-                                  center=CENTER, flow_order=flow_order)
-        cols = [
-            dev[f].astype(jnp.float32) if f in dev
-            else host_cols[host_idx[f]].astype(jnp.float32)
-            for f in feature_names
-        ]
-        return predictor(jnp.stack(cols, axis=1))
+        with jax.named_scope(SCOPE_WINDOW_FEATURES):
+            dev = device_feature_dict(windows, is_indel.astype(bool),
+                                      indel_nuc.astype(jnp.int32),
+                                      ref_code.astype(jnp.int32),
+                                      alt_code.astype(jnp.int32),
+                                      is_snp.astype(bool),
+                                      center=CENTER, flow_order=flow_order)
+            cols = [
+                dev[f].astype(jnp.float32) if f in dev
+                else host_cols[host_idx[f]].astype(jnp.float32)
+                for f in feature_names
+            ]
+            x = jnp.stack(cols, axis=1)
+        with jax.named_scope(SCOPE_MODEL):
+            return predictor(x)
 
     if genome_resident:
         def fn(genome_blocks, gpos, host_cols, is_indel, indel_nuc,
                ref_code, alt_code, is_snp):
-            return body(windows_from_packed(genome_blocks, gpos), host_cols,
+            with jax.named_scope(SCOPE_WINDOW_GATHER):
+                windows = windows_from_packed(genome_blocks, gpos)
+            return body(windows, host_cols,
                         is_indel, indel_nuc, ref_code, alt_code, is_snp)
     else:
         fn = body
@@ -543,43 +579,46 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
 
     plan = plan or shard_score.resolve_plan("jit")
     mesh = shard_score.mesh_for(plan)
-    windows = hf.windows
-    genome = gpos_all = None
-    gpos_fill = 0
-    genome_resident = windows is None and table is not None and fasta is not None
-    if genome_resident:
-        from variantcalling_tpu.featurize import (device_genome, gather_windows,
-                                                  genome_packable,
-                                                  globalize_positions,
-                                                  pack_global_positions,
-                                                  packed_position_fill)
+    with stage("prepare_inputs"):
+        windows = hf.windows
+        genome = gpos_all = None
+        gpos_fill = 0
+        genome_resident = windows is None and table is not None and fasta is not None
+        if genome_resident:
+            from variantcalling_tpu.featurize import (device_genome, gather_windows,
+                                                      genome_packable,
+                                                      globalize_positions,
+                                                      pack_global_positions,
+                                                      packed_position_fill)
 
-        if not genome_packable(fasta):
-            # positions won't fit 4-byte packing (> ~4 Gbp incl. gaps):
-            # host window gather, without paying the genome upload
-            genome_resident = False
-            windows = gather_windows(table, fasta)
-        else:
-            # replicate the genome across the run mesh so chunk dispatches
-            # never reshard the multi-GB array (a 1-device plan falls
-            # through to the process-default policy); the helper keeps
-            # the cache key identical across every consumer
-            from variantcalling_tpu.featurize import standard_genome_sharding
-
-            genome = device_genome(
-                fasta, sharding=standard_genome_sharding(mesh))
-            blk_all, off_all = globalize_positions(table, genome)
-            gpos_all = pack_global_positions(blk_all, off_all, genome)
-            if gpos_all is None:  # safety net: packable() and the packer disagree
+            if not genome_packable(fasta):
+                # positions won't fit 4-byte packing (> ~4 Gbp incl. gaps):
+                # host window gather, without paying the genome upload
                 genome_resident = False
                 windows = gather_windows(table, fasta)
             else:
-                gpos_fill = packed_position_fill(genome)
+                # replicate the genome across the run mesh so chunk dispatches
+                # never reshard the multi-GB array (a 1-device plan falls
+                # through to the process-default policy); the helper keeps
+                # the cache key identical across every consumer
+                from variantcalling_tpu.featurize import standard_genome_sharding
 
-    program = _fused_program(model, hf.names, flow_order,
-                             genome_resident=genome_resident,
-                             strategy=strategy, mesh=mesh)
-    host_cols = tuple(_narrow_column(hf.cols[f]) for f in program[1])
+                genome = device_genome(
+                    fasta, sharding=standard_genome_sharding(mesh))
+                blk_all, off_all = globalize_positions(table, genome)
+                gpos_all = pack_global_positions(blk_all, off_all, genome)
+                if gpos_all is None:  # safety net: packable() and the packer disagree
+                    genome_resident = False
+                    windows = gather_windows(table, fasta)
+                else:
+                    gpos_fill = packed_position_fill(genome)
+        host_cols = tuple(_narrow_column(hf.cols[f])
+                          for f in _host_names(hf.names))
+
+    with stage("fused_program", built=False):
+        program = _fused_program(model, hf.names, flow_order,
+                                 genome_resident=genome_resident,
+                                 strategy=strategy, mesh=mesh)
     n = len(table) if table is not None else len(windows)
     return _FusedInputs(n, program, genome, gpos_all, gpos_fill, windows,
                         host_cols, hf.alle, model)
@@ -617,17 +656,18 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     genome_resident = first.gpos is not None
     genome = first.genome
     gpos_fill = first.gpos_fill
-    if genome_resident:
-        gpos_all, windows = cat([i.gpos for i in inputs]), None
-    else:
-        gpos_all, windows = None, cat([i.windows for i in inputs])
-    host_cols = tuple(cat([i.host_cols[k] for i in inputs])
-                      for k in range(len(first.host_cols)))
-    is_indel = cat([i.alle.is_indel for i in inputs])
-    indel_nuc = cat([i.alle.indel_nuc for i in inputs])
-    ref_code = cat([i.alle.ref_code for i in inputs])
-    alt_code = cat([i.alle.alt_code for i in inputs])
-    is_snp = cat([i.alle.is_snp for i in inputs])
+    with stage("dispatch_feed"):
+        if genome_resident:
+            gpos_all, windows = cat([i.gpos for i in inputs]), None
+        else:
+            gpos_all, windows = None, cat([i.windows for i in inputs])
+        host_cols = tuple(cat([i.host_cols[k] for i in inputs])
+                          for k in range(len(first.host_cols)))
+        is_indel = cat([i.alle.is_indel for i in inputs])
+        indel_nuc = cat([i.alle.indel_nuc for i in inputs])
+        ref_code = cat([i.alle.ref_code for i in inputs])
+        alt_code = cat([i.alle.alt_code for i in inputs])
+        is_snp = cat([i.alle.is_snp for i in inputs])
 
     n = sum(i.n for i in inputs)
     out = np.empty(n, dtype=np.float32)
@@ -638,8 +678,12 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     # score bits both engines agree on; DAN/threshold programs return
     # final scores and have no host finalize (finalize is None)
     def finish(res, k):
-        arr = np.asarray(res)[:k]
-        return finalize(arr) if finalize is not None else arr
+        with stage("dispatch_wait"):  # blocked on the device, then D2H
+            arr = np.asarray(res)[:k]
+        if finalize is None:
+            return arr
+        with stage("score_finalize"):
+            return finalize(arr)
 
     for lo in range(0, n, chunk_size):
         hi = min(lo + chunk_size, n)
@@ -659,36 +703,29 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
         # async dispatch overlaps chunk i+1's upload with chunk i's compute;
         # the bounded in-flight window keeps device residency at O(chunk)
         # (plus the resident genome) instead of the whole dataset
-        common = (
-            tuple(prep(c) for c in host_cols),
-            prep(is_indel),
-            prep(indel_nuc, fill=4),
-            prep(ref_code, fill=4),
-            prep(alt_code, fill=4),
-            prep(is_snp),
-        )
-        if genome_resident:
-            # padding positions sit past the genome end -> all-N windows
-            call_args = (genome.blocks, prep(gpos_all, fill=gpos_fill), *common)
-        else:
-            call_args = (prep(windows, fill=4), *common)
-        pending.append((lo, hi, fn(*call_args)))
-        last_call = (call_args, target)
+        with stage("dispatch_feed", rows=target):
+            common = (
+                tuple(prep(c) for c in host_cols),
+                prep(is_indel),
+                prep(indel_nuc, fill=4),
+                prep(ref_code, fill=4),
+                prep(alt_code, fill=4),
+                prep(is_snp),
+            )
+            if genome_resident:
+                # padding positions sit past the genome end -> all-N windows
+                call_args = (genome.blocks, prep(gpos_all, fill=gpos_fill), *common)
+            else:
+                call_args = (prep(windows, fill=4), *common)
+        # the enqueue; on a first call also trace + lower + cache load or compile
+        with stage("dispatch_enqueue", rows=target):
+            res = fn(*call_args)
+        pending.append((lo, hi, res))
         while len(pending) > 2:
             plo, phi, res = pending.pop(0)
             out[plo:phi] = finish(res, phi - plo)
     for lo, hi, res in pending:
         out[lo:hi] = finish(res, hi - lo)
-    if n and obs.active() and isinstance(first.model, FlatForest):
-        # runtime MFU/roofline attribution (obs v2): the XLA compiler's
-        # own FLOP count for the compiled fused program that scored this
-        # run, per resolved strategy — replaces bench.py's analytic
-        # projection with a measurement. Post-loop so the lower+compile
-        # walk never sits in the chunk cadence; shapes only are read.
-        from variantcalling_tpu.obs import profile as profile_mod
-
-        profile_mod.record_scoring_cost(
-            forest_mod.last_strategy, fn, last_call[0], last_call[1])
     return out
 
 
@@ -766,8 +803,9 @@ def score_variants(model, x: np.ndarray, feature_names: list[str],
 
     plan = plan or shard_score.resolve_plan(eng.name)
     mesh = shard_score.mesh_for(plan)
-    fn, finalize = _predictor_for(model, feature_names, strategy=strategy,
-                                  mesh=mesh)
+    with stage("fused_program", built=False):
+        fn, finalize = _predictor_for(model, feature_names, strategy=strategy,
+                                      mesh=mesh)
     n_dev = plan.devices
     sharding = data_sharding(mesh, 2) if mesh is not None else None
     chunk_size = max(CHUNK, n_dev) - (CHUNK % n_dev if n_dev > 1 else 0)
@@ -963,6 +1001,7 @@ class FilterContext:
         return self.forest_strategy \
             if self.forest_strategy in forest_mod.FOREST_STRATEGIES else None
 
+    @timed(name="host_featurize")
     def host_features(self, table: VariantTable):
         """Host featurization for one table/chunk — the CPU half of
         scoring, shared by :meth:`score_table` and the mesh megabatch
@@ -1067,6 +1106,7 @@ class FilterContext:
         flush_run()
         return out
 
+    @timed(name="score_finalize")
     def assemble_filters(self, table: VariantTable, score: np.ndarray,
                          hf) -> FactorizedColumn:
         """FILTER assembly from a table's scores — row-local, shared by
@@ -1373,6 +1413,25 @@ def run_streaming(args, model, fasta: FastaReader, annotate, blacklist,
 def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                         engine: engine_mod.EngineDecision | None = None,
                         mesh_plan=None, rank_plan=None) -> dict:
+    # obs v2 attribution: created BEFORE the reader so the parallel-IO
+    # worker pools (shard inflate / chunk parse) attribute their work
+    # from the very first shard; the executor feeds per-stage work/
+    # queue-wait/backpressure into the same profile, every trace.stage
+    # span finds it on the obs run, and the stream adds the IO byte
+    # totals. One emit at commit time -> `vctpu obs bottleneck` names the
+    # limiting stage (ROADMAP item 1).
+    from variantcalling_tpu.obs import profile as profile_mod
+
+    prof = profile_mod.StageProfiler() if profile_mod.enabled() else None
+    with obs.bind_profiler(prof):
+        return _stream_chunks(args, model, fasta, annotate, blacklist, prof,
+                              engine=engine, mesh_plan=mesh_plan,
+                              rank_plan=rank_plan)
+
+
+def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
+                   engine: engine_mod.EngineDecision | None = None,
+                   mesh_plan=None, rank_plan=None) -> dict:
     import contextvars
     import threading
     import time as _time
@@ -1391,16 +1450,11 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                                                       retry_chunk,
                                                       retry_transient)
 
-    # obs v2 attribution: created BEFORE the reader so the parallel-IO
-    # worker pools (shard inflate / chunk parse) attribute their work
-    # from the very first shard; the executor feeds per-stage work/
-    # queue-wait/backpressure into the same profile and this loop adds
-    # writeback work and the IO byte totals. One emit at commit time ->
-    # `vctpu obs bottleneck` names the limiting stage (ROADMAP item 1).
-    from variantcalling_tpu.obs import profile as profile_mod
     from variantcalling_tpu.obs import sampler as sampler_mod
 
-    prof = profile_mod.StageProfiler() if profile_mod.enabled() else None
+    # declared up front, so that a run that only reused reads "0 builds"
+    obs.counter("predictor.builds").add(0)
+    obs.counter("predictor.reuses").add(0)
     # continuous-profiler attribution (obs v3): this thread runs the
     # sequenced single-writer commit loop for the duration of the run
     sampler_mod.register_current("committer")
@@ -1463,36 +1517,21 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
         # a diverted chunk flows on as a (table, None, None) marker.
         # The chunk's trace binds to the thread for the duration so
         # ladder events link to it, and the body emits its trace span.
+        # ONE measurement (trace.stage) is the stage's span, histogram,
+        # attribution row (``score_stage.w<idx>`` on a pooled worker) and
+        # causal span, on whichever thread runs it.
         tid = getattr(table, "_obs_trace", None)
-        with obs.trace_scope(tid):
-            t0 = _time.perf_counter()  # vctpu-lint: disable=VCT006 — obs trace-span timing
+        with obs.trace_scope(tid), \
+                stage("score_stage", records=len(table), causal=True):
             out = _guard_chunk(table, "score_stage",
                                lambda: ctx.score_table(table))
-            if tid is not None:
-                obs.trace_span(tid, "score_stage",
-                               _time.perf_counter() - t0,  # vctpu-lint: disable=VCT006 — obs trace-span timing
-                               records=len(table))
         if out is None:
             return table, None, None
         score, filters = out
         return table, score, filters
 
-    def _timed_worker(fn, stage_name, item, n_records):
-        """Run one stage callable on an IO-pool worker with the same
-        span/histogram telemetry the executor would emit for that stage,
-        plus a per-worker attribution row (``<stage>.w<idx>``)."""
-        if not obs.active():
-            return fn(item)
-        t0 = _time.perf_counter()  # vctpu-lint: disable=VCT006 — obs span timing
-        out = fn(item)
-        dt = _time.perf_counter() - t0  # vctpu-lint: disable=VCT006 — obs span timing
-        tname = threading.current_thread().name
-        obs.span(stage_name, dt, tname)
-        obs.histogram(f"stage.{stage_name}.s").observe(dt)
-        if prof is not None:
-            prof.stage(f"{stage_name}.{tname.rsplit('-', 1)[-1]}").add_work(
-                dt, records=n_records)
-        return out
+    # the executor records nothing of its own for a stage that does
+    score_stage.self_timed = True
 
     def raw_chunk_worker(item):
         """The ZERO-WAIT pooled chunk body: parse -> fused featurize+
@@ -1543,10 +1582,7 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                     obs.trace_span(tid, "ingest",
                                    _time.perf_counter() - t0,  # vctpu-lint: disable=VCT006 — obs trace-span timing
                                    records=len(table))
-            scored = _timed_worker(score_stage, "score_stage", table,
-                                   len(table))
-            return _timed_worker(render_stage, "render_stage", scored,
-                                 len(table))
+            return render_stage(score_stage(table))
 
         with obs.trace_scope(tid):
             out = retry_chunk(body, "chunk_worker")
@@ -1572,29 +1608,25 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
         # is dropped after render, but compress + the sequenced commit
         # still emit spans of this chunk's DAG
         tid = getattr(table, "_obs_trace", None)
-        t0 = _time.perf_counter()  # vctpu-lint: disable=VCT006 — obs trace-span timing
-        if score is None:
-            # quarantined chunk (recovery ladder): ZERO bytes reach the
-            # main output; the ORIGINAL records (no TREE_SCORE, original
-            # FILTER) go to the <out>.quarantine sidecar for triage
-            qbody = assemble_table_bytes(table)
-            if qbody is None:
-                qbody = render_table_bytes_python(table)
-            out = b"", len(table), 0, bytes(qbody), tid
-        else:
+        with stage("render_stage", trace=tid, records=len(table), causal=True):
+            if score is None:
+                # quarantined chunk (recovery ladder): ZERO bytes reach the
+                # main output; the ORIGINAL records (no TREE_SCORE, original
+                # FILTER) go to the <out>.quarantine sidecar for triage
+                qbody = assemble_table_bytes(table)
+                if qbody is None:
+                    qbody = render_table_bytes_python(table)
+                return b"", len(table), 0, bytes(qbody), tid
             extra = {"TREE_SCORE": np.round(score, 4)}
             body = assemble_table_bytes(table, new_filters=filters,
                                         extra_info=extra)
             if body is None:  # native hiccup mid-run: Python renderer, same bytes
                 body = render_table_bytes_python(table, new_filters=filters,
                                                  extra_info=extra)
-            out = (body, len(table), int(np.sum(filters.codes == 0)), None,
-                   tid)
-        if tid is not None:
-            obs.trace_span(tid, "render_stage",
-                           _time.perf_counter() - t0,  # vctpu-lint: disable=VCT006 — obs trace-span timing
-                           records=len(table))
-        return out
+            return (body, len(table), int(np.sum(filters.codes == 0)), None,
+                    tid)
+
+    render_stage.self_timed = True
 
     out_path = str(args.output_file)
     gz = out_path.endswith(".gz")
@@ -1622,13 +1654,12 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
             if not len(body):  # quarantined chunk: nothing to compress
                 return b"", k, p, q, tid
             data = memoryview(body) if isinstance(body, np.ndarray) else body
-            t0 = _time.perf_counter()  # vctpu-lint: disable=VCT006 — obs trace-span timing
-            out = compressor.add(data)
-            if tid is not None:
-                obs.trace_span(tid, "compress_stage",
-                               _time.perf_counter() - t0,  # vctpu-lint: disable=VCT006 — obs trace-span timing
-                               bytes_in=len(data))
+            with stage("compress_stage", trace=tid, causal=True,
+                       bytes_in=len(data)):
+                out = compressor.add(data)
             return out, k, p, q, tid
+
+        compress_stage.self_timed = True
 
         # the ONE stage that is NOT a pure chunk body: the compressor's
         # block carry absorbs every byte it sees, so a re-dispatch (chunk
@@ -1749,7 +1780,8 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
             journal_mod.release_token(part_token)
         raise
 
-    wb = prof.stage("writeback") if prof is not None else None
+    if prof is not None:
+        prof.stage("writeback")  # the row exists even for an empty stream
     # the parallel layout (VCTPU_IO_THREADS > 1): scoring AND record
     # render ride the SAME ordered-window fan-out as chunk parse — all
     # per-chunk work shares the IO pool, reassembled into canonical
@@ -1791,11 +1823,11 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                 faults.check("pipeline.stage_hang")
                 # hf None == featurize-stage quarantine marker; the
                 # megabatch stream passes it through to the render path
-                hf = _guard_chunk(
-                    table, "featurize_stage",
-                    lambda: _timed_worker(ctx.host_features,
-                                          "featurize_stage", table,
-                                          len(table)))
+                def featurize():
+                    with stage("featurize_stage", records=len(table)):
+                        return ctx.host_features(table)
+
+                hf = _guard_chunk(table, "featurize_stage", featurize)
                 return table, hf
 
             tid = getattr(table, "_obs_trace", None)
@@ -1808,16 +1840,12 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                                    records=len(table))
             return out
 
-        def render_worker(item):
-            return _timed_worker(render_stage, "render_stage", item,
-                                 len(item[0]))
-
         if source_pooled:
             window = reader.io_threads + 2
             prepped = imap_ordered(reader.shared_pool(), prep_worker,
                                    _traced_chunks(reader), window=window)
             scored = shard_score.megabatch_stream(prepped, ctx, profiler=prof)
-            source = imap_ordered(reader.shared_pool(), render_worker,
+            source = imap_ordered(reader.shared_pool(), render_stage,
                                   scored, window=window)
             stages = []
         else:
@@ -1832,26 +1860,14 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                 # time as queue-wait instead: source_pooled below)
                 it = iter(reader)
                 while True:
-                    if obs.active():
-                        t0 = _time.perf_counter()  # vctpu-lint: disable=VCT006 — obs span timing
-                        try:
+                    try:
+                        # items=0: the executor feed counts the pulled
+                        # items on this row (the pooled-source rule) —
+                        # work seconds only here
+                        with stage("ingest", items=0):
                             table = next(it)
-                        except StopIteration:
-                            return
-                        dt = _time.perf_counter() - t0  # vctpu-lint: disable=VCT006 — obs span timing
-                        obs.span("ingest", dt,
-                                 threading.current_thread().name)
-                        obs.histogram("stage.ingest.s").observe(dt)
-                        if prof is not None:
-                            # items=0: the executor feed counts the
-                            # pulled items on this row (the pooled-source
-                            # rule) — work seconds only here
-                            prof.stage("ingest").add_work(dt, items=0)
-                    else:
-                        try:
-                            table = next(it)
-                        except StopIteration:
-                            return
+                    except StopIteration:
+                        return
                     yield table
 
             source = shard_score.megabatch_stream(
@@ -1886,7 +1902,7 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                          profiler=prof, source_name="ingest",
                          # mesh serial-IO counts too: the source chain
                          # attributes its own ingest/featurize/score work
-                         # (timed_tables + _timed_worker + score.dN), so
+                         # (timed_tables + trace.stage + score.dN), so
                          # feed-blocked time is queue-wait, never work —
                          # and the serial cached layout likewise runs the
                          # self-attributing chunk body inline on the feed
@@ -1953,21 +1969,13 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
                     n_quar_chunks += 1
                     n_quar_records += k
                 data = memoryview(body) if isinstance(body, np.ndarray) else body
-                if wb is not None or trace_id is not None:
-                    t0 = _time.perf_counter()  # vctpu-lint: disable=VCT006 — obs writeback attribution
+                # the sequenced commit: the TERMINAL span of the chunk's
+                # DAG (named like the profiler's consumer stage so
+                # critical-path reconciles against it)
+                with stage("writeback", trace=trace_id, causal=True,
+                           chunk=n_chunks, bytes_out=len(data)):
                     _sink_write(sink, data)
-                    dt = _time.perf_counter() - t0  # vctpu-lint: disable=VCT006 — obs writeback attribution
-                    if wb is not None:
-                        wb.add_work(dt, bytes_out=len(data))
-                    if trace_id is not None:
-                        # the sequenced commit: the TERMINAL span of the
-                        # chunk's DAG (named like the profiler's consumer
-                        # stage so critical-path reconciles against it)
-                        obs.trace_span(trace_id, "writeback", dt,
-                                       chunk=n_chunks, bytes_out=len(data))
-                        obs.end_trace(trace_id)
-                else:
-                    _sink_write(sink, data)
+                obs.end_trace(trace_id)
                 n_total += k
                 n_pass += p
                 n_chunks += 1
@@ -2183,7 +2191,7 @@ def run_loaded(args, model, fasta: FastaReader, annotate, blacklist,
     cold CLI (:func:`_run_impl`) rides the same code so serve output is
     byte-identical to the batch path by construction."""
     from variantcalling_tpu.utils import cancellation
-    from variantcalling_tpu.utils.trace import report, stage
+    from variantcalling_tpu.utils.trace import report
 
     eng = engine if engine is not None else engine_mod.resolve_for_run()
     # rank-partitioned scale-out FIRST (docs/scaleout.md): a multi-rank

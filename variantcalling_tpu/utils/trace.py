@@ -1,37 +1,66 @@
-"""Tracing/profiling: per-stage wall-clock + JAX device profiler, first-class.
+"""Tracing/profiling: the ONE span primitive, and the JAX device profiler.
 
 The reference's only profiling primitive is an (unused, buggy — it prints
 t_start - t_end, a negative duration) wall-clock decorator
 (ugvc/utils/decorators.py:4-14) plus simppl's command echo. SURVEY §5.1
 makes tracing first-class here:
 
-- ``stage(name)`` / ``@timed``: nested wall-clock spans collected into a
-  process-global table every pipeline can dump (``report()``), enabled by
-  default (near-zero overhead), logged at DEBUG. Span collection is
-  THREAD-AWARE: nesting depth lives in a ``threading.local`` (streaming
-  worker threads used to interleave through one shared ``_depth`` and
-  corrupt the whole table's indentation) and each span records the thread
-  that closed it; ``report()`` renders per-thread groups.
-- every closed span also lands in the obs event stream when a run is
-  active (:mod:`variantcalling_tpu.obs`) — trace spans, degradations and
-  executor lifecycle unify into ONE ordered JSONL log.
+- ``stage(name, **fields)`` / ``@timed``: a nested wall-clock span. It is
+  live exactly when an obs run is (``obs.active()``): with obs off the
+  call is one module-bool check returning a shared no-op — no object, no
+  lock, no append, nothing kept. With obs on ONE measurement feeds every
+  reader (docs/observability.md "Writing new instrumentation"):
+
+  * a ``jax.profiler.TraceAnnotation("vctpu:<name>", trace=<chunk trace
+    id>, thread=<python thread name>)``, so the span lands in the
+    profiler's host plane on the DEVICE TRACE'S CLOCK whenever a
+    ``jax.profiler`` trace is being taken (the keyword arguments arrive as
+    event stats; all Python threads' lines are named ``python`` there, so
+    the thread name has to ride as a stat);
+  * one obs ``span`` event with an explicit ``start`` (the stream's ``t``
+    clock, taken at entry, outside the stream's lock), ``dur``,
+    ``thread``, ``depth``, ``parent`` (the enclosing ``stage`` on this
+    thread) and ``trace_id`` (the chunk's causal trace);
+  * the run's :class:`~variantcalling_tpu.obs.profile.StageProfiler` row
+    ``<name>.w<idx>`` on a pooled worker (``<name>`` elsewhere), with the
+    parent's name on the row, and the histogram ``stage.<name>.s``;
+  * with ``causal=True`` the chunk's causal ``trace`` span, fed from the
+    same measurement;
+  * the run's span table (``ObsRun.spans``, bounded), which ``report()``
+    renders per thread. Nothing process-global grows: the table lives
+    and dies with the run.
+
 - ``device_trace(logdir)``: context manager around ``jax.profiler`` —
   captures an XLA trace (HLO timelines, fusion views) viewable in
   TensorBoard/Perfetto; no-op if profiling is unavailable.
-- ``VCTPU_TRACE=1`` env makes every ``stage`` span print as it closes.
+- ``VCTPU_TRACE=1`` makes every live ``stage`` span log at INFO as it
+  closes (DEBUG otherwise).
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import re
+import sys
 import threading
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 from variantcalling_tpu import logger, obs
 from variantcalling_tpu.utils import degrade
 from variantcalling_tpu import knobs
+
+#: the profiler-trace name prefix of every program span (the benchmark's
+#: ``program_spans.py`` finds them by it)
+ANNOTATION_PREFIX = "vctpu:"
+
+#: a pooled worker's thread name ends in its index (``vctpu-io-w3``): its
+#: attribution row is ``<name>.w3``, the family spelling
+#: ``vctpu obs bottleneck`` merges
+_WORKER_RE = re.compile(r"-(w\d+)$")
+
+#: span fields that are also StageProfiler accumulators
+_ROW_FIELDS = ("items", "records", "bytes_in", "bytes_out")
 
 
 @dataclass
@@ -40,69 +69,140 @@ class Span:
     seconds: float
     depth: int
     thread: str = "MainThread"
+    parent: str | None = None
 
 
 class _ThreadState(threading.local):
-    depth = 0
+    def __init__(self):
+        self.stack: list[_LiveSpan] = []  # this thread's open spans
 
 
-@dataclass
-class _Tracer:
-    """Process-global span table; append is thread-safe, depth is
-    per-thread (a worker's nesting cannot corrupt another's)."""
-
-    spans: list[Span] = field(default_factory=list)
-    _local: _ThreadState = field(default_factory=_ThreadState, repr=False)
-    _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
-
-    def clear(self) -> None:
-        with self._lock:
-            self.spans.clear()
-
-    def report(self) -> str:
-        """Per-thread groups: the main thread's spans first (unlabeled,
-        the historical format), every worker thread after, labeled."""
-        with self._lock:
-            spans = list(self.spans)
-        threads = ["MainThread"] + sorted(
-            {s.thread for s in spans} - {"MainThread"})
-        lines = ["stage timings:"]
-        for t in threads:
-            mine = [s for s in spans if s.thread == t]
-            if not mine:
-                continue
-            if t != "MainThread":
-                lines.append(f"  [thread {t}]")
-            pad = "  " if t == "MainThread" else "    "
-            for s in mine:
-                lines.append(f"{pad}{'  ' * s.depth}{s.name}: {s.seconds:.3f}s")
-        return "\n".join(lines)
+_LOCAL = _ThreadState()
 
 
-TRACER = _Tracer()
+class _NoSpan:
+    """What ``stage()`` hands out while no obs run is open: one shared,
+    stateless context manager."""
+
+    __slots__ = ()
+    seconds = 0.0
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **fields) -> None:
+        pass
 
 
-@contextlib.contextmanager
-def stage(name: str):
-    """Nested wall-clock span; spans land in TRACER.spans in close order
-    (per thread), and in the obs stream when a run is active."""
-    local = TRACER._local
-    local.depth += 1
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        local.depth -= 1
-        thread = threading.current_thread().name
-        with TRACER._lock:
-            TRACER.spans.append(Span(name, dt, local.depth, thread))
-        if obs.active():
-            obs.span(name, dt, thread, depth=local.depth)
+_NOOP = _NoSpan()
+
+
+class _LiveSpan:
+    __slots__ = ("name", "fields", "causal", "trace_id", "thread", "parent",
+                 "run", "start", "seconds", "_ann")
+
+    def __init__(self, run, name: str, trace: str | None, causal: bool,
+                 fields: dict):
+        self.run = run
+        self.name = name
+        self.fields = fields
+        self.causal = causal
+        self.trace_id = trace
+        self.seconds = 0.0  # the measurement, once the span has closed
+
+    def set(self, **fields) -> None:
+        """Fields known only once the body ran (a parsed chunk's record
+        count): they join the span event and the attribution row."""
+        self.fields.update(fields)
+
+    def __enter__(self):
+        stack = _LOCAL.stack
+        self.parent = stack[-1].name if stack else None
+        stack.append(self)
+        self.thread = threading.current_thread().name
+        if self.trace_id is None:
+            self.trace_id = obs.current_trace()
+        self._ann = None
+        jax = sys.modules.get("jax")  # never the reason jax gets imported
+        if jax is not None:
+            self._ann = jax.profiler.TraceAnnotation(
+                ANNOTATION_PREFIX + self.name, trace=self.trace_id or "",
+                thread=self.thread)
+            self._ann.__enter__()
+        self.start = self.run.now()
+        return self
+
+    def __exit__(self, *exc):
+        run, name = self.run, self.name
+        dur = self.seconds = run.now() - self.start
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        stack = _LOCAL.stack
+        stack.pop()
+        if exc[0] is not None:
+            # a failed body records nothing (the recovery ladder re-runs
+            # it, and the attempt that succeeds is the chunk's span)
+            return False
+        depth = len(stack)
+        fields = self.fields
+        body = dict(fields, start=round(self.start, 6), dur=round(dur, 6),
+                    thread=self.thread, depth=depth)
+        if self.parent is not None:
+            body["parent"] = self.parent
+        if self.trace_id is not None:
+            body["trace_id"] = self.trace_id
+        run._emit("span", name, body)
+        run.metrics.histogram(f"stage.{name}.s").observe(dur)
+        prof = run.profiler
+        if prof is not None:
+            worker = _WORKER_RE.search(self.thread)
+            row = f"{name}.{worker.group(1)}" if worker else name
+            prof.stage(row, parent=self.parent).add_work(
+                dur, **{k: fields[k] for k in _ROW_FIELDS if k in fields})
+        if self.causal and self.trace_id is not None:
+            obs.trace_span(self.trace_id, name, dur, **fields)
+        run.spans.append(Span(name, dur, depth, self.thread, self.parent))
         if knobs.get_bool("VCTPU_TRACE"):
-            logger.info("stage %s: %.3fs", name, dt)
+            logger.info("stage %s: %.3fs", name, dur)
         else:
-            logger.debug("stage %s: %.3fs", name, dt)
+            logger.debug("stage %s: %.3fs", name, dur)
+        return False
+
+
+def stage(name: str, *, trace: str | None = None, causal: bool = False,
+          **fields):
+    """The span primitive (module docstring): ``with stage("render_stage",
+    records=n, causal=True): ...``.
+
+    ``trace`` names the chunk's causal trace where the thread has none
+    bound (the committer); by default it is ``obs.current_trace()``.
+    ``fields`` must be JSON-serializable; ``records`` / ``bytes_in`` /
+    ``bytes_out`` also feed the attribution row. Names carry no dot: the
+    row's family is everything before the first one."""
+    if not obs.active():
+        return _NOOP
+    run = obs.current()
+    if run is None:  # the run closed between the two reads
+        return _NOOP
+    return _LiveSpan(run, name, trace, causal, fields)
+
+
+def current_span() -> str | None:
+    """The innermost ``stage`` open on this thread (None outside any)."""
+    stack = _LOCAL.stack
+    return stack[-1].name if stack else None
+
+
+def note(**fields) -> None:
+    """Add fields to the innermost ``stage`` open on this thread, from
+    code below the site that opened it (``built=True`` from the
+    predictor cache's miss path). Nothing to do outside a live span."""
+    stack = _LOCAL.stack
+    if stack:
+        stack[-1].fields.update(fields)
 
 
 def timed(fn=None, *, name: str | None = None):
@@ -121,8 +221,27 @@ def timed(fn=None, *, name: str | None = None):
     return deco(fn) if fn is not None else deco
 
 
+def spans() -> list[Span]:
+    """The open run's spans in close order (empty with obs off)."""
+    run = obs.current()
+    return list(run.spans) if run is not None else []
+
+
 def report() -> str:
-    return TRACER.report()
+    """The open run's spans as per-thread groups: the main thread's first
+    (unlabeled, the historical format), every worker thread after,
+    labeled."""
+    mine = spans()
+    threads = ["MainThread"] + sorted({s.thread for s in mine} - {"MainThread"})
+    lines = ["stage timings:"]
+    for t in threads:
+        pad = "  " if t == "MainThread" else "    "
+        rows = [f"{pad}{'  ' * s.depth}{s.name}: {s.seconds:.3f}s"
+                for s in mine if s.thread == t]
+        if rows and t != "MainThread":
+            lines.append(f"  [thread {t}]")
+        lines += rows
+    return "\n".join(lines)
 
 
 @contextlib.contextmanager
