@@ -38,6 +38,17 @@ def rng():
     return np.random.default_rng(0)
 
 
+@pytest.fixture
+def fresh_predictor_cache():
+    """An empty compiled-predictor cache. It is keyed on the model's
+    CONTENT, so a test that expects a build must not depend on which test
+    scored an equal model before it in the same worker."""
+    from variantcalling_tpu.pipelines import filter_variants as fv
+
+    fv._PREDICTOR_CACHE.clear()
+    return fv._PREDICTOR_CACHE
+
+
 def assert_no_stream_leaks(dirs=(), grace_s: float = 3.0) -> None:
     """The chaos invariant, enforced on the regular suite (ISSUE 10): no
     ``vctpu-*``/``pipe-*``/``genome-prefetch`` thread survives a test

@@ -226,8 +226,9 @@ def jit_engine(monkeypatch):
 
 
 @pytest.fixture()
-def streamed(world, jit_engine, tmp_path):
-    """One obs-on streaming run under a ``jax.profiler`` trace."""
+def streamed(world, jit_engine, fresh_predictor_cache, tmp_path):
+    """One obs-on streaming run under a ``jax.profiler`` trace, from an
+    empty predictor cache (so that it builds)."""
     import jax
 
     from variantcalling_tpu.pipelines.filter_variants import run_streaming
@@ -301,6 +302,12 @@ def test_streaming_run_emits_every_span_once_a_chunk(streamed):
     # and the fused_program span says whether it built
     built = [e["built"] for e in by_name["fused_program"]]
     assert built.count(True) >= 1 and len(built) == chunks
+    # ... or waited for another worker's build, or first call at a bucket:
+    # a lookup is one of build, wait or reuse, never a build AND a wait
+    for e in by_name["fused_program"] + by_name["dispatch_enqueue"]:
+        assert isinstance(e["waited"], bool)
+        assert not (e.get("built") and e["waited"])
+    assert built.count(True) == 1  # single flight: the workers share one
 
 
 def test_profiler_trace_holds_a_vctpu_event_for_each_span(streamed):
@@ -347,7 +354,8 @@ def test_output_bytes_equal_with_obs_on_and_off(streamed, world, tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_predictor_builds_moves_on_a_miss_and_not_on_a_hit(tmp_path):
+def test_predictor_builds_moves_on_a_miss_and_not_on_a_hit(
+        fresh_predictor_cache, tmp_path):
     from variantcalling_tpu.pipelines import filter_variants as fv
     from variantcalling_tpu.synthetic import synthetic_forest
 

@@ -10,7 +10,6 @@ features on device, fused with classifier inference.
 
 from __future__ import annotations
 
-import threading
 from dataclasses import dataclass
 from functools import partial
 
@@ -23,6 +22,7 @@ from variantcalling_tpu.io.fasta import FastaReader, encode_seq
 from variantcalling_tpu.io.vcf import VariantTable
 from variantcalling_tpu.ops import features as fops
 from variantcalling_tpu.ops import intervals as iops
+from variantcalling_tpu.utils.keyed_cache import KeyedCache
 
 WINDOW_RADIUS = 20  # bases either side of the anchor in the gathered window
 CENTER = WINDOW_RADIUS
@@ -132,16 +132,14 @@ def classify_alleles(table: VariantTable) -> AlleleColumns:
 # pair. The fused program compiles ONCE (per-contig arrays would retrace
 # per contig length). Two entries cached (the sharded + unsharded variants
 # of one genome; ~3.1GB HBM each for hg38).
-_DEVICE_GENOME_CACHE: dict = {}
 _DEVICE_GENOME_MAX = 2
-# chunk featurization fans out on the IO pool (vctpu-lint VCT010): a
-# per-KEY build lock makes a cache miss build-once-wait-rest — two
-# workers racing the SAME genome would otherwise both encode and upload
-# ~3.1GB to HBM — while builds of DISTINCT keys (different fasta/radius/
-# sharding) proceed concurrently instead of queueing behind a multi-
-# second upload they do not want. The global lock only guards the dicts.
-_DEVICE_GENOME_LOCK = threading.Lock()
-_DEVICE_GENOME_KEYLOCKS: dict = {}
+# chunk featurization fans out on the IO pool (vctpu-lint VCT010): a miss
+# is single flight (utils/keyed_cache.py) — two workers racing the SAME
+# genome would otherwise both encode and upload ~3.1GB to HBM — while
+# builds of DISTINCT keys (different fasta/radius/sharding) proceed
+# concurrently instead of queueing behind a multi-second upload they do
+# not want.
+_DEVICE_GENOME_CACHE = KeyedCache(_DEVICE_GENOME_MAX)
 # tables below this size featurize through the host window gather — a tiny
 # job must not pay a whole-genome encode + HBM upload
 GENOME_RESIDENT_MIN_VARIANTS = 100_000
@@ -181,28 +179,9 @@ class DeviceGenome:
 def device_genome(fasta: FastaReader, radius: int = WINDOW_RADIUS,
                   sharding=None) -> DeviceGenome:
     key = (getattr(fasta, "path", id(fasta)), radius, str(sharding))
-    hit = _DEVICE_GENOME_CACHE.get(key)
-    if hit is not None:
-        return hit
-    with _DEVICE_GENOME_LOCK:
-        hit = _DEVICE_GENOME_CACHE.get(key)
-        if hit is not None:
-            return hit
-        # one small Lock per distinct key for the process lifetime —
-        # a handful of genomes, never evicted (evicting one while a
-        # builder holds it would let a third thread double-build)
-        key_lock = _DEVICE_GENOME_KEYLOCKS.setdefault(key, threading.Lock())
-    with key_lock:
-        with _DEVICE_GENOME_LOCK:
-            hit = _DEVICE_GENOME_CACHE.get(key)  # re-check: the builder we waited on
-            if hit is not None:
-                return hit
-        out = _build_device_genome(fasta, radius, sharding)
-        with _DEVICE_GENOME_LOCK:
-            while len(_DEVICE_GENOME_CACHE) >= _DEVICE_GENOME_MAX:
-                _DEVICE_GENOME_CACHE.pop(next(iter(_DEVICE_GENOME_CACHE)))
-            _DEVICE_GENOME_CACHE[key] = out
-    return out
+    genome, _how = _DEVICE_GENOME_CACHE.get(
+        key, lambda: _build_device_genome(fasta, radius, sharding))
+    return genome
 
 
 def _build_device_genome(fasta: FastaReader, radius: int,

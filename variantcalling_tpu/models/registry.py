@@ -10,9 +10,16 @@ or a fitted sklearn classifier (converted to FlatForest on load).
 
 from __future__ import annotations
 
+import dataclasses
+import hashlib
 import os
 import pickle
+import threading
+import weakref
 
+import numpy as np
+
+from variantcalling_tpu.models import dan as dan_mod
 from variantcalling_tpu.models.dan import DanModel
 from variantcalling_tpu.models.forest import FlatForest, from_sklearn
 from variantcalling_tpu.models.threshold import ThresholdModel
@@ -36,6 +43,64 @@ def family_of(model: object) -> str:
     if isinstance(model, ThresholdModel):
         return "threshold"
     return "forest"
+
+
+#: id(model) -> (weakref to it, its digest). Models are unhashable mutable
+#: dataclasses, so the memo is keyed on identity and a weakref both proves
+#: the id still names the object digested and drops the entry with it.
+_DIGEST_MEMO: dict[int, tuple[weakref.ref, str]] = {}
+#: re-entrant: a collection inside the locked store can run ``forget``
+_DIGEST_MEMO_LOCK = threading.RLock()
+
+
+def content_digest(model: object) -> str:
+    """Content address of a loaded model: equal for two models that
+    compile to the same scoring program and finalize alike (two unpickles
+    of one file), different when any field a program closes over differs.
+
+    This is the model's part of the compiled-predictor cache key
+    (``pipelines/filter_variants._PREDICTOR_CACHE``), so it is computed
+    once per model OBJECT and a lookup is a dict probe; a model is not to
+    be edited in place once it has scored."""
+    memo = _DIGEST_MEMO.get(id(model))
+    if memo is not None and memo[0]() is model:
+        return memo[1]
+    family = family_of(model)
+    if isinstance(model, DanModel):
+        body = dan_mod.weights_digest(model)
+    elif isinstance(model, (FlatForest, ThresholdModel)):
+        body = _fields_digest(model)
+    else:
+        raise TypeError(
+            f"no content digest for a {type(model).__name__}: only loaded "
+            "registry models (FlatForest, ThresholdModel, DanModel) compile")
+    digest = f"{family}:{body}"
+    key = id(model)
+
+    def forget(ref, key=key):
+        with _DIGEST_MEMO_LOCK:
+            if _DIGEST_MEMO.get(key, (None,))[0] is ref:
+                del _DIGEST_MEMO[key]
+
+    with _DIGEST_MEMO_LOCK:
+        _DIGEST_MEMO[key] = (weakref.ref(model, forget), digest)
+    return digest
+
+
+def _fields_digest(model: object) -> str:
+    """sha256 over every dataclass field: arrays with dtype, shape and
+    bytes, everything else by ``repr``."""
+    h = hashlib.sha256()
+    for f in dataclasses.fields(model):
+        v = getattr(model, f.name)
+        h.update(f.name.encode())
+        if isinstance(v, np.ndarray):
+            a = np.ascontiguousarray(v)
+            h.update(f"{a.dtype}{a.shape}".encode())
+            h.update(a.tobytes())
+        else:
+            h.update(repr(v).encode())
+    return h.hexdigest()
 
 
 def family_of_name(model_name: str) -> str | None:

@@ -17,6 +17,7 @@ import os
 import pickle
 import sys
 import threading
+import weakref
 
 import numpy as np
 
@@ -26,7 +27,7 @@ import jax.numpy as jnp
 from variantcalling_tpu import engine as engine_mod
 from variantcalling_tpu import knobs, logger, obs
 from variantcalling_tpu.engine import EngineError
-from variantcalling_tpu.utils import degrade
+from variantcalling_tpu.utils import degrade, keyed_cache
 from variantcalling_tpu.utils.trace import note, stage, timed
 from variantcalling_tpu.featurize import host_featurize
 from variantcalling_tpu.io import bed as bedio
@@ -221,45 +222,43 @@ def _is_cg_insertion(table: VariantTable, windows: np.ndarray, center: int) -> n
     return cand & (((ins == 1) & (anchor == 1) & (nxt == 2)) | ((ins == 2) & (anchor == 2) & (nxt == 1)))
 
 
-# Compiled predictors keyed on (model identity, feature order[, flow order]).
-# A fresh jax.jit per call would recompile the forest program on every
-# pipeline invocation; cached entries hold the model reference so id() stays
-# valid for the cache lifetime. Bounded FIFO so a long-lived process scoring
-# many models does not accumulate compiled programs forever.
-_PREDICTOR_CACHE: dict[tuple, tuple[object, object]] = {}
+# Compiled predictors, one per model CONTENT per process. The key is
+# (kind, registry.content_digest(model), feature order[, flow order,
+# genome_resident], strategy token, mesh): every main() call unpickles its
+# model anew, so a key on id(model) missed once per file and per pooled
+# worker, and each miss made a jax.jit object whose first call per bucket
+# traced, lowered and loaded its own copy of one executable. Two models of
+# equal content now share one program, so there is no `is model` test on a
+# hit: the digest covers everything a program closes over. A miss is single
+# flight (utils/keyed_cache.py): one thread builds, the threads that want
+# the same key meanwhile wait and take its result or its exception. Bounded
+# FIFO so a long-lived process scoring many models does not accumulate
+# compiled programs forever; `.clear()` empties it and its in-flight table.
+_PREDICTOR_CACHE_MAX = 8
+_PREDICTOR_CACHE = keyed_cache.KeyedCache(_PREDICTOR_CACHE_MAX)
 
 #: ``jax.named_scope`` names of the fused program's three parts
 SCOPE_WINDOW_GATHER = "vctpu_window_gather"
 SCOPE_WINDOW_FEATURES = "vctpu_window_features"
 SCOPE_MODEL = "vctpu_model"
-_PREDICTOR_CACHE_MAX = 8
+
+#: how a lookup ended -> its counter
+_LOOKUP_COUNTER = {keyed_cache.HIT: "predictor.reuses",
+                   keyed_cache.BUILT: "predictor.builds",
+                   keyed_cache.WAITED: "predictor.waits"}
 
 
-#: chunk prep/scoring fans out on the IO pool (vctpu-lint VCT010): the
-#: eviction loop's pop-next-iter is NOT atomic — two workers inserting
-#: concurrently could pop the same key (KeyError) or evict past the cap
-_PREDICTOR_CACHE_LOCK = threading.Lock()
-
-
-def _cache_get(key: tuple, model):
-    """The cached program for ``key`` if it was built over this very
-    ``model`` object, else None (counter ``predictor.reuses``)."""
-    hit = _PREDICTOR_CACHE.get(key)
-    if hit is not None and hit[0] is model:
-        obs.counter("predictor.reuses").add(1)
-        return hit[1]
-    return None
-
-
-def _cache_put(key: tuple, value: tuple) -> None:
-    """Keep a program just built (counter ``predictor.builds``; the
-    enclosing ``fused_program`` span learns ``built``)."""
-    obs.counter("predictor.builds").add(1)
-    note(built=True)
-    with _PREDICTOR_CACHE_LOCK:
-        while len(_PREDICTOR_CACHE) >= _PREDICTOR_CACHE_MAX:
-            _PREDICTOR_CACHE.pop(next(iter(_PREDICTOR_CACHE)))
-        _PREDICTOR_CACHE[key] = value
+def _cached_program(key: tuple, build):
+    """The program under ``key``, built by ``build()`` on a miss (counters
+    ``predictor.builds`` / ``predictor.reuses`` / ``predictor.waits``; the
+    enclosing ``fused_program`` span learns ``built`` or ``waited``)."""
+    value, how = _PREDICTOR_CACHE.get(key, build)
+    obs.counter(_LOOKUP_COUNTER[how]).add(1)
+    if how == keyed_cache.BUILT:
+        note(built=True)
+    elif how == keyed_cache.WAITED:
+        note(waited=True)
+    return value
 
 
 def _strategy_token(strategy: str | None) -> tuple:
@@ -300,22 +299,22 @@ def _raw_predictor(model, feature_names: list[str], strategy: str | None = None)
 
 def _predictor_for(model, feature_names: list[str], strategy: str | None = None,
                    mesh=None):
-    key = ("x", id(model), tuple(feature_names), _strategy_token(strategy), mesh)
-    hit = _cache_get(key, model)
-    if hit is not None:
-        return hit
-    program, finalize = _raw_predictor(model, feature_names, strategy=strategy)
-    if mesh is not None:
-        # data-parallel mesh plan (>1 device): the SAME program body runs
-        # per device over its dp shard of the feature matrix — a pure
-        # map, margins never cross devices (docs/streaming_executor.md
-        # "Mesh-sharded scoring")
-        from variantcalling_tpu.parallel import shard_score
+    key = ("x", registry_mod.content_digest(model), tuple(feature_names),
+           _strategy_token(strategy), mesh)
 
-        program = shard_score.shard_program(program, mesh, n_data_args=1)
-    pair = (jax.jit(program), finalize)
-    _cache_put(key, (model, pair))
-    return pair
+    def build():
+        program, finalize = _raw_predictor(model, feature_names, strategy=strategy)
+        if mesh is not None:
+            # data-parallel mesh plan (>1 device): the SAME program body runs
+            # per device over its dp shard of the feature matrix — a pure
+            # map, margins never cross devices (docs/streaming_executor.md
+            # "Mesh-sharded scoring")
+            from variantcalling_tpu.parallel import shard_score
+
+            program = shard_score.shard_program(program, mesh, n_data_args=1)
+        return jax.jit(program), finalize
+
+    return _cached_program(key, build)
 
 
 def _host_names(feature_names: list[str]) -> list[str]:
@@ -344,14 +343,17 @@ def _fused_program(model, feature_names: list[str], flow_order: str,
     position — windows are gathered on device, so per-run transfer is
     4 bytes a variant instead of the 41-byte window row.
     """
+    key = ("fused", registry_mod.content_digest(model), tuple(feature_names),
+           flow_order, genome_resident, _strategy_token(strategy), mesh)
+    return _cached_program(key, lambda: _build_fused_program(
+        model, feature_names, flow_order, genome_resident, strategy, mesh))
+
+
+def _build_fused_program(model, feature_names, flow_order, genome_resident,
+                         strategy, mesh):
+    """A miss of :func:`_fused_program`: ``(jitted, host_names, finalize)``."""
     from variantcalling_tpu.featurize import (CENTER, device_feature_dict,
                                               windows_from_packed)
-
-    key = ("fused", id(model), tuple(feature_names), flow_order,
-           genome_resident, _strategy_token(strategy), mesh)
-    hit = _cache_get(key, model)
-    if hit is not None:
-        return hit
 
     # This is the JIT engine's program: featurize + forest inference fused
     # into one device program (engine contract, docs/robustness.md — the
@@ -404,9 +406,7 @@ def _fused_program(model, feature_names: list[str], flow_order: str,
             fn, mesh, n_data_args=7,
             replicated_leading=1 if genome_resident else 0)
 
-    jitted = (jax.jit(fn), host_names, finalize)
-    _cache_put(key, (model, jitted))
-    return jitted
+    return jax.jit(fn), host_names, finalize
 
 
 def _narrow_column(a: np.ndarray) -> np.ndarray:
@@ -615,13 +615,46 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
         host_cols = tuple(_narrow_column(hf.cols[f])
                           for f in _host_names(hf.names))
 
-    with stage("fused_program", built=False):
+    with stage("fused_program", built=False, waited=False):
         program = _fused_program(model, hf.names, flow_order,
                                  genome_resident=genome_resident,
                                  strategy=strategy, mesh=mesh)
     n = len(table) if table is not None else len(windows)
     return _FusedInputs(n, program, genome, gpos_all, gpos_fill, windows,
                         host_cols, hf.alle, model)
+
+
+#: argument signatures each live jit object has been called at. A jit
+#: object's first call at a signature traces, lowers and loads (or compiles)
+#: an executable, and jax does not share that work between threads: pooled
+#: workers that reach a new bucket size together would each do all of it.
+_CALLED_AT: "weakref.WeakKeyDictionary[object, set]" = weakref.WeakKeyDictionary()
+_CALLED_AT_LOCK = threading.Lock()
+_FIRST_CALLS = keyed_cache.SingleFlight()
+
+
+def _enqueue(fn, sig: tuple, call_args: tuple):
+    """``fn(*call_args)``, the first call at ``sig`` made by one thread
+    alone: threads that arrive with the same (program, ``sig``) meanwhile
+    wait for it to return (or take its exception), then make their own,
+    which finds the executable in place. Once a signature has been called
+    the gate is one set lookup."""
+    if sig in _CALLED_AT.get(fn, ()):
+        return fn(*call_args)
+
+    def first_call():
+        res = fn(*call_args)
+        with _CALLED_AT_LOCK:
+            _CALLED_AT.setdefault(fn, set()).add(sig)
+        return res
+
+    res, waited = _FIRST_CALLS.do((id(fn), sig), first_call)
+    if not waited:
+        return res
+    # the flight's result was the first caller's: a waiter makes its own call
+    obs.counter("predictor.waits").add(1)
+    note(waited=True)
+    return fn(*call_args)
 
 
 def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
@@ -668,6 +701,9 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
         ref_code = cat([i.alle.ref_code for i in inputs])
         alt_code = cat([i.alle.alt_code for i in inputs])
         is_snp = cat([i.alle.is_snp for i in inputs])
+    # what, besides the bucket size, makes jax trace this program anew
+    shapes = (genome.blocks.shape if genome_resident else windows.shape[1:],
+              tuple(c.dtype.char for c in host_cols))
 
     n = sum(i.n for i in inputs)
     out = np.empty(n, dtype=np.float32)
@@ -718,8 +754,8 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
             else:
                 call_args = (prep(windows, fill=4), *common)
         # the enqueue; on a first call also trace + lower + cache load or compile
-        with stage("dispatch_enqueue", rows=target):
-            res = fn(*call_args)
+        with stage("dispatch_enqueue", rows=target, waited=False):
+            res = _enqueue(fn, (target, shapes), call_args)
         pending.append((lo, hi, res))
         while len(pending) > 2:
             plo, phi, res = pending.pop(0)
@@ -803,7 +839,7 @@ def score_variants(model, x: np.ndarray, feature_names: list[str],
 
     plan = plan or shard_score.resolve_plan(eng.name)
     mesh = shard_score.mesh_for(plan)
-    with stage("fused_program", built=False):
+    with stage("fused_program", built=False, waited=False):
         fn, finalize = _predictor_for(model, feature_names, strategy=strategy,
                                       mesh=mesh)
     n_dev = plan.devices
@@ -1455,6 +1491,7 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     # declared up front, so that a run that only reused reads "0 builds"
     obs.counter("predictor.builds").add(0)
     obs.counter("predictor.reuses").add(0)
+    obs.counter("predictor.waits").add(0)
     # continuous-profiler attribution (obs v3): this thread runs the
     # sequenced single-writer commit loop for the duration of the run
     sampler_mod.register_current("committer")

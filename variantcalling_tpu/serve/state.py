@@ -11,10 +11,10 @@ per size in ``shard_score``, the persistent XLA compile cache) were
 already designed for a long-lived process; this module is the thin
 request-facing layer that keeps the HOST objects resident too.
 
-Thread safety: per-key build locks (the PR 9 ``device_genome`` pattern)
-— two concurrent requests for the same model block on one load; requests
-for different models load in parallel; the table locks are only held for
-dict bookkeeping.
+Thread safety: single-flight builds (``utils/keyed_cache.py``, shared
+with ``device_genome`` and the predictor cache) — two concurrent requests
+for the same model block on one load; requests for different models load
+in parallel; the table locks are only held for dict bookkeeping.
 """
 
 from __future__ import annotations
@@ -23,6 +23,7 @@ import os
 import threading
 
 from variantcalling_tpu import logger
+from variantcalling_tpu.utils import keyed_cache
 
 #: bounded FIFO sizes: models are small (pickles), genomes hold memmaps
 _MAX_MODELS = 8
@@ -35,45 +36,31 @@ def file_identity(path: str) -> tuple[str, int, int]:
 
 
 class _KeyedCache:
-    """Bounded FIFO with per-key build locks (same-key requests build
-    once; distinct keys build concurrently)."""
+    """Bounded FIFO with single-flight builds (same-key requests build
+    once; distinct keys build concurrently), counting hits and misses."""
 
     def __init__(self, name: str, max_entries: int):
         self.name = name
-        self.max_entries = max_entries
+        self._cache = keyed_cache.KeyedCache(max_entries, on_evict=self._evicted)
         self._lock = threading.Lock()
-        self._entries: dict[tuple, object] = {}
-        self._building: dict[tuple, threading.Lock] = {}
         self.hits = 0
         self.misses = 0
 
+    def _evicted(self, key: tuple) -> None:
+        logger.info("serve: %s cache evicted %s", self.name, key[0])
+
     def get(self, key: tuple, build):
+        value, how = self._cache.get(key, build)
         with self._lock:
-            if key in self._entries:
-                self.hits += 1
-                return self._entries[key]
-            gate = self._building.setdefault(key, threading.Lock())
-        with gate:
-            # re-check: the racing loser finds the winner's entry
-            with self._lock:
-                if key in self._entries:
-                    self.hits += 1
-                    return self._entries[key]
-            value = build()
-            with self._lock:
+            if how == keyed_cache.BUILT:
                 self.misses += 1
-                self._entries[key] = value
-                while len(self._entries) > self.max_entries:
-                    evicted = next(iter(self._entries))
-                    del self._entries[evicted]
-                    logger.info("serve: %s cache evicted %s", self.name,
-                                evicted[0])
-                self._building.pop(key, None)
-            return value
+            else:  # found, or another request's build awaited
+                self.hits += 1
+        return value
 
     def stats(self) -> dict:
         with self._lock:
-            return {"entries": len(self._entries), "hits": self.hits,
+            return {"entries": len(self._cache), "hits": self.hits,
                     "misses": self.misses}
 
 
