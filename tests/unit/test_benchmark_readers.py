@@ -143,3 +143,77 @@ def test_every_new_metric_names_a_reader_and_moves_throughput(bench):
     groups = bench.load("readers", "idle_by_span").GROUPS
     assert split | {"score_finalize"} == \
         set(groups["program"]) | set(groups["feed"]) | {"dispatch_wait"}
+
+
+# -- the serve cell's readers (ISSUE 30) ---------------------------------------
+
+def serve_span(name, dur, req="r1"):
+    return {"kind": "span", "name": name, "dur": dur, "req": req}
+
+
+#: two requests: 4 s and 8 s from end to end, 1 s and 2 s of them in admission,
+#: their pipelines 2 s and 4 s of wall
+SERVED = [serve_span("serve_request", 4.0), serve_span("serve_admit", 1.0),
+          serve_span("serve_request", 8.0, "r2"), serve_span("serve_admit", 2.0, "r2"),
+          serve_span("score_stage", 3.0),
+          {"kind": "profile", "name": "pipeline", "wall_s": 2.0, "req": "r1"},
+          {"kind": "profile", "name": "pipeline", "wall_s": 4.0, "req": "r2"}]
+SERVE_FINAL = [final(**{"serve.requests_genome_resident": 7, "served": 10,
+                        "declared": 0})]
+
+
+@pytest.mark.parametrize("reader, args, ctx, want", [
+    ("wall_quantile", {"q": 0.5}, {"file_walls": [5.0, 1.0, 3.0, 2.0, 4.0]}, 3.0),
+    # 0.95 x 4 intervals = 3.8: four fifths of the way from 4.0 to 5.0
+    ("wall_quantile", {"q": 0.95}, {"file_walls": [5.0, 1.0, 3.0, 2.0, 4.0]}, 4.8),
+    ("wall_quantile", {"q": 0.95}, {"file_walls": [2.5]}, 2.5),
+    ("wall_quantile", {"q": 0.5}, {"file_walls": []}, None),
+    ("span_share", {"part": "serve_admit", "whole": "serve_request"},
+     {"obs_events": SERVED}, 100 * 3.0 / 12.0),
+    ("span_share", {"part": "serve_admit", "whole": "serve_request"},
+     {"obs_events": [serve_span("score_stage", 3.0)]}, None),  # a program without the span
+    # 1 - 6 s of pipeline over (12 - 3) s holding a slot
+    ("daemon_overhead", {}, {"obs_events": SERVED}, 100 * (1 - 6.0 / 9.0)),
+    ("daemon_overhead", {}, {"obs_events": SERVED[4:]}, None),
+    ("counter_ratio", {"part": "serve.requests_genome_resident", "whole": "served"},
+     {"obs_events": SERVE_FINAL}, 70.0),
+    ("counter_ratio", {"part": "declared", "whole": "served"},
+     {"obs_events": SERVE_FINAL}, 0.0),      # declared and never moved: a reading
+    ("counter_ratio", {"part": "no.such.counter", "whole": "served"},
+     {"obs_events": SERVE_FINAL}, None),     # a program that does not count it
+    ("counter_ratio", {"part": "served", "whole": "declared"},
+     {"obs_events": SERVE_FINAL}, None),     # nothing to divide by
+])
+def test_serve_reader_on_hand_made_events(bench, reader, args, ctx, want):
+    got = bench.load("readers", reader).read(ctx, **args)
+    assert got == (pytest.approx(want) if want is not None else None)
+
+
+def test_the_serve_cells_metrics_name_readers_and_list_only_that_cell(bench):
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    cell = "forest-t40d6-hg38x2-exome.serve-c4"
+    new = {"request_p50_s": "daemon front", "request_p95_s": "daemon front",
+           "admission_wait_share": "admission",
+           "daemon_overhead_share": "daemon front",
+           "resident_requests_share": "device featurize and score"}
+    by_name = {m["name"]: m for m in bm["per_layer"]}
+    for name, layer in new.items():
+        m = by_name[name]
+        assert (m["workloads"], m["moves"], m["layer"]) == ([cell], "variants_per_s", layer)
+        with open(os.path.join(BENCH, "layer_metrics", name + ".json"),
+                  encoding="utf-8") as fh:
+            how = json.load(fh)
+        assert callable(bench.load("readers", how["reader"]).read)
+    # the cell is appended, one chip, with nothing cut, and no roofline lists it
+    assert bm["workloads"][-1]["name"] == cell and bm["workloads"][-1]["chips"] == 1
+    assert bm["configs"][-1]["reduced"] == []
+    assert cell not in by_name["forest_wide_block_roofline"]["workloads"]
+    with open(os.path.join(BENCH, "traffic", "serve-c4.json"), encoding="utf-8") as fh:
+        traffic = json.load(fh)
+    assert {k: traffic[k] for k in ("driver", "clients", "think_s", "warm",
+                                    "warmup_requests_per_reference", "trace_requests",
+                                    "check_sample_per_file")} == {
+        "driver": "closed_loop_serve", "clients": 4, "think_s": 0, "warm": True,
+        "warmup_requests_per_reference": 1, "trace_requests": 32,
+        "check_sample_per_file": 65536}

@@ -677,13 +677,18 @@ def test_serve_sheds_beyond_capacity(daemon, serve_world):
 
 def test_serve_request_deadline_cancels(daemon, serve_world):
     """A request whose deadline expires mid-run is cancelled at a chunk
-    boundary: 504 deadline status, destination untouched, daemon alive."""
+    boundary: 504 deadline status, destination untouched, daemon alive.
+
+    The injected hang outlasts the deadline by construction: EVERY chunk
+    body waits 1.5 s before it parses, so no chunk can reach the committer
+    (whose per-chunk poll is where the cancellation lands) before the 0.5 s
+    deadline has expired, whatever the machine's speed."""
     w = serve_world
     out = os.path.join(w["dir"], "late.vcf")
     code, payload = _post(
         daemon.address, "/v1/filter",
-        _filter_body(w, out, deadline_s=1.0,
-                     faults="pipeline.stage_hang:0@0.4"))
+        _filter_body(w, out, deadline_s=0.5,
+                     faults="pipeline.stage_hang:0@1.5"))
     assert code == 504 and payload["status"] == "deadline"
     assert not os.path.exists(out)
     code, _ = _get(daemon.address, "/healthz")

@@ -330,6 +330,31 @@ def test_persistent_genome_cache_roundtrip_and_invalidation(tmp_path):
         assert F.decode_seq(np.asarray(fr3.fetch_encoded(name))) == s
 
 
+def test_a_resident_reader_writes_its_sidecar_once(tmp_path, monkeypatch):
+    """The reader that encoded and persisted the genome serves the sidecar
+    from then on: a ``vctpu serve`` daemon keeps one reader for its life,
+    and every request's prefetch calls ``encode_all`` on it again."""
+    from variantcalling_tpu.io import fasta as F
+
+    p = tmp_path / "r.fa"
+    s = "ACGT" * 2000
+    with open(p, "wb") as fh:
+        fh.write(b">c\n")
+        for i in range(0, len(s), 60):
+            fh.write(s[i:i + 60].encode() + b"\n")
+    fr = F.FastaReader(str(p))
+    writes = []
+    persist = fr._persist_encoded
+    monkeypatch.setattr(fr, "_persist_encoded", lambda: writes.append(1) or persist())
+    before = np.array(fr.fetch_encoded("c"))
+    fr.encode_all()
+    assert writes == [1] and fr._venc is not None and not fr._encoded
+    fr.encode_all()  # the next request's prefetch
+    fr.encode_all()
+    assert writes == [1]
+    np.testing.assert_array_equal(fr.fetch_encoded("c"), before)
+
+
 def test_fetch_encoded_thread_safe_single_encode(tmp_path):
     from variantcalling_tpu.io import fasta as F
 

@@ -184,6 +184,14 @@ def device_genome(fasta: FastaReader, radius: int = WINDOW_RADIUS,
     return genome
 
 
+def device_genome_stats() -> dict:
+    """How many reference genomes the device holds, and their bytes
+    (``vctpu serve``: ``/v1/status``, ``/v1/warm``)."""
+    genomes = [g for _, g in _DEVICE_GENOME_CACHE.items()]
+    return {"entries": len(genomes),
+            "bytes": sum(int(g.blocks.nbytes) for g in genomes)}
+
+
 def _build_device_genome(fasta: FastaReader, radius: int,
                          sharding) -> DeviceGenome:
     gap = np.full(2 * radius, 4, dtype=np.uint8)

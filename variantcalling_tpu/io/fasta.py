@@ -288,8 +288,16 @@ class FastaReader:
             if cancel is not None and cancel.is_set():
                 return
             self.fetch_encoded(chrom)
-        if persist and not (cancel is not None and cancel.is_set()):
-            self._persist_encoded()
+        if persist and not (cancel is not None and cancel.is_set()) \
+                and self._persist_encoded():
+            # serve the sidecar just written from here on, as a later
+            # process would: a resident reader (vctpu serve) that kept its
+            # in-memory copy would find ``_venc`` unset at every request's
+            # prefetch and write the whole sidecar again
+            self._load_persistent_cache()
+            if self._venc is not None:
+                with self._enc_lock:
+                    self._encoded.clear()
 
     def _encode_contig(self, chrom: str) -> np.ndarray:
         """Whole-contig encode without the str round-trip: raw bytes ->

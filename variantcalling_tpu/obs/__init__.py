@@ -71,6 +71,7 @@ from __future__ import annotations
 
 import atexit
 import collections
+import contextvars
 import itertools
 import json
 import os
@@ -111,6 +112,16 @@ _RUN: "ObsRun | None" = None
 # dying process
 _LOCK = threading.RLock()
 
+#: the StageProfiler of the pipeline run this context belongs to, and the
+#: request it serves. Both ride the context the executor already copies
+#: into its workers (``parallel/pipeline.py``: ``IoPool.submit``,
+#: ``StagePipeline.run``), so two pipeline runs in flight under ONE obs run
+#: (two ``vctpu serve`` requests) each keep their own.
+_PROFILER: contextvars.ContextVar = contextvars.ContextVar(
+    "vctpu_obs_profiler", default=None)
+_REQUEST: "contextvars.ContextVar[request_scope | None]" = \
+    contextvars.ContextVar("vctpu_obs_request", default=None)
+
 #: trace-id spelling (``t<N>``, run-scoped) — obs.trace_of recognizes a
 #: bare id threaded through a stage-item tuple by this shape
 _TRACE_ID_RE = re.compile(r"^t\d+$")
@@ -141,10 +152,6 @@ class ObsRun:
         #: (``VCTPU_OBS_CPUPROF``, obs/sampler.py), owned the same way
         self.cpu_sampler = None
         self.jaxprof_dir: str | None = None
-        #: the StageProfiler of the pipeline run in flight (bound by
-        #: :func:`bind_profiler`): a ``trace.stage`` site deep in the
-        #: dispatch finds its attribution row here without a parameter
-        self.profiler = None
         #: the run's closed ``trace.stage`` spans in close order
         #: (``trace.report()``); bounded, so a daemon-long run keeps the
         #: newest and never grows
@@ -196,6 +203,10 @@ class ObsRun:
             # timestamped INSIDE the lock: file order == seq order == ts order
             t = self.now()
             event = dict(fields)  # extras first; the envelope wins on collision
+            request = _REQUEST.get()
+            if request is not None:
+                # everything a request's threads emit says whose it is
+                event.setdefault("req", request.req)
             event.update(v=SCHEMA_VERSION, seq=self._seq,
                          ts=round(self._t0_wall + t, 6), t=round(t, 6),
                          kind=kind, name=name, pid=pid, tid=tid)
@@ -603,6 +614,9 @@ def span(name: str, dur: float, thread: str, depth: int = 0, **fields) -> None:
         return
     run = _RUN
     if run is not None:
+        request = _REQUEST.get()
+        if request is not None and request.root is not None and not depth:
+            fields.setdefault("parent", request.root)
         run._emit("span", name, dict(fields, start=round(max(0.0, run.now() - dur), 6),
                                      dur=round(dur, 6), thread=thread,
                                      depth=depth))
@@ -753,25 +767,71 @@ def current() -> ObsRun | None:
     return _RUN
 
 
-class bind_profiler:
-    """Context manager: hang a pipeline run's StageProfiler on the open
-    obs run for the pipeline's duration (restores what was bound), so
-    ``trace.stage`` sites find their attribution rows without a
-    parameter. No-op without a run or a profiler."""
+def current_profiler():
+    """The StageProfiler bound to this context (None outside a pipeline
+    run, or with profiling off): where a ``trace.stage`` site deep in the
+    dispatch finds its attribution row without a parameter."""
+    return _PROFILER.get()
 
-    __slots__ = ("prof", "_run", "_prev")
+
+class bind_profiler:
+    """Context manager: bind a pipeline run's StageProfiler to the current
+    context for the pipeline's duration (restores what was bound). The
+    run's worker threads inherit it with the rest of the context. No-op
+    without a profiler."""
+
+    __slots__ = ("prof", "_token")
 
     def __init__(self, prof):
         self.prof = prof
+        self._token = None
 
     def __enter__(self):
-        self._run = _RUN if self.prof is not None else None
-        if self._run is not None:
-            self._prev = self._run.profiler
-            self._run.profiler = self.prof
+        if self.prof is not None:
+            self._token = _PROFILER.set(self.prof)
         return self.prof
 
     def __exit__(self, *exc):
-        if self._run is not None:
-            self._run.profiler = self._prev
+        if self._token is not None:
+            _PROFILER.reset(self._token)
+            self._token = None
         return False
+
+
+class request_scope:
+    """Context manager: bind a request (``vctpu serve``) to the current
+    context. While it is bound, every event the context's threads emit
+    carries ``req``, and a ``trace.stage`` span that is the outermost on
+    its thread names ``root`` (the request's root span) as its parent.
+    ``notes`` is what the code under the request tells the front about
+    it (:func:`request_note`); bound with obs on or off."""
+
+    __slots__ = ("req", "root", "notes", "_token")
+
+    def __init__(self, req: str, root: str | None = None):
+        self.req = req
+        self.root = root
+        self.notes: dict = {}
+        self._token = None
+
+    def __enter__(self):
+        self._token = _REQUEST.set(self)
+        return self
+
+    def __exit__(self, *exc):
+        _REQUEST.reset(self._token)
+        self._token = None
+        return False
+
+
+def current_request() -> "request_scope | None":
+    """The request bound to this context (None outside ``vctpu serve``)."""
+    return _REQUEST.get()
+
+
+def request_note(**fields) -> None:
+    """Tell the request this context serves (if any) something about its
+    run (``genome_resident=True`` from the fused dispatch)."""
+    request = _REQUEST.get()
+    if request is not None:
+        request.notes.update(fields)

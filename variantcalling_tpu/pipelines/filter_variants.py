@@ -13,6 +13,7 @@ host memory with one compile per chunk shape.
 from __future__ import annotations
 
 import argparse
+import logging
 import os
 import pickle
 import sys
@@ -612,6 +613,8 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
                     windows = gather_windows(table, fasta)
                 else:
                     gpos_fill = packed_position_fill(genome)
+        if genome_resident:
+            obs.request_note(genome_resident=True)  # serve counts such requests
         host_cols = tuple(_narrow_column(hf.cols[f])
                           for f in _host_names(hf.names))
 
@@ -1184,6 +1187,34 @@ class FilterContext:
         )
 
 
+def warm_program(model, fasta: FastaReader | None, flow_order: str = "TGCA",
+                 engine: engine_mod.EngineDecision | None = None) -> bool:
+    """Build (or find) the fused program that a plain request for ``model``
+    on ``fasta`` asks for — default flags, no annotation intervals — with
+    engine, strategy and mesh resolved as a run resolves them and the
+    window layout chosen by the rule a chunk follows
+    (``_genome_resident_worthwhile``: resident iff the genome is on the
+    device already). ``vctpu serve``'s ``/v1/warm``. False where such a
+    request runs no jitted program (native engine, a model outside the
+    fused families)."""
+    from variantcalling_tpu.featurize import (BASE_FEATURES,
+                                              _genome_resident_worthwhile,
+                                              genome_packable,
+                                              standard_genome_sharding)
+
+    ctx = FilterContext(model, fasta, flow_order=flow_order, engine=engine)
+    if ctx.engine.name != "jit" or not isinstance(model, _FUSED_MODEL_TYPES):
+        return False
+    mesh = ctx.mesh
+    resident = fasta is not None and genome_packable(fasta) \
+        and _genome_resident_worthwhile(
+            (), fasta, sharding=standard_genome_sharding(mesh))
+    _fused_program(model, list(BASE_FEATURES), flow_order,
+                   genome_resident=resident, strategy=ctx._pinned_strategy(),
+                   mesh=mesh)
+    return True
+
+
 def filter_variants(
     table: VariantTable,
     model,
@@ -1453,8 +1484,9 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
     # worker pools (shard inflate / chunk parse) attribute their work
     # from the very first shard; the executor feeds per-stage work/
     # queue-wait/backpressure into the same profile, every trace.stage
-    # span finds it on the obs run, and the stream adds the IO byte
-    # totals. One emit at commit time -> `vctpu obs bottleneck` names the
+    # span finds it in the context this run's threads share (so two runs
+    # in flight under one obs run keep their own), and the stream adds
+    # the IO byte totals. One emit at commit time -> `vctpu obs bottleneck` names the
     # limiting stage (ROADMAP item 1).
     from variantcalling_tpu.obs import profile as profile_mod
 
@@ -2292,7 +2324,10 @@ def run_loaded(args, model, fasta: FastaReader, annotate, blacklist,
             logger.error("%s", e)
             return 2
         if stats is not None:
-            logger.debug("%s", report())
+            if logger.isEnabledFor(logging.DEBUG):
+                # formatted only when asked for: a resident process's span
+                # table holds thousands of spans of hundreds of threads
+                logger.debug("%s", report())
             logger.info("wrote %s: %d variants, %d PASS (engine %s)",
                         args.output_file, stats["n"], stats["n_pass"],
                         stats["engine"])
@@ -2378,7 +2413,8 @@ def run_loaded(args, model, fasta: FastaReader, annotate, blacklist,
         # assembly can splice FILTER/TREE_SCORE between original byte spans
         write_vcf(args.output_file, table, new_filters=filters,
                   extra_info={"TREE_SCORE": np.round(score, 4)}, verbatim_core=True)
-    logger.debug("%s", report())
+    if logger.isEnabledFor(logging.DEBUG):
+        logger.debug("%s", report())
     logger.info(
         "wrote %s: %d variants, %d PASS", args.output_file, len(table), int(np.sum(filters == PASS))
     )

@@ -20,6 +20,7 @@ The policy (docs/serving.md "Admission and shedding"):
   executing trips its cancel token (chunk-granular, utils/cancellation).
 
 Metrics every decision feeds (the ``vctpu obs prom`` request series):
+the ``serve_admit`` span (field ``queued``: it had to wait),
 ``serve.in_flight`` / ``serve.queued`` gauges,
 ``serve.requests_{accepted,shed,…}.by_endpoint.*`` counters, and the
 per-endpoint rolling-quantile histograms the early-shed reads.
@@ -31,6 +32,7 @@ import threading
 import time
 
 from variantcalling_tpu import knobs
+from variantcalling_tpu.utils.trace import stage
 
 
 class ShedError(Exception):
@@ -96,43 +98,47 @@ class AdmissionController:
         draining sheds, :class:`QueueDeadlineError` when the deadline
         expires first. The caller MUST call the returned release exactly
         once (a ``finally`` away from the request body)."""
-        if self.draining:
-            raise ShedError("draining")
-        # a free execution slot admits immediately — the bounded queue
-        # (and its depth/SLO checks) only governs requests that must WAIT
-        if self._slots.acquire(blocking=False):
-            with self._lock:
-                self._inflight += 1
-        else:
-            with self._lock:
-                if self._queued >= self.queue_depth:
-                    raise ShedError("queue_full")
-                if deadline_s is not None:
-                    est = self._estimated_wait_s(endpoint, self._queued,
-                                                 self._inflight)
-                    if est is not None and est > deadline_s:
-                        # admitting would only burn a queue slot on a
-                        # request the deadline already condemned — shed
-                        # with the honest wait estimate as the retry hint
-                        raise ShedError("slo", retry_after_s=round(est, 3))
-                self._queued += 1
-            t0 = time.monotonic()
-            try:
-                ok = self._slots.acquire(
-                    timeout=deadline_s if deadline_s is not None else None)
-            finally:
-                with self._lock:
-                    self._queued -= 1
-            if not ok:
-                raise QueueDeadlineError(
-                    f"deadline ({deadline_s:.1f}s) expired after "
-                    f"{time.monotonic() - t0:.1f}s in the admission queue")
+        # one span per ADMITTED request (a refused one raises through it and
+        # records none): how long the request stood before it held a slot
+        with stage("serve_admit", endpoint=endpoint, queued=False) as span:
             if self.draining:
-                # drain began while we waited: give the slot back unused
-                self._slots.release()
                 raise ShedError("draining")
-            with self._lock:
-                self._inflight += 1
+            # a free execution slot admits immediately — the bounded queue
+            # (and its depth/SLO checks) only governs requests that must WAIT
+            if self._slots.acquire(blocking=False):
+                with self._lock:
+                    self._inflight += 1
+            else:
+                with self._lock:
+                    if self._queued >= self.queue_depth:
+                        raise ShedError("queue_full")
+                    if deadline_s is not None:
+                        est = self._estimated_wait_s(endpoint, self._queued,
+                                                     self._inflight)
+                        if est is not None and est > deadline_s:
+                            # admitting would only burn a queue slot on a
+                            # request the deadline already condemned — shed
+                            # with the honest wait estimate as the retry hint
+                            raise ShedError("slo", retry_after_s=round(est, 3))
+                    self._queued += 1
+                span.set(queued=True)
+                t0 = time.monotonic()
+                try:
+                    ok = self._slots.acquire(
+                        timeout=deadline_s if deadline_s is not None else None)
+                finally:
+                    with self._lock:
+                        self._queued -= 1
+                if not ok:
+                    raise QueueDeadlineError(
+                        f"deadline ({deadline_s:.1f}s) expired after "
+                        f"{time.monotonic() - t0:.1f}s in the admission queue")
+                if self.draining:
+                    # drain began while we waited: give the slot back unused
+                    self._slots.release()
+                    raise ShedError("draining")
+                with self._lock:
+                    self._inflight += 1
 
         released = threading.Event()
 

@@ -15,8 +15,9 @@ Endpoints (request lifecycle + failure matrix: docs/serving.md):
   score summary statistics.
 - ``POST /v1/coverage`` — the single-pass coverage reduce over an
   inline depth vector.
-- ``POST /v1/warm``     — preload a model + reference into the resident
-  caches (the cold/warm split ``bench.py serve`` measures).
+- ``POST /v1/warm``     — make a model + reference resident: the host
+  objects, the reference genome on the device and the model's fused
+  program, so that a request of any size finds them there.
 - ``GET /healthz`` ``GET /v1/status`` ``GET /v1/metrics`` — liveness,
   admission/cache introspection, Prometheus text exposition.
 
@@ -45,6 +46,7 @@ from variantcalling_tpu.serve.admission import (AdmissionController,
 from variantcalling_tpu.serve.metrics import ServeMetrics
 from variantcalling_tpu.serve.state import ResidentState
 from variantcalling_tpu.utils import cancellation, faults
+from variantcalling_tpu.utils.trace import stage
 
 #: knob names a request may NOT override: scoping these per request
 #: would change daemon-global machinery mid-flight (the serve topology
@@ -158,6 +160,9 @@ class Server:
         elif obs.enabled():
             self._obs_run = obs.start_run(
                 "serve", default_path=os.path.abspath("vctpu_serve.obs.jsonl"))
+        # declared up front, so that a daemon none of whose requests found
+        # its genome on the device reads 0 and not "no such counter"
+        self.metrics.add("serve.requests_genome_resident", 0)
         handler = _make_handler(self)
         if self.socket_path:
             with contextlib.suppress(OSError):
@@ -259,12 +264,32 @@ class Server:
 
     # -- request execution --------------------------------------------------
 
-    def execute(self, endpoint: str, body: dict) -> tuple[int, dict]:
+    def execute(self, endpoint: str, body: dict,
+                respond=None) -> tuple[int, dict]:
         """One pipeline request end to end: admission -> isolation scope
-        -> pipeline -> (HTTP status, JSON payload). Never raises — every
+        -> pipeline -> (HTTP status, JSON payload), handed to ``respond``
+        (the transport's writer) where one is given. Never raises — every
         failure maps to a per-request response; only the transport layer
-        above can fail past this point."""
+        above can fail past this point.
+
+        The request is bound to the context (``obs.request_scope``), so
+        every event and span below carries its ``req``; ``serve_request``
+        is the root span, ``serve_admit`` / ``serve_state`` /
+        ``serve_respond`` and the pipeline's own spans lie under it."""
         req = f"r{next(self._req_n)}"
+        with obs.request_scope(req, root="serve_request") as scope, \
+                stage("serve_request", endpoint=endpoint) as root:
+            code, payload = self._run_request(endpoint, body, req)
+            if scope.notes.get("genome_resident"):
+                self.metrics.add("serve.requests_genome_resident")
+            root.set(status=payload.get("status"), code=code)
+            if respond is not None:
+                with stage("serve_respond", code=code):
+                    respond(code, payload)
+        return code, payload
+
+    def _run_request(self, endpoint: str, body: dict,
+                     req: str) -> tuple[int, dict]:
         deadline_s = body.get("deadline_s", self.default_deadline_s)
         try:
             deadline_s = float(deadline_s) if deadline_s else None
@@ -384,14 +409,15 @@ class Server:
 
         if not body.get("output"):
             raise RequestError("missing required field 'output'")
-        args = _filter_namespace(body, output_file=body["output"])
-        eng = engine_mod.resolve_request()
-        model = self.state.get_model(args.model_file, args.model_name)
-        fasta = self.state.get_fasta(args.reference_file)
-        annotate = {fv._interval_name(p): _read_intervals(p)
-                    for p in args.annotate_intervals}
-        blacklist = fv.read_blacklist(args.blacklist) if args.blacklist \
-            else None
+        with stage("serve_state"):
+            args = _filter_namespace(body, output_file=body["output"])
+            eng = engine_mod.resolve_request()
+            model = self.state.get_model(args.model_file, args.model_name)
+            fasta = self.state.get_fasta(args.reference_file)
+            annotate = {fv._interval_name(p): _read_intervals(p)
+                        for p in args.annotate_intervals}
+            blacklist = fv.read_blacklist(args.blacklist) if args.blacklist \
+                else None
         rc = fv.run_loaded(args, model, fasta, annotate, blacklist,
                            engine=eng)
         if rc != 0:
@@ -405,14 +431,15 @@ class Server:
         from variantcalling_tpu.io.vcf import read_vcf
         from variantcalling_tpu.pipelines import filter_variants as fv
 
-        args = _filter_namespace(body, output_file=None)
-        eng = engine_mod.resolve_request()
-        model = self.state.get_model(args.model_file, args.model_name)
-        fasta = self.state.get_fasta(args.reference_file)
+        with stage("serve_state"):
+            args = _filter_namespace(body, output_file=None)
+            eng = engine_mod.resolve_request()
+            model = self.state.get_model(args.model_file, args.model_name)
+            fasta = self.state.get_fasta(args.reference_file)
+            ctx = fv.FilterContext(model, fasta, flow_order=args.flow_order,
+                                   is_mutect=args.is_mutect, engine=eng)
         table = read_vcf(args.input_file)
         cancellation.check("score request")
-        ctx = fv.FilterContext(model, fasta, flow_order=args.flow_order,
-                               is_mutect=args.is_mutect, engine=eng)
         score, filters = ctx.score_table(table)
         cancellation.check("score request")
         return 200, {"status": "ok", "n": int(len(table)),
@@ -446,24 +473,51 @@ class Server:
                             "p95": int(stats["percentiles"][2])}}
 
     def _do_warm(self, body: dict, req: str) -> tuple[int, dict]:
+        """Make what the body names resident, and say what now is: a
+        ``reference`` as host reader, ``.venc`` sidecar and — where
+        requests score on the jit engine — the genome on the device, under
+        the key ``featurize._genome_resident_worthwhile`` looks up, so a
+        request of any size then gathers its windows there; a ``model``
+        as host object and as the fused program a plain request on that
+        reference asks for (its executables still compile or load at each
+        bucket size's first call)."""
+        from variantcalling_tpu import featurize
+        from variantcalling_tpu.pipelines import filter_variants as fv
+
+        for field in ("model", "reference"):
+            if body.get(field) and not os.path.exists(body[field]):
+                raise RequestError(f"{field} path does not exist: "
+                                   f"{body[field]}")
+        eng = engine_mod.resolve_request()
         warmed = []
-        if body.get("model") and body.get("model_name"):
-            if not os.path.exists(body["model"]):
-                raise RequestError(f"model path does not exist: "
-                                   f"{body['model']}")
-            self.state.get_model(body["model"], body["model_name"])
-            warmed.append("model")
+        fasta = None
         if body.get("reference"):
-            if not os.path.exists(body["reference"]):
-                raise RequestError(f"reference path does not exist: "
-                                   f"{body['reference']}")
             fasta = self.state.get_fasta(body["reference"])
             fasta.encode_all()  # persist/load the .venc sidecar now
             warmed.append("reference")
+            if eng.name == "jit" and featurize.genome_packable(fasta):
+                featurize.device_genome(
+                    fasta, sharding=featurize.standard_genome_sharding())
+                warmed.append("device_genome")
+        if body.get("model") and body.get("model_name"):
+            model = self.state.get_model(body["model"], body["model_name"])
+            warmed.append("model")
+            if fv.warm_program(model, fasta, engine=eng,
+                               flow_order=body.get("flow_order", "TGCA")):
+                warmed.append("program")
         if not warmed:
             raise RequestError("nothing to warm: pass model+model_name "
                                "and/or reference")
-        return 200, {"status": "ok", "warmed": warmed}
+        return 200, {"status": "ok", "warmed": warmed,
+                     "resident": self.resident_payload()}
+
+    def resident_payload(self) -> dict:
+        """What the daemon holds: host models and readers
+        (``state.stats()``) and the genomes on the device."""
+        resident = self.state.stats()
+        self.metrics.set_gauge("serve.device_genomes",
+                               resident["device_genomes"]["entries"])
+        return resident
 
     # -- introspection payloads --------------------------------------------
 
@@ -484,7 +538,7 @@ class Server:
             "max_inflight": self.admission.max_inflight,
             "queue_depth": self.admission.queue_depth,
             "endpoints": per_endpoint,
-            "resident": self.state.stats(),
+            "resident": self.resident_payload(),
             "cache": _chunk_cache_stats(),
         }
 
@@ -655,8 +709,15 @@ def _make_handler(server: Server):
                 self._respond(400, {"status": "bad_request",
                                     "error": f"malformed request: {e}"})
                 return
+            sent = []
+
+            def respond(code: int, payload: dict) -> None:
+                sent.append(code)
+                self._respond(code, payload,
+                              retry_after_s=payload.get("retry_after_s"))
+
             try:
-                code, payload = server.execute(endpoint, body)
+                server.execute(endpoint, body, respond=respond)
             # belt and braces under the isolation boundary: a bug in the
             # serve layer itself must still produce a response — a
             # handler thread dying silently leaves the client hanging,
@@ -665,10 +726,9 @@ def _make_handler(server: Server):
             except BaseException as e:  # noqa: BLE001  # vctpu-lint: disable=VCT002 — transport-level last resort: reported to the client as a 500, logged; never silent
                 logger.warning("serve: internal error handling %s: %s: %s",
                                endpoint, type(e).__name__, e)
-                code, payload = 500, {"status": "error",
-                                      "kind": type(e).__name__,
-                                      "error": str(e)[:2000]}
-            self._respond(code, payload,
-                          retry_after_s=payload.get("retry_after_s"))
+                if not sent:
+                    respond(500, {"status": "error",
+                                  "kind": type(e).__name__,
+                                  "error": str(e)[:2000]})
 
     return Handler

@@ -36,24 +36,27 @@ class ServeMetrics:
 
     # -- recording ----------------------------------------------------------
 
-    def _counter(self, name: str):
-        self.registry.counter(name).add(1)
-        obs.counter(name).add(1)  # no-op when obs is off
+    def add(self, name: str, n: int = 1) -> None:
+        """One counter, in both sinks (``add(name, 0)`` declares it)."""
+        self.registry.counter(name).add(n)
+        obs.counter(name).add(n)  # no-op when obs is off
 
     def count(self, endpoint: str, status: str) -> None:
-        self._counter(f"serve.requests_{status}")
-        self._counter(f"serve.requests_{status}.by_endpoint.{endpoint}")
+        self.add(f"serve.requests_{status}")
+        self.add(f"serve.requests_{status}.by_endpoint.{endpoint}")
 
     def observe_latency(self, endpoint: str, dur_s: float) -> None:
         self.registry.histogram(
             f"serve.request_s.by_endpoint.{endpoint}").observe(dur_s)
         obs.histogram(f"serve.request_s.by_endpoint.{endpoint}").observe(dur_s)
 
+    def set_gauge(self, name: str, value: float) -> None:
+        self.registry.gauge(name).set(value)
+        obs.gauge(name).set(value)
+
     def set_load(self, in_flight: int, queued: int) -> None:
-        self.registry.gauge("serve.in_flight").set(in_flight)
-        self.registry.gauge("serve.queued").set(queued)
-        obs.gauge("serve.in_flight").set(in_flight)
-        obs.gauge("serve.queued").set(queued)
+        self.set_gauge("serve.in_flight", in_flight)
+        self.set_gauge("serve.queued", queued)
 
     # -- reading (admission + status endpoints) -----------------------------
 

@@ -85,5 +85,8 @@ class ResidentState:
         return self._fastas.get(key, lambda: FastaReader(reference_file))
 
     def stats(self) -> dict:
+        from variantcalling_tpu.featurize import device_genome_stats
+
         return {"models": self._models.stats(),
-                "genomes": self._fastas.stats()}
+                "genomes": self._fastas.stats(),
+                "device_genomes": device_genome_stats()}

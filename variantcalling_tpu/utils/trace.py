@@ -12,7 +12,8 @@ makes tracing first-class here:
   reader (docs/observability.md "Writing new instrumentation"):
 
   * a ``jax.profiler.TraceAnnotation("vctpu:<name>", trace=<chunk trace
-    id>, thread=<python thread name>)``, so the span lands in the
+    id>, thread=<python thread name>)`` (and ``req=<request id>`` under a
+    ``vctpu serve`` request), so the span lands in the
     profiler's host plane on the DEVICE TRACE'S CLOCK whenever a
     ``jax.profiler`` trace is being taken (the keyword arguments arrive as
     event stats; all Python threads' lines are named ``python`` there, so
@@ -20,8 +21,11 @@ makes tracing first-class here:
   * one obs ``span`` event with an explicit ``start`` (the stream's ``t``
     clock, taken at entry, outside the stream's lock), ``dur``,
     ``thread``, ``depth``, ``parent`` (the enclosing ``stage`` on this
-    thread) and ``trace_id`` (the chunk's causal trace);
-  * the run's :class:`~variantcalling_tpu.obs.profile.StageProfiler` row
+    thread; for a thread's outermost span under a request, the request's
+    root span), ``trace_id`` (the chunk's causal trace) and, under a
+    request, ``req``;
+  * the :class:`~variantcalling_tpu.obs.profile.StageProfiler` row of the
+    pipeline run this context belongs to (``obs.current_profiler()``),
     ``<name>.w<idx>`` on a pooled worker (``<name>`` elsewhere), with the
     parent's name on the row, and the histogram ``stage.<name>.s``;
   * with ``causal=True`` the chunk's causal ``trace`` span, fed from the
@@ -102,7 +106,7 @@ _NOOP = _NoSpan()
 
 class _LiveSpan:
     __slots__ = ("name", "fields", "causal", "trace_id", "thread", "parent",
-                 "run", "start", "seconds", "_ann")
+                 "request", "run", "start", "seconds", "_ann")
 
     def __init__(self, run, name: str, trace: str | None, causal: bool,
                  fields: dict):
@@ -125,12 +129,15 @@ class _LiveSpan:
         self.thread = threading.current_thread().name
         if self.trace_id is None:
             self.trace_id = obs.current_trace()
+        request = self.request = obs.current_request()
         self._ann = None
         jax = sys.modules.get("jax")  # never the reason jax gets imported
         if jax is not None:
+            stats = {"trace": self.trace_id or "", "thread": self.thread}
+            if request is not None:
+                stats["req"] = request.req
             self._ann = jax.profiler.TraceAnnotation(
-                ANNOTATION_PREFIX + self.name, trace=self.trace_id or "",
-                thread=self.thread)
+                ANNOTATION_PREFIX + self.name, **stats)
             self._ann.__enter__()
         self.start = self.run.now()
         return self
@@ -150,13 +157,19 @@ class _LiveSpan:
         fields = self.fields
         body = dict(fields, start=round(self.start, 6), dur=round(dur, 6),
                     thread=self.thread, depth=depth)
+        request = self.request
         if self.parent is not None:
             body["parent"] = self.parent
+        elif request is not None and request.root not in (None, name):
+            # the outermost span of a worker thread: the request's root
+            # span is its ancestor (the attribution row keeps no parent:
+            # there it means "a part of another row's work")
+            body["parent"] = request.root
         if self.trace_id is not None:
             body["trace_id"] = self.trace_id
         run._emit("span", name, body)
         run.metrics.histogram(f"stage.{name}.s").observe(dur)
-        prof = run.profiler
+        prof = obs.current_profiler()
         if prof is not None:
             worker = _WORKER_RE.search(self.thread)
             row = f"{name}.{worker.group(1)}" if worker else name
