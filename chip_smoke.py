@@ -414,14 +414,14 @@ class Smoke:
                 f"dp=4 run header says {header_value(data, 'vctpu_mesh')!r}")
         # the genome: resident once, replicated over the four devices
         resident = [g for k, g in featurize._DEVICE_GENOME_CACHE.items()
-                    if k[0] == self.ref and len(g.blocks.sharding.device_set) == 4]
+                    if k[0] == self.ref and len(g.rows.sharding.device_set) == 4]
         require(len(resident) == 1,
                 f"{len(resident)} four-device genome entries resident (want 1: "
                 "uploaded once, not per megabatch)")
-        blocks = resident[0].blocks
-        require(blocks.sharding.is_fully_replicated,
-                f"genome sharding {blocks.sharding} is not replicated")
-        genome_bytes = int(blocks.nbytes)
+        rows = resident[0].rows
+        require(rows.sharding.is_fully_replicated,
+                f"genome sharding {rows.sharding} is not replicated")
+        genome_bytes = int(rows.nbytes)
         # evidence that all four chips held and ran a shard: a peak well
         # above the replicated genome is shard inputs + kernel temporaries
         peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))

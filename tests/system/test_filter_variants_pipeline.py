@@ -165,7 +165,7 @@ def test_filter_pipeline_single_contig(synthetic_world):
 
 def test_genome_resident_scoring_matches_host_windows(tmp_path, rng):
     """The device-resident-genome window gather must score identically to
-    the host window path (featurize.device_genome / windows_on_device)."""
+    the host window path (featurize.device_genome / windows_from_packed)."""
     import bench
     from variantcalling_tpu.featurize import host_featurize
     from variantcalling_tpu.io.fasta import FastaReader
@@ -185,17 +185,20 @@ def test_genome_resident_scoring_matches_host_windows(tmp_path, rng):
     np.testing.assert_allclose(s_host, s_dev, atol=1e-6)
 
 
-def test_globalize_positions_int32_safe_at_hg38_scale():
-    """Global coordinates past 2^31 must decompose exactly into int32
-    (block, offset) pairs — jax without x64 truncates int64 device arrays."""
-    from variantcalling_tpu.featurize import (_GBLOCK, DeviceGenome, GENOME_BLOCK_BITS,
-                                              globalize_positions)
+def test_globalize_positions_exact_past_int32_at_hg38_scale():
+    """Global byte positions past 2^31 must come out exact in the ONE uint32
+    a record puts on the wire — jax without x64 has no int64, and int32
+    ends at 2.1e9 where hg38 with its gaps ends at 3.2e9."""
+    from variantcalling_tpu.featurize import (GENOME_ROW_BYTES, DeviceGenome,
+                                              globalize_positions,
+                                              packed_position_fill)
     from variantcalling_tpu.io.vcf import VariantTable, VcfHeader
 
     big = 3_100_000_000  # chrX-at-end-of-hg38 scale global offset
-    genome = DeviceGenome(blocks=np.empty((big // _GBLOCK + 10, 0), dtype=np.uint8),
+    n_rows = (big + 60_000_000) // GENOME_ROW_BYTES + 1
+    genome = DeviceGenome(rows=np.empty((n_rows, 0), dtype=np.uint32),
                           offsets={"chrX": big, "chr1": 40},
-                          lengths={"chrX": 50_000_000, "chr1": 1_000}, flat=False)
+                          lengths={"chrX": 50_000_000, "chr1": 1_000})
     n = 5
     table = VariantTable(
         header=VcfHeader(),
@@ -205,16 +208,17 @@ def test_globalize_positions_int32_safe_at_hg38_scale():
         alt=np.array(["G"] * n, dtype=object), qual=np.zeros(n),
         filters=np.array(["PASS"] * n, dtype=object), info=np.array(["."] * n, dtype=object),
     )
-    blk, off = globalize_positions(table, genome)
-    assert blk.dtype == np.int32 and off.dtype == np.int32
-    recon = blk.astype(np.int64) * _GBLOCK + off
-    assert recon[0] == big + 0
-    assert recon[1] == big + 49_999_998
-    assert recon[2] == 40 + 499
-    assert recon[4] == big + 7_654_320
-    # unknown contig resolves past the genome end (all-N window)
-    assert blk[3] >= genome.blocks.shape[0]
-    assert (1 << GENOME_BLOCK_BITS) == _GBLOCK
+    gpos = globalize_positions(table, genome)
+    assert gpos.dtype == np.uint32 and gpos.shape == (n,)
+    assert int(gpos[0]) == big + 0 and int(gpos[0]) > np.iinfo(np.int32).max
+    assert int(gpos[1]) == big + 49_999_998
+    assert int(gpos[2]) == 40 + 499
+    assert int(gpos[4]) == big + 7_654_320
+    # unknown contig resolves past the genome end (all-N window), and the
+    # row its window would start in is still an int32-safe number
+    assert int(gpos[3]) == packed_position_fill(genome) == n_rows * GENOME_ROW_BYTES
+    assert (int(gpos[3]) - 20) // GENOME_ROW_BYTES >= n_rows - 1
+    assert n_rows < (1 << 23)
 
 
 def test_fused_narrow_columns_bit_identical_to_f32_matrix(tmp_path):

@@ -3,6 +3,8 @@ import pytest
 
 import jax.numpy as jnp
 
+from variantcalling_tpu.featurize import GENOME_ROW_BYTES as _ROW
+from variantcalling_tpu.featurize import WINDOW_RADIUS as _R
 from variantcalling_tpu.io.fasta import encode_seq
 from variantcalling_tpu.ops import features as fops
 from variantcalling_tpu.ops import intervals as iops
@@ -114,57 +116,213 @@ def test_interval_membership_and_distance():
     assert iops.membership(g2, np.array([3_000_000_000]), np.array([3_000_001_000]))[0]
 
 
-def test_blocked_genome_packed_positions_round_trip():
-    """hg38-scale (flat=False) genomes: pack -> device unpack must land on
-    the same (block, offset) gather as the unpacked path, the pad fill must
-    read all-N, and over-large genomes must refuse to pack. The small-
-    fixture tests all take the flat branch, so the blocked arithmetic is
-    exercised here with a synthetic 2-D block array."""
-    import jax.numpy as jnp
+_GATHER_ROWS = 1024
 
-    from variantcalling_tpu.featurize import (_GBLOCK, DeviceGenome,
-                                              GENOME_BLOCK_BITS,
-                                              pack_global_positions,
+
+def _table(chrom, pos):
+    """A VariantTable of SNP records at (chrom, 1-based pos)."""
+    from variantcalling_tpu.io.vcf import VariantTable, VcfHeader
+
+    n = len(pos)
+    return VariantTable(
+        header=VcfHeader(), chrom=np.array(chrom, dtype=object),
+        pos=np.array(pos, dtype=np.int64),
+        vid=np.array(["."] * n, dtype=object), ref=np.array(["A"] * n, dtype=object),
+        alt=np.array(["G"] * n, dtype=object), qual=np.zeros(n),
+        filters=np.array(["PASS"] * n, dtype=object), info=np.array(["."] * n, dtype=object))
+
+
+@pytest.fixture(scope="module")
+def row_genome():
+    """A synthetic genome in the device layout: 2 MiB + 300 bytes of random
+    codes (so windows cross the old 2^20-byte block edge and the last real
+    row is followed by a trailing pad), the leading gap of N every built
+    genome has, and the jitted gather at one padded shape."""
+    import jax
+
+    from variantcalling_tpu.featurize import (DeviceGenome, _genome_rows,
                                               packed_position_fill,
-                                              windows_from_packed,
-                                              windows_on_device)
+                                              windows_from_packed)
 
     rng = np.random.default_rng(3)
-    n_blocks = 4
-    blocks = rng.integers(0, 4, size=(n_blocks, _GBLOCK)).astype(np.uint8)
-    genome = DeviceGenome(blocks=blocks, offsets={}, lengths={}, flat=False)
-
-    # positions spread across block boundaries (incl. within-radius edges)
-    gpos = np.asarray([0, 25, _GBLOCK - 1, _GBLOCK, _GBLOCK + 7,
-                       2 * _GBLOCK - 3, 3 * _GBLOCK + 11, 4 * _GBLOCK - 21],
-                      dtype=np.int64)
-    blk = (gpos >> GENOME_BLOCK_BITS).astype(np.int32)
-    off = (gpos & (_GBLOCK - 1)).astype(np.int32)
-
-    packed = pack_global_positions(blk, off, genome)
-    assert packed is not None and packed.dtype == np.uint32
-    w_packed = np.asarray(windows_from_packed(jnp.asarray(blocks), jnp.asarray(packed)))
-    w_pair = np.asarray(windows_on_device(jnp.asarray(blocks), jnp.asarray(blk), jnp.asarray(off)))
-    np.testing.assert_array_equal(w_packed, w_pair)
-
-    # direct numpy expectation from the flattened genome
-    flat = blocks.reshape(-1)
-    r = 20
-    for i, p in enumerate(gpos):
-        idx = np.arange(p - r, p + r + 1)
-        exp = np.where((idx >= 0) & (idx < len(flat)), flat[np.clip(idx, 0, len(flat) - 1)], 4)
-        np.testing.assert_array_equal(w_packed[i], exp)
-
-    # pad fill unpacks past the end -> all-N
+    flat = rng.integers(0, 5, size=(2 << 20) + 300).astype(np.uint8)
+    flat[:2 * _R] = 4
+    genome = DeviceGenome(_genome_rows([flat]), offsets={"c1": 2 * _R},
+                          lengths={"c1": len(flat) - 2 * _R})
     fill = packed_position_fill(genome)
-    w_fill = np.asarray(windows_from_packed(
-        jnp.asarray(blocks), jnp.asarray(np.asarray([fill], dtype=np.uint32))))
-    np.testing.assert_array_equal(w_fill, np.full((1, 2 * r + 1), 4))
+    gather = jax.jit(windows_from_packed)
+    rows = jnp.asarray(genome.rows)
 
-    # genomes whose packed range exceeds 2^32 refuse to pack
-    too_big = DeviceGenome(blocks=np.empty((5000, 0), dtype=np.uint8),
-                           offsets={}, lengths={}, flat=False)
-    assert pack_global_positions(blk, off, too_big) is None
+    def windows(gpos):
+        gpos = np.asarray(gpos, dtype=np.uint32)
+        assert len(gpos) <= _GATHER_ROWS
+        padded = np.full(_GATHER_ROWS, fill, dtype=np.uint32)
+        padded[:len(gpos)] = gpos
+        return np.asarray(gather(rows, jnp.asarray(padded)))[:len(gpos)]
+
+    return flat, genome, windows
+
+
+def _flat_expectation(flat, gpos):
+    idx = np.asarray(gpos, dtype=np.int64)[:, None] + np.arange(-_R, _R + 1)[None, :]
+    ok = (idx >= 0) & (idx < len(flat))
+    return np.where(ok, flat[np.clip(idx, 0, len(flat) - 1)], 4).astype(np.uint8)
+
+
+def _bad_positions(flat, genome):
+    """What globalize_positions gives records with no place in the genome:
+    an unknown contig, position 0, and one ``radius`` past the contig's
+    end — beside one within ``radius`` of the end, which keeps its place."""
+    from variantcalling_tpu.featurize import globalize_positions
+
+    clen = genome.lengths["c1"]
+    table = _table(["chrUn", "c1", "c1", "c1"], [100, 0, clen + _R + 1, clen + 5])
+    gpos = globalize_positions(table, genome)
+    assert gpos.dtype == np.uint32
+    assert gpos[3] == 2 * _R + clen + 4  # within radius of the end: resolved
+    return gpos
+
+
+_GATHER_CASES = {
+    # start = gpos - R at each of the four byte alignments within a word
+    "byte_alignment_0": lambda flat, g: [_R + 4000],
+    "byte_alignment_1": lambda flat, g: [_R + 4001],
+    "byte_alignment_2": lambda flat, g: [_R + 4002],
+    "byte_alignment_3": lambda flat, g: [_R + 4003],
+    # every start from 44 bytes before a row's end to its successor's third byte
+    "crossing_a_512_byte_row": lambda flat, g: np.arange(7 * _ROW - 44, 7 * _ROW + 3) + _R,
+    "crossing_the_old_2_20_block": lambda flat, g: np.arange((1 << 20) - 45, (1 << 20) + 45),
+    "first_window": lambda flat, g: [_R, _R + 1, _R + 2, _R + 3],
+    # the window begins in the last real row and runs into the closing row of N
+    "last_real_row": lambda flat, g: np.arange(len(flat) - 60, len(flat) + _R),
+    "inside_the_trailing_pad": lambda flat, g: np.arange(len(flat) + _R, (g.rows.shape[0] - 1) * _ROW + 2 * _R),
+    "packed_position_fill": lambda flat, g: [g.rows.shape[0] * _ROW],
+    "bad_positions_of_globalize": _bad_positions,
+    "random_1000": lambda flat, g: np.random.default_rng(11).integers(_R, len(flat) + 600, size=1000),
+}
+
+
+@pytest.mark.parametrize("case", list(_GATHER_CASES))
+def test_row_gather_matches_flat_numpy(row_genome, case):
+    """The device gather — two whole-row lookups, barrel shift, funnel
+    shift, byte split — against plain indexing of the flat genome, where
+    out-of-range bytes read N."""
+    from variantcalling_tpu.featurize import packed_position_fill
+
+    flat, genome, windows = row_genome
+    gpos = np.asarray(_GATHER_CASES[case](flat, genome), dtype=np.int64)
+    assert len(gpos) and gpos.min() >= _R
+    got = windows(gpos)
+    assert got.shape == (len(gpos), 2 * _R + 1) and got.dtype == np.uint8
+    np.testing.assert_array_equal(got, _flat_expectation(flat, gpos))
+    if case in ("packed_position_fill", "inside_the_trailing_pad"):
+        assert (got == 4).all()
+    if case == "bad_positions_of_globalize":
+        assert (gpos[:3] == packed_position_fill(genome)).all() and (got[:3] == 4).all()
+        assert (got[3] != 4).any()
+
+
+def test_genome_rows_layout_shares_the_one_concatenation():
+    """Byte p of the genome is bits 8*(p&3) of word p>>2; whole rows, closed
+    by one row of N; the word view is the concatenation itself, not a copy."""
+    from variantcalling_tpu.featurize import GENOME_ROW_WORDS, _genome_rows
+
+    parts = [np.full(40, 4, np.uint8), np.arange(1000, dtype=np.uint8) % 4]
+    rows = _genome_rows(parts)
+    assert rows.dtype == np.uint32 and rows.shape == (4, GENOME_ROW_WORDS)
+    assert rows.base is not None and not rows.flags.owndata  # a view
+    flat = np.concatenate(parts)
+    p = np.arange(len(flat))
+    np.testing.assert_array_equal((rows.reshape(-1)[p >> 2] >> (8 * (p & 3))) & 0xFF, flat)
+    assert (rows[-1] == 0x04040404).all()
+    assert (rows.reshape(-1).view(np.uint8)[len(flat):] == 4).all()
+
+
+def test_device_gather_equals_host_gather_on_multi_contig_fasta(tmp_path):
+    """windows_from_packed over device_genome == gather_windows (host), row
+    for row: three contigs of unlike lengths, first and last bases, a
+    position within ``radius`` past a contig's end, one past that, and a
+    contig the FASTA does not have."""
+    from variantcalling_tpu.featurize import (device_genome, gather_windows,
+                                              globalize_positions,
+                                              windows_from_packed)
+    from variantcalling_tpu.io.fasta import FastaReader
+
+    rng = np.random.default_rng(5)
+    lens = {"chrA": 700, "chrB": 1301, "chrC": 517}
+    fa = tmp_path / "multi.fa"
+    with open(fa, "w") as fh:
+        for name, n in lens.items():
+            seq = "".join("ACGTN"[i] for i in rng.integers(0, 5, size=n))
+            fh.write(f">{name}\n")
+            fh.writelines(seq[i:i + 60] + "\n" for i in range(0, n, 60))
+    chrom, pos = [], []
+    for name, n in lens.items():
+        for p1 in [1, 2, _R, _R + 1, n // 2, n - _R, n - 1, n, n + 5, n + _R, n + _R + 1, n + 400]:
+            chrom.append(name)
+            pos.append(p1)
+        extra = rng.integers(1, n + 1, size=40)
+        chrom += [name] * len(extra)
+        pos += list(extra)
+    chrom.append("chrNotThere")
+    pos.append(10)
+    table = _table(chrom, pos)
+    fasta = FastaReader(str(fa))
+    genome = device_genome(fasta)
+    assert set(genome.offsets) == set(lens)
+    got = np.asarray(windows_from_packed(genome.rows, jnp.asarray(globalize_positions(table, genome))))
+    want = gather_windows(table, fasta)
+    np.testing.assert_array_equal(got, want)
+    assert (want[-1] == 4).all() and (want != 4).any()
+
+
+def test_window_gather_takes_row_indices_not_byte_indices():
+    """The regression guard: the gather this replaced took one index pair
+    per BYTE (41 x rows). Lowered at 1,024 rows, no gather in the StableHLO
+    may take more than 2 x rows index vectors, nor all of them together."""
+    import re
+
+    import jax
+
+    from variantcalling_tpu.featurize import GENOME_ROW_WORDS, windows_from_packed
+
+    rows = 1024
+    text = jax.jit(windows_from_packed).lower(
+        jax.ShapeDtypeStruct((64, GENOME_ROW_WORDS), jnp.uint32),
+        jax.ShapeDtypeStruct((rows,), jnp.uint32)).as_text()
+    gathers = [ln for ln in text.splitlines() if "stablehlo.gather" in ln or "dynamic_gather" in ln]
+    assert gathers, "no gather found: the pattern below guards nothing"
+    counts = []
+    for ln in gathers:
+        operands = re.search(r":\s*\(tensor<[^>]*>,\s*tensor<([0-9x]*)x[a-z]+[0-9]+>\)", ln)
+        assert operands, ln
+        dims = [int(d) for d in operands.group(1).split("x") if d]
+        index_vector_dim = int(re.search(r"index_vector_dim = (\d+)", ln).group(1))
+        counts.append(int(np.prod([d for i, d in enumerate(dims) if i != index_vector_dim])))
+    assert max(counts) <= 2 * rows and sum(counts) <= 2 * rows, counts
+
+
+def test_genome_packable_is_the_uint32_range(tmp_path):
+    """Packable iff every byte position, and the fill past the closing row,
+    fits uint32 — from contig lengths alone; the builder refuses the rest."""
+    from variantcalling_tpu import featurize
+
+    class Lengths:
+        path = "lengths-only"
+
+        def __init__(self, *lens):
+            self.references = [f"c{i}" for i in range(len(lens))]
+            self._lens = dict(zip(self.references, lens))
+
+        def get_reference_length(self, c):
+            return self._lens[c]
+
+    assert featurize.genome_packable(Lengths(3_100_000_000, 57_000_000))  # hg38 scale
+    edge = (1 << 32) - 3 * featurize.GENOME_ROW_BYTES
+    assert featurize.genome_packable(Lengths(edge - 200))
+    assert not featurize.genome_packable(Lengths(edge + 2 * featurize.GENOME_ROW_BYTES))
+    with pytest.raises(ValueError, match="uint32"):
+        featurize._build_device_genome(Lengths(1 << 32), featurize.WINDOW_RADIUS, None)
 
 
 def test_genome_cache_key_shared_across_consumers(tmp_path):
@@ -220,7 +378,7 @@ def test_single_device_plan_keeps_the_genome_on_one_device(tmp_path):
     sh = standard_genome_sharding(shard_score.mesh_for(plan1))
     assert sh is None and standard_genome_sharding() is None
     genome = device_genome(FastaReader(str(fa)), sharding=sh)
-    assert len(genome.blocks.sharding.device_set) == 1
+    assert len(genome.rows.sharding.device_set) == 1
     plan4 = shard_score.MeshPlan(4, "4", "test")
     sh4 = standard_genome_sharding(shard_score.mesh_for(plan4))
     assert sh4.is_fully_replicated and len(sh4.device_set) == 4

@@ -111,6 +111,37 @@ def test_dan_program_compiles_for_v5e(v5e):
                          len(names), v5e)
 
 
+def test_window_gather_compiles_for_v5e_at_hg38_size(v5e):
+    """The resident genome's window gather at the size the cells run it
+    (hg38's 6.06 M rows of 128 words, the 262,144-row bucket) on one chip,
+    and as a pure map over dp=4 with the genome replicated: two whole-row
+    gather fusions, no per-byte index tensor, no collective."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from variantcalling_tpu.featurize import (GENOME_ROW_WORDS, WINDOW_RADIUS,
+                                              windows_from_packed)
+    from variantcalling_tpu.parallel import shard_score
+
+    single, mesh, dp_sharded = v5e
+    n_rows, bucket = 6_055_937, 262_144
+    width = 2 * WINDOW_RADIUS + 1
+    for program, genome_sharding, pos_sharding in (
+            (windows_from_packed, single, single),
+            (shard_score.shard_program(windows_from_packed, mesh, n_data_args=1,
+                                       replicated_leading=1),
+             NamedSharding(mesh, P()), dp_sharded)):
+        compiled = jax.jit(program).lower(
+            jax.ShapeDtypeStruct((n_rows, GENOME_ROW_WORDS), jnp.uint32,
+                                 sharding=genome_sharding),
+            jax.ShapeDtypeStruct((bucket,), jnp.uint32, sharding=pos_sharding)).compile()
+        text = compiled.as_text()
+        assert f"s32[{bucket * width}" not in text  # the per-byte index of the old gather
+        for collective in ("all-gather", "all-reduce", "collective-permute", "all-to-all"):
+            assert collective not in text
+        # what one dispatch adds to the resident genome stays far under a chip
+        assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+
+
 # ---------------------------------------------------------------------------
 # compile-cache placement
 # ---------------------------------------------------------------------------

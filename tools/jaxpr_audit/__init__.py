@@ -183,7 +183,7 @@ def build_fused_programs(contract: dict) -> list[tuple[str, object, tuple, str]]
     import jax.numpy as jnp
     import numpy as np
 
-    from variantcalling_tpu.featurize import (DEVICE_FEATURES, GENOME_BLOCK_BITS,
+    from variantcalling_tpu.featurize import (DEVICE_FEATURES, GENOME_ROW_WORDS,
                                               WINDOW_RADIUS)
     from variantcalling_tpu.models.forest import FlatForest
     from variantcalling_tpu.parallel import shard_score
@@ -209,7 +209,7 @@ def build_fused_programs(contract: dict) -> list[tuple[str, object, tuple, str]]
                        for _ in host_names)
     aux = tuple(jax.ShapeDtypeStruct((rows,), jnp.uint8) for _ in range(5))
     win_aval = jax.ShapeDtypeStruct((rows, 2 * WINDOW_RADIUS + 1), jnp.uint8)
-    genome_aval = jax.ShapeDtypeStruct((4, 1 << GENOME_BLOCK_BITS), jnp.uint8)
+    genome_aval = jax.ShapeDtypeStruct((64, GENOME_ROW_WORDS), jnp.uint32)
     gpos_aval = jax.ShapeDtypeStruct((rows,), jnp.uint32)
     programs: list[tuple[str, object, tuple, str]] = []
     for variant in spec["variants"]:

@@ -121,17 +121,25 @@ def classify_alleles(table: VariantTable) -> AlleleColumns:
     return AlleleColumns(is_snp, is_indel, is_ins, indel_length, indel_nuc, ref_code, alt_code, n_alts)
 
 
-# device-resident genome: fasta path -> (blocked device array, offsets, lengths).
+# device-resident genome: fasta path -> (device rows, offsets, lengths).
 # Shipping the genome to HBM once turns per-run window transfer (41 bytes a
-# variant) into an on-device gather fed by one (block, offset) int32 pair
-# per variant. All contigs are concatenated with 2*WINDOW_RADIUS-wide N
-# gaps so windows never leak across contig boundaries, and the array is
-# reshaped to (n_blocks, 2^GENOME_BLOCK_BITS): hg38's ~3.1e9 global
-# coordinates exceed int32 (the only integer width jax uses without x64),
-# so all device-side indexing stays in the (small block id, small offset)
-# pair. The fused program compiles ONCE (per-contig arrays would retrace
-# per contig length). Two entries cached (the sharded + unsharded variants
-# of one genome; ~3.1GB HBM each for hg38).
+# variant) into an on-device gather fed by ONE uint32 per variant: its byte
+# position in the concatenation of all contigs, which are joined by
+# 2*WINDOW_RADIUS-wide N gaps so windows never leak across contig
+# boundaries (hg38 + gaps is ~3.2e9: it fits uint32, not int32 — the only
+# signed width jax indexes with without x64). The bytes live on the device
+# as little-endian 32-bit words in rows of GENOME_ROW_WORDS = 128 — one
+# row is the 128 lanes of a vector register, 512 bases — closed by one row
+# of N, so a window is TWO WHOLE-ROW LOOKUPS (the row its first byte falls
+# in and that row's successor; a row number is < 2^23, int32-safe at any
+# genome size) and the per-window alignment is vector shifts after the
+# gather (windows_from_packed). Whole rows because of what an index costs
+# on the chip: a uint8 array read with one index per BYTE takes 26 ns a
+# byte on a v5e (41 x 262,144 indices a dispatch: 0.28 s), two row lookups
+# and the shifts 43 ns a WINDOW (PERF.md, PR 31). One layout for every
+# genome size; the fused program compiles ONCE (per-contig arrays would
+# retrace per contig length). Two entries cached (the sharded + unsharded
+# variants of one genome; ~3.1GB HBM each for hg38).
 _DEVICE_GENOME_MAX = 2
 # chunk featurization fans out on the IO pool (vctpu-lint VCT010): a miss
 # is single flight (utils/keyed_cache.py) — two workers racing the SAME
@@ -154,26 +162,23 @@ def _genome_resident_worthwhile(table, fasta, radius: int | None = None,
     key = (getattr(fasta, "path", id(fasta)),
            WINDOW_RADIUS if radius is None else radius, str(sharding))
     return key in _DEVICE_GENOME_CACHE or len(table) >= GENOME_RESIDENT_MIN_VARIANTS
-GENOME_BLOCK_BITS = 20
-_GBLOCK = 1 << GENOME_BLOCK_BITS
-
-
-_FLAT_MAX = (1 << 31) - 4 * _GBLOCK  # flat int32 layout headroom
+GENOME_ROW_WORDS = 128
+GENOME_ROW_BYTES = 4 * GENOME_ROW_WORDS
 
 
 class DeviceGenome:
-    __slots__ = ("blocks", "offsets", "lengths", "flat")
+    """The concatenated genome on the device: ``rows`` is
+    ``uint32[n, GENOME_ROW_WORDS]``, byte ``p`` of the concatenation in bits
+    ``8 * (p & 3)`` of word ``p >> 2``, the last row all N; ``offsets`` and
+    ``lengths`` give each contig's first byte and length. A position on the
+    wire is that byte number as one uint32 (:func:`globalize_positions`)."""
 
-    def __init__(self, blocks, offsets: dict[str, int], lengths: dict[str, int],
-                 flat: bool):
-        # flat=True: ``blocks`` is a 1-D array (total length < 2^31) and
-        # windows gather with plain int32 indices — the fast path. Larger
-        # genomes (hg38 + gaps ~3.2e9 > int32) use the (block, offset)
-        # 2-D layout, which costs an extra coordinate per lookup.
-        self.blocks = blocks
+    __slots__ = ("rows", "offsets", "lengths")
+
+    def __init__(self, rows, offsets: dict[str, int], lengths: dict[str, int]):
+        self.rows = rows
         self.offsets = offsets
         self.lengths = lengths
-        self.flat = flat
 
 
 def device_genome(fasta: FastaReader, radius: int = WINDOW_RADIUS,
@@ -189,11 +194,23 @@ def device_genome_stats() -> dict:
     (``vctpu serve``: ``/v1/status``, ``/v1/warm``)."""
     genomes = [g for _, g in _DEVICE_GENOME_CACHE.items()]
     return {"entries": len(genomes),
-            "bytes": sum(int(g.blocks.nbytes) for g in genomes)}
+            "bytes": sum(int(g.rows.nbytes) for g in genomes)}
+
+
+def _genome_rows(parts: list[np.ndarray]) -> np.ndarray:
+    """``parts`` (uint8 base codes) joined, padded with N to whole rows and
+    closed by one row of N — in ONE concatenation, which the word view then
+    shares: a second 3.1 GB host copy is what this must not make."""
+    total = sum(len(p) for p in parts)
+    tail = np.full((-total) % GENOME_ROW_BYTES + GENOME_ROW_BYTES, 4, dtype=np.uint8)
+    return np.concatenate(parts + [tail]).view("<u4").reshape(-1, GENOME_ROW_WORDS)
 
 
 def _build_device_genome(fasta: FastaReader, radius: int,
                          sharding) -> DeviceGenome:
+    if not genome_packable(fasta, radius):
+        raise ValueError(f"{getattr(fasta, 'path', fasta)}: positions do not fit "
+                         "uint32 (callers ask genome_packable first)")
     gap = np.full(2 * radius, 4, dtype=np.uint8)
     parts = [gap]
     offsets: dict[str, int] = {}
@@ -206,26 +223,22 @@ def _build_device_genome(fasta: FastaReader, radius: int,
         parts.append(seq)
         parts.append(gap)
         cur += len(seq) + len(gap)
-    flat_arr = np.concatenate(parts)
-    use_flat = len(flat_arr) < _FLAT_MAX
-    if not use_flat:
-        pad = (-len(flat_arr)) % _GBLOCK
-        if pad:
-            flat_arr = np.concatenate([flat_arr, np.full(pad, 4, dtype=np.uint8)])
-        flat_arr = flat_arr.reshape(-1, _GBLOCK)
-    arr = jax.device_put(flat_arr, sharding) if sharding is not None else jax.device_put(flat_arr)
-    return DeviceGenome(arr, offsets, lengths, use_flat)
+    rows = _genome_rows(parts)
+    del parts
+    arr = jax.device_put(rows, sharding) if sharding is not None else jax.device_put(rows)
+    return DeviceGenome(arr, offsets, lengths)
 
 
 def globalize_positions(table: VariantTable, genome: DeviceGenome,
-                        radius: int = WINDOW_RADIUS) -> tuple[np.ndarray, np.ndarray]:
-    """(block int32, within-block offset int32) per record.
+                        radius: int = WINDOW_RADIUS) -> np.ndarray:
+    """One uint32 per record: the byte position of its anchor in the
+    device genome (4 bytes a variant on the wire).
 
     Unknown contigs and positions past the contig end (wrong reference
-    build / truncated FASTA) get an out-of-range block so their windows
-    read all-N — the host gather's safety behavior. Positions within
-    ``radius`` past the end still resolve idx-wise into the N gap, exactly
-    like the host path.
+    build / truncated FASTA) get :func:`packed_position_fill`, past the
+    genome's end, so their windows read all-N — the host gather's safety
+    behavior. Positions within ``radius`` past the end still resolve
+    idx-wise into the N gap, exactly like the host path.
     """
     # per-contig lookup through the parser's integer contig codes: one
     # dict probe per contig, not one string conversion per record
@@ -237,91 +250,69 @@ def globalize_positions(table: VariantTable, genome: DeviceGenome,
     pos0 = table.pos.astype(np.int64) - 1
     gpos = pos0 + np.nan_to_num(off, nan=0).astype(np.int64)
     bad = np.isnan(off) | (pos0 < 0) | (pos0 >= np.nan_to_num(clen, nan=-1) + radius)
-    if genome.flat:
-        gpos[bad] = int(genome.blocks.shape[0]) + _GBLOCK  # past the end
-        return np.zeros(len(gpos), dtype=np.int32), gpos.astype(np.int32)
-    n_blocks = int(genome.blocks.shape[0])
-    gpos[bad] = n_blocks * _GBLOCK + _GBLOCK  # one block past the end
-    return (gpos >> GENOME_BLOCK_BITS).astype(np.int32), \
-        (gpos & (_GBLOCK - 1)).astype(np.int32)
+    gpos[bad] = packed_position_fill(genome)
+    return gpos.astype(np.uint32)
 
 
 def genome_packable(fasta: FastaReader, radius: int = WINDOW_RADIUS) -> bool:
-    """Whether the genome's positions will fit 4-byte packing — computable
-    from contig lengths alone, BEFORE paying the encode + HBM upload."""
+    """Whether every position of the genome, and the fill past its end, fits
+    one uint32 — computable from contig lengths alone, BEFORE paying the
+    encode + HBM upload (~4.29 Gbp incl. N gaps; hg38 is 3.2)."""
     gap = 2 * radius
     total = gap + sum(fasta.get_reference_length(c) + gap for c in fasta.references)
-    if total < _FLAT_MAX:
-        return True
-    n_blocks = -(-total // _GBLOCK)
-    return (n_blocks + 3) << GENOME_BLOCK_BITS <= (1 << 32)
-
-
-def pack_global_positions(block: np.ndarray, off: np.ndarray, genome: DeviceGenome) -> np.ndarray | None:
-    """Pack (block, offset) into ONE uint32 per record, or None if it can't fit.
-
-    Transfer-thinning for the fused scoring path: the per-variant position
-    pair (8 bytes) becomes 4 bytes on the wire. Fits whenever every
-    possible packed value — including the out-of-range sentinel and the
-    +1-block headroom the device-side unpack can produce — stays below
-    2^32 (hg38 + N gaps ≈ 3.2e9, comfortably in range).
-    """
-    if genome.flat:
-        # flat genomes are < 2^31 by construction (io gather is int32)
-        return off.astype(np.uint32)
-    n_blocks = int(genome.blocks.shape[0])
-    if (n_blocks + 3) << GENOME_BLOCK_BITS > (1 << 32):
-        return None
-    return ((block.astype(np.int64) << GENOME_BLOCK_BITS) | off.astype(np.int64)).astype(np.uint32)
+    n_rows = -(-total // GENOME_ROW_BYTES) + 1
+    return n_rows * GENOME_ROW_BYTES < (1 << 32)
 
 
 def packed_position_fill(genome: DeviceGenome) -> int:
-    """Padding value for packed positions: one block past the genome end."""
-    if genome.flat:
-        return int(genome.blocks.shape[0]) + _GBLOCK
-    return (int(genome.blocks.shape[0]) + 1) << GENOME_BLOCK_BITS
+    """The position of padding rows and of records with no place in the
+    genome: the first byte past the closing row of N, so the window falls
+    in no real row and reads all-N."""
+    return int(genome.rows.shape[0]) * GENOME_ROW_BYTES
 
 
-def windows_from_packed(genome_blocks, gpos, radius: int = WINDOW_RADIUS):
-    """Windows gathered from uint32 packed positions (traceable).
-
-    Flat genomes treat the packed value as the flat index; blocked genomes
-    unpack the (block, offset) pair before the gather.
-    """
-    import jax.numpy as jnp
-
-    if genome_blocks.ndim == 1:
-        return windows_on_device(genome_blocks, None, gpos.astype(jnp.int32), radius)
-    g = gpos.astype(jnp.uint32)
-    blk = (g >> GENOME_BLOCK_BITS).astype(jnp.int32)
-    off = (g & jnp.uint32(_GBLOCK - 1)).astype(jnp.int32)
-    return windows_on_device(genome_blocks, blk, off, radius)
-
-
-def windows_on_device(genome_blocks, block, off, radius: int = WINDOW_RADIUS):
-    """(N, 2R+1) uint8 windows gathered on device; out-of-range reads N=4.
+def windows_from_packed(genome_rows, gpos, radius: int = WINDOW_RADIUS):
+    """(N, 2R+1) uint8 windows centered on the uint32 byte positions
+    ``gpos``, gathered on device; bytes outside the genome read N=4.
 
     Traceable — used inside the fused featurize+score program so the window
-    tensor never exists host-side. All arithmetic is int32-safe: 1-D
-    genomes (< 2^31) gather flat; larger ones use the (block + carry,
-    offset within block) pair.
+    tensor never exists host-side. One row index per window, not one index
+    per byte: the window's first byte is ``start = gpos - R``, in word
+    ``(start >> 2) & 127`` of row ``start >> 9``; that row and its successor
+    are fetched whole (the form of an embedding lookup), a barrel shift
+    over the lane axis on the bits of the word number brings the window's
+    first word to lane 0, a funnel shift of adjacent words by
+    ``8 * (start & 3)`` bits brings its first byte to bit 0, and the words
+    are split into bytes. Everything after the two lookups is elementwise.
+    A position under ``radius`` reads all-N: the genome begins with a gap
+    of ``2 * radius`` N, so no real window starts before byte 0.
     """
     import jax.numpy as jnp
 
-    if genome_blocks.ndim == 1:  # flat fast path
-        idx = off[:, None] + jnp.arange(-radius, radius + 1)[None, :]
-        glen = genome_blocks.shape[0]
-        valid = (idx >= 0) & (idx < glen)
-        vals = genome_blocks[jnp.clip(idx, 0, glen - 1)]
-        return jnp.where(valid, vals, 4).astype(jnp.uint8)
-
-    t = off[:, None] + jnp.arange(-radius, radius + 1)[None, :]  # may be +-R out
-    blk = block[:, None] + (t >> GENOME_BLOCK_BITS)  # arithmetic shift: floor div
-    o2 = t & (_GBLOCK - 1)
-    n_blocks = genome_blocks.shape[0]
-    valid = (blk >= 0) & (blk < n_blocks)
-    vals = genome_blocks[jnp.clip(blk, 0, n_blocks - 1), o2]
-    return jnp.where(valid, vals, 4).astype(jnp.uint8)
+    width = 2 * radius + 1
+    n_words = -(-(width + 3) // 4) + 1  # the window at any byte alignment, + the funnel's next word
+    lane_bits = GENOME_ROW_WORDS.bit_length() - 1
+    assert n_words + GENOME_ROW_WORDS - 1 <= 2 * GENOME_ROW_WORDS, "window wider than two genome rows"
+    g = gpos.astype(jnp.uint32)
+    start = g - jnp.uint32(radius)
+    n_real = genome_rows.shape[0] - 1  # the last row is the closing N
+    row = (start >> (lane_bits + 2)).astype(jnp.int32)
+    valid = (g >= radius) & (row < n_real)
+    row = jnp.clip(row, 0, n_real - 1)
+    x = jnp.concatenate([genome_rows.at[r].get(mode="promise_in_bounds")
+                         for r in (row, row + 1)], axis=1)
+    word = (start >> 2) & jnp.uint32(GENOME_ROW_WORDS - 1)
+    for bit in reversed(range(lane_bits)):  # barrel shift: 64, 32, ... 1 lanes
+        step = 1 << bit
+        keep = n_words + step - 1  # what the lower bits' stages may still reach
+        take = ((word >> bit) & 1).astype(bool)[:, None]
+        x = jnp.where(take, x[:, step:step + keep], x[:, :keep])
+    shift = ((start & 3) << 3)[:, None]  # funnel shift: 0, 8, 16 or 24 bits
+    lo, hi = x[:, :-1], x[:, 1:]
+    words = jnp.where(shift == 0, lo, (lo >> shift) | (hi << ((32 - shift) & 31)))
+    octets = (words[:, :, None] >> (8 * jnp.arange(4, dtype=jnp.uint32))) & 0xFF
+    win = octets.astype(jnp.uint8).reshape(words.shape[0], -1)[:, :width]
+    return jnp.where(valid[:, None], win, jnp.uint8(4))
 
 
 def _contig_runs(table_or_chrom, n: int):
@@ -693,7 +684,8 @@ def featurize(
     filter pipeline's hot-path design); device kernels are jit-compiled
     once per padded batch shape.
     """
-    resident = _genome_resident_worthwhile(table, fasta, sharding=standard_genome_sharding())
+    resident = genome_packable(fasta) and _genome_resident_worthwhile(
+        table, fasta, sharding=standard_genome_sharding())
     hf = host_featurize(table, fasta, annotate_intervals=annotate_intervals,
                         extra_info_fields=extra_info_fields,
                         compute_windows=not resident)
@@ -703,11 +695,11 @@ def featurize(
 
 
 @partial(jax.jit, static_argnames=("center", "flow_order"))
-def _device_feature_program_genome(genome_blocks, block, off, is_indel, indel_nuc,
+def _device_feature_program_genome(genome_rows, gpos, is_indel, indel_nuc,
                                    ref_code, alt_code, is_snp, *, center: int,
                                    flow_order: str):
     """Standalone window-kernel program over the device-resident genome."""
-    windows = windows_on_device(genome_blocks, block, off, radius=center)
+    windows = windows_from_packed(genome_rows, gpos, radius=center)
     d = device_feature_dict(windows, is_indel, indel_nuc, ref_code, alt_code, is_snp,
                             center=center, flow_order=flow_order)
     return tuple(d[k] for k in DEVICE_FEATURES)
@@ -739,10 +731,9 @@ def materialize_features(hf: HostFeatures, flow_order: str = fops.DEFAULT_FLOW_O
     )
     if genome_path:
         genome = device_genome(fasta, sharding=standard_genome_sharding())
-        blk, off = globalize_positions(table, genome)
-        n_blocks = int(genome.blocks.shape[0])
+        gpos = globalize_positions(table, genome)
         device_out = _device_feature_program_genome(
-            genome.blocks, pad(blk, fill=n_blocks + 1), pad(off), *alle_args,
+            genome.rows, pad(gpos, fill=packed_position_fill(genome)), *alle_args,
             center=CENTER, flow_order=flow_order,
         )
     else:

@@ -387,10 +387,10 @@ def _build_fused_program(model, feature_names, flow_order, genome_resident,
             return predictor(x)
 
     if genome_resident:
-        def fn(genome_blocks, gpos, host_cols, is_indel, indel_nuc,
+        def fn(genome_rows, gpos, host_cols, is_indel, indel_nuc,
                ref_code, alt_code, is_snp):
             with jax.named_scope(SCOPE_WINDOW_GATHER):
-                windows = windows_from_packed(genome_blocks, gpos)
+                windows = windows_from_packed(genome_rows, gpos)
             return body(windows, host_cols,
                         is_indel, indel_nuc, ref_code, alt_code, is_snp)
     else:
@@ -589,7 +589,6 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
             from variantcalling_tpu.featurize import (device_genome, gather_windows,
                                                       genome_packable,
                                                       globalize_positions,
-                                                      pack_global_positions,
                                                       packed_position_fill)
 
             if not genome_packable(fasta):
@@ -606,13 +605,8 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
 
                 genome = device_genome(
                     fasta, sharding=standard_genome_sharding(mesh))
-                blk_all, off_all = globalize_positions(table, genome)
-                gpos_all = pack_global_positions(blk_all, off_all, genome)
-                if gpos_all is None:  # safety net: packable() and the packer disagree
-                    genome_resident = False
-                    windows = gather_windows(table, fasta)
-                else:
-                    gpos_fill = packed_position_fill(genome)
+                gpos_all = globalize_positions(table, genome)
+                gpos_fill = packed_position_fill(genome)
         if genome_resident:
             obs.request_note(genome_resident=True)  # serve counts such requests
         host_cols = tuple(_narrow_column(hf.cols[f])
@@ -705,7 +699,7 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
         alt_code = cat([i.alle.alt_code for i in inputs])
         is_snp = cat([i.alle.is_snp for i in inputs])
     # what, besides the bucket size, makes jax trace this program anew
-    shapes = (genome.blocks.shape if genome_resident else windows.shape[1:],
+    shapes = (genome.rows.shape if genome_resident else windows.shape[1:],
               tuple(c.dtype.char for c in host_cols))
 
     n = sum(i.n for i in inputs)
@@ -753,7 +747,7 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
             )
             if genome_resident:
                 # padding positions sit past the genome end -> all-N windows
-                call_args = (genome.blocks, prep(gpos_all, fill=gpos_fill), *common)
+                call_args = (genome.rows, prep(gpos_all, fill=gpos_fill), *common)
             else:
                 call_args = (prep(windows, fill=4), *common)
         # the enqueue; on a first call also trace + lower + cache load or compile
