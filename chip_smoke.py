@@ -6,7 +6,7 @@ Drives the system's main path once through the entry points a user calls
 — ``python -m variantcalling_tpu filter_variants_pipeline`` (as
 ``variantcalling_tpu.__main__.main``) and an in-process ``vctpu serve``
 daemon on a unix socket — at the full width of both model families the
-repo serves (bench-shape forest T=40 depth 6; DAN E=16 H=256 L=2), with
+repo serves (the benchmark's forest, T=40 depth 6; DAN E=16 H=256 L=2), with
 seeded random weights and seeded inputs (1,000,000 variants, 10 Mbp, 4
 contigs), and checks what comes out by the repo's own contracts:
 
@@ -162,14 +162,13 @@ class Smoke:
     def make_inputs(self) -> None:
         import numpy as np
 
-        import bench
         from variantcalling_tpu.featurize import BASE_FEATURES
         from variantcalling_tpu.models import registry
-        from variantcalling_tpu.synthetic import synthetic_dan, synthetic_forest
+        from variantcalling_tpu.synthetic import DEPTH, N_TREES, make_fixtures_fast, synthetic_dan, synthetic_forest
 
-        bench.make_fixtures_fast(self.work, n=self.n, genome_len=self.genome)
+        make_fixtures_fast(self.work, n=self.n, genome_len=self.genome)
         self.forest = synthetic_forest(np.random.default_rng(0),
-                                       n_trees=bench.N_TREES, depth=bench.DEPTH)
+                                       n_trees=N_TREES, depth=DEPTH)
         dan = synthetic_dan(np.random.default_rng(1), list(BASE_FEATURES),
                             embed_dim=16, hidden=256, n_layers=2)
         registry.save_models(self.models, {"forest": self.forest, "dan": dan})

@@ -163,20 +163,6 @@ if [ "${VCTPU_PROF_SMOKE:-0}" != "0" ]; then
   }
 fi
 
-# -- opt-in tier-0 bench regression gate (docs/observability.md) -----------
-# VCTPU_BENCH_GATE=1: run a fresh reduced bench (hot/e2e/obs phases) and
-# gate it against the newest committed BENCH_r*.json with the explicit
-# per-metric noise bands in tools/bench_gate.py. Opt-in because the
-# fresh bench costs minutes; the sentry fails the run BEFORE pytest on a
-# throughput regression beyond the bands.
-if [ "${VCTPU_BENCH_GATE:-0}" != "0" ]; then
-  echo "bench gate stage: python -m tools.bench_gate --run"
-  env PYTHONPATH= JAX_PLATFORMS=cpu python -m tools.bench_gate --run || {
-    echo "bench gate found a regression beyond the noise bands — failing before pytest" >&2
-    exit 1
-  }
-fi
-
 rc=0
 env PYTHONPATH= JAX_PLATFORMS=cpu \
   XLA_FLAGS="--xla_force_host_platform_device_count=8" \

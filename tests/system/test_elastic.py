@@ -34,11 +34,10 @@ _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 
 @pytest.fixture(scope="module")
 def world(tmp_path_factory):
-    import bench
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("elastic_e2e"))
-    bench.make_fixtures(d, n=2500, genome_len=150_000)
+    make_fixtures(d, n=2500, genome_len=150_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)

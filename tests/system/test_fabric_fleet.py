@@ -40,11 +40,10 @@ def _sha(data: bytes) -> str:
 
 @pytest.fixture(scope="module")
 def world(tmp_path_factory):
-    import bench
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = tmp_path_factory.mktemp("fabric_fleet")
-    bench.make_fixtures(str(d), n=1500, genome_len=120_000)
+    make_fixtures(str(d), n=1500, genome_len=120_000)
     model_pkl = str(d / "model.pkl")
     with open(model_pkl, "wb") as fh:
         pickle.dump({"m": synthetic_forest(np.random.default_rng(0),

@@ -166,15 +166,14 @@ def test_filter_pipeline_single_contig(synthetic_world):
 def test_genome_resident_scoring_matches_host_windows(tmp_path, rng):
     """The device-resident-genome window gather must score identically to
     the host window path (featurize.device_genome / windows_from_packed)."""
-    import bench
     from variantcalling_tpu.featurize import host_featurize
     from variantcalling_tpu.io.fasta import FastaReader
     from variantcalling_tpu.io.vcf import read_vcf
     from variantcalling_tpu.pipelines.filter_variants import fused_featurize_score
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path)
-    bench.make_fixtures(d, n=3000, genome_len=100_000)
+    make_fixtures(d, n=3000, genome_len=100_000)
     table = read_vcf(f"{d}/calls.vcf")
     fasta = FastaReader(f"{d}/ref.fa")
     model = synthetic_forest(np.random.default_rng(0), n_trees=10, depth=5)
@@ -225,16 +224,15 @@ def test_fused_narrow_columns_bit_identical_to_f32_matrix(tmp_path):
     """The fused path's narrow wire dtypes (uint8 host columns, packed
     uint32 positions) must reproduce the stacked-f32-matrix scores exactly
     — the _narrow_column contract is exactness, not approximation."""
-    import bench
     from variantcalling_tpu.featurize import featurize, host_featurize
     from variantcalling_tpu.io.fasta import FastaReader
     from variantcalling_tpu.io.vcf import read_vcf
     from variantcalling_tpu.pipelines.filter_variants import (fused_featurize_score,
                                                               score_variants)
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path)
-    bench.make_fixtures(d, n=2000, genome_len=60_000)
+    make_fixtures(d, n=2000, genome_len=60_000)
     table = read_vcf(f"{d}/calls.vcf")
     fasta = FastaReader(f"{d}/ref.fa")
     model = synthetic_forest(np.random.default_rng(1), n_trees=8, depth=5)
@@ -249,15 +247,15 @@ def test_fused_threshold_model_matches_direct_predict(tmp_path):
     """ThresholdModel must flow through the fused tuple-of-columns program
     (it consumes the stacked matrix assembled on device) and match its
     direct predict_score on the materialized f32 matrix."""
-    import bench
     from variantcalling_tpu.featurize import featurize, host_featurize
     from variantcalling_tpu.io.fasta import FastaReader
     from variantcalling_tpu.io.vcf import read_vcf
     from variantcalling_tpu.models.threshold import ThresholdModel, predict_score
     from variantcalling_tpu.pipelines.filter_variants import fused_featurize_score
+    from variantcalling_tpu.synthetic import make_fixtures
 
     d = str(tmp_path)
-    bench.make_fixtures(d, n=1500, genome_len=60_000)
+    make_fixtures(d, n=1500, genome_len=60_000)
     table = read_vcf(f"{d}/calls.vcf")
     fasta = FastaReader(f"{d}/ref.fa")
 

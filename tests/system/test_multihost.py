@@ -265,10 +265,10 @@ def test_two_rank_filter_variants_pipeline_cli(tmp_path, multiprocess_collective
     contract in variantcalling_tpu/engine.py), and the test is now
     flakehunt-marked so `VCTPU_FLAKEHUNT=1 ./run_tests.sh` /
     tools/flakehunt.sh keep measuring its pass rate under load."""
-    import bench
+    from variantcalling_tpu.synthetic import make_fixtures
 
     d = str(tmp_path)
-    bench.make_fixtures(d, n=6000, genome_len=300_000)
+    make_fixtures(d, n=6000, genome_len=300_000)
     # a model pickle the CLI can load
     import pickle
 

@@ -174,12 +174,12 @@ def test_cli_consumes_reference_shaped_pickle(grid, tmp_path):
     <grid pickle> --model_name rf_model_ignore_gt_incl_hpol_runs."""
     import os
 
-    import bench
+    from variantcalling_tpu.synthetic import make_fixtures
 
     _d, files = grid
     path, _src = files["exact_gt"]
     d = str(tmp_path)
-    bench.make_fixtures(d, n=1500, genome_len=60_000)
+    make_fixtures(d, n=1500, genome_len=60_000)
     repo = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     out = os.path.join(d, "filtered.vcf")
     p = subprocess.run(

@@ -35,11 +35,10 @@ _RANKS = 2
 
 @pytest.fixture(scope="module")
 def world(tmp_path_factory):
-    import bench
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("scaleout"))
-    bench.make_fixtures(d, n=2500, genome_len=150_000)
+    make_fixtures(d, n=2500, genome_len=150_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)
@@ -66,7 +65,7 @@ def _cli_args(world, out: str) -> list[str]:
 
 def _norm(data: bytes) -> bytes:
     # the ONE provenance-normalization spelling (chaoshunt shares it
-    # with loadhunt, the bench digest legs and these suites)
+    # with loadhunt and these suites)
     from tools.chaoshunt.harness import normalize_output
 
     return normalize_output(data)

@@ -1,6 +1,7 @@
 """The benchmark's readers of the program's spans, counters and scopes
 (``benchmarks/readers/``), each on a hand-made input whose answer is worked
-out by hand. Tier-1: a reader that misreads would misstate every later PR's
+out by hand, and the required work a family states, against the program's
+operand shapes. Tier-1: a reader that misreads would misstate every later PR's
 per-layer numbers, and the benchmark's own tests (``benchmarks/``) are not
 part of this suite."""
 
@@ -217,3 +218,27 @@ def test_the_serve_cells_metrics_name_readers_and_list_only_that_cell(bench):
         "driver": "closed_loop_serve", "clients": 4, "think_s": 0, "warm": True,
         "warmup_requests_per_reference": 1, "trace_requests": 32,
         "check_sample_per_file": 65536}
+
+
+def test_forest_required_flops_match_the_programs_gemm_shapes(bench):
+    """The work ``step_mfu`` and ``forest_wide_block_roofline`` divide by
+    (``families/forest.py``, from the configuration's shapes alone) must
+    equal the FLOPs implied by the ACTUAL ``forest.to_gemm`` operand shapes
+    of a forest of that configuration: the count cannot drift from the
+    packing."""
+    import numpy as np
+
+    from variantcalling_tpu.models import forest as fmod
+    from variantcalling_tpu.synthetic import synthetic_forest
+
+    with open(os.path.join(BENCH, "configs", "forest-t40d6-hg38x2.json")) as fh:
+        config = json.load(fh)
+    f = synthetic_forest(np.random.default_rng(0), n_trees=config["n_trees"],
+                         depth=config["depth"], n_features=config["n_features"])
+    gf = fmod.to_gemm(f, config["n_features"])
+    t, fdim, i = gf.a.shape
+    l = gf.m2.shape[2]
+    assert (t, fdim, i, l) == (config["n_trees"], config["n_features"],
+                               config["n_internal"], config["n_leaves"])
+    assert bench.load("families", "forest").flops_per_variant(config) \
+        == 2 * t * (fdim * i + i * l + l) == 111_680

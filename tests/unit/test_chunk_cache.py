@@ -270,12 +270,11 @@ def test_open_session_rank_agnostic_two_rank_counts(monkeypatch):
 
 @pytest.fixture(scope="module")
 def stream_world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("cacheworld"))
-    bench.make_fixtures(d, n=3000, genome_len=200_000)
+    make_fixtures(d, n=3000, genome_len=200_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)

@@ -59,13 +59,12 @@ def _leak_sentinel():
 
 @pytest.fixture(scope="module")
 def dan_world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.featurize import BASE_FEATURES
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_dan, synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_dan, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("dan"))
-    bench.make_fixtures(d, n=3000, genome_len=150_000)
+    make_fixtures(d, n=3000, genome_len=150_000)
     model = synthetic_dan(np.random.default_rng(0), BASE_FEATURES)
     forest = synthetic_forest(np.random.default_rng(1), n_trees=8, depth=4)
     _WATCHED_DIRS.append(d)

@@ -69,12 +69,11 @@ def _leak_sentinel():
 
 @pytest.fixture(scope="module")
 def world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("elastic"))
-    bench.make_fixtures(d, n=2500, genome_len=150_000)
+    make_fixtures(d, n=2500, genome_len=150_000)
     with open(f"{d}/calls.vcf", "rb") as fh:
         text = fh.read()
     with bgzf_mod.BgzfWriter(f"{d}/calls.vcf.gz") as w:

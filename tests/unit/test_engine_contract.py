@@ -135,11 +135,10 @@ def test_native_engine_refuses_mid_run_degradation(fresh_engine):
 
 @pytest.fixture(scope="module")
 def parity_world(tmp_path_factory):
-    import bench
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("engine_parity"))
-    bench.make_fixtures(d, n=12000, genome_len=300_000)
+    make_fixtures(d, n=12000, genome_len=300_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=10, depth=5)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)

@@ -167,13 +167,12 @@ def test_fabric_knobs_registered_and_unscopable():
 
 @pytest.fixture(scope="module")
 def fabric_world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.pipelines.filter_variants import run as frun
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = tmp_path_factory.mktemp("fabric_world")
     _WATCHED_DIRS.append(str(d))
-    bench.make_fixtures(str(d), n=1500, genome_len=120_000)
+    make_fixtures(str(d), n=1500, genome_len=120_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     model_pkl = str(d / "model.pkl")
     with open(model_pkl, "wb") as fh:

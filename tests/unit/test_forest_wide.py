@@ -318,46 +318,16 @@ def test_auto_resolution_matrix(rng):
 
 
 # ---------------------------------------------------------------------------
-# MFU attribution cannot drift from the packing (bench unit test)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_flops_match_wide_shapes(rng):
-    """bench.gemm_flops_per_variant(strategy='wide') must equal the FLOPs
-    implied by the ACTUAL to_wide operand shapes, for several blockings —
-    so the committed mfu_pct is attributable to the packed program."""
-    import bench
-    from variantcalling_tpu.synthetic import synthetic_forest
-
-    for n_trees, depth in ((40, 6), (7, 5), (3, 9)):
-        f = synthetic_forest(rng, n_trees=n_trees, depth=depth, n_features=12)
-        gf = fmod.to_gemm(f, 12)
-        t, fdim, i = gf.a.shape
-        l = gf.m2.shape[2]
-        assert bench.gemm_flops_per_variant(gf) == 2 * t * (fdim * i + i * l)
-        for g in (None, 1, 4, n_trees):
-            wf = fmod.to_wide(gf, g)
-            b, _, gi = wf.a.shape
-            gl = wf.m2.shape[2]
-            tp = b * wf.tree_block
-            from_shapes = 2 * fdim * (b * gi) + b * 2 * gi * gl + 2 * tp * l
-            assert bench.gemm_flops_per_variant(gf, "wide", g) == from_shapes
-            # pallas rides the same wide-block shapes
-            assert bench.gemm_flops_per_variant(gf, "pallas", g) == from_shapes
-
-
-# ---------------------------------------------------------------------------
 # formatted CLI bytes across strategies on the 12k engine-contract fixture
 # ---------------------------------------------------------------------------
 
 
 @pytest.fixture(scope="module")
 def wide_parity_world(tmp_path_factory):
-    import bench
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("wide_parity"))
-    bench.make_fixtures(d, n=12000, genome_len=300_000)
+    make_fixtures(d, n=12000, genome_len=300_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=10, depth=5)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)

@@ -217,14 +217,13 @@ def test_single_device_pipeline_byte_identical_to_jit_path(tmp_path):
 import os, sys
 sys.path.insert(0, os.environ["VCTPU_TEST_REPO"])
 import numpy as np
-import bench
 from variantcalling_tpu.io.fasta import FastaReader
 from variantcalling_tpu.io.vcf import read_vcf, write_vcf
 from variantcalling_tpu.pipelines.filter_variants import filter_variants
-from variantcalling_tpu.synthetic import synthetic_forest
+from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 d = os.environ["VCTPU_TEST_DIR"]
 if not os.path.exists(os.path.join(d, "calls.vcf")):
-    bench.make_fixtures(d, n=4000, genome_len=200_000)
+    make_fixtures(d, n=4000, genome_len=200_000)
 table = read_vcf(os.path.join(d, "calls.vcf"))
 fasta = FastaReader(os.path.join(d, "ref.fa"))
 model = synthetic_forest(np.random.default_rng(0), n_trees=10, depth=5)

@@ -283,12 +283,11 @@ def test_trace_many_threads_never_negative_depth(tmp_path):
 
 @pytest.fixture(scope="module")
 def stream_world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("obs_stream"))
-    bench.make_fixtures(d, n=4000, genome_len=200_000)
+    make_fixtures(d, n=4000, genome_len=200_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)

@@ -283,12 +283,11 @@ def test_bottleneck_cli_and_span_fallback(tmp_path, capsys):
 
 @pytest.fixture(scope="module")
 def stream_world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("obs_profile"))
-    bench.make_fixtures(d, n=4000, genome_len=200_000)
+    make_fixtures(d, n=4000, genome_len=200_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     return {"dir": d, "model": model,
             "fasta": FastaReader(f"{d}/ref.fa"), "n": 4000}
@@ -387,26 +386,6 @@ def test_serial_pipeline_also_profiles(stream_world, tmp_path, monkeypatch):
     # falls back to the depth-0 spans (ingest/featurize+score/writeback)
     assert b["limiting_stage"] is not None
     assert b["source"] in ("profile", "spans")
-
-
-# ---------------------------------------------------------------------------
-# the published-peaks table
-# ---------------------------------------------------------------------------
-
-
-def test_device_peaks_table_is_keyed_by_device_kind(monkeypatch):
-    """One table, keyed by what the device calls itself; a device that is
-    not listed gets None (and so no utilization/roofline field)."""
-    import jax
-
-    class _Dev:
-        device_kind = "TPU v5 lite"
-
-    assert profile_mod.device_peaks() is None  # cpu
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
-    peaks = profile_mod.device_peaks()
-    assert peaks == {"device_kind": "TPU v5 lite", "flops_bf16": 197e12,
-                     "hbm_bytes_per_s": 819e9}
 
 
 def test_jaxprof_hook_captures_device_trace(tmp_path, monkeypatch):

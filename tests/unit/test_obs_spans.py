@@ -190,12 +190,11 @@ def test_bottleneck_lists_children_under_parents_and_ranks_neither_twice():
 
 @pytest.fixture(scope="module")
 def world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("obs_spans"))
-    bench.make_fixtures(d, n=4000, genome_len=200_000)
+    make_fixtures(d, n=4000, genome_len=200_000)
     return {"dir": d, "n": 4000, "fasta": FastaReader(f"{d}/ref.fa"),
             "model": synthetic_forest(np.random.default_rng(0), n_trees=8,
                                       depth=4)}

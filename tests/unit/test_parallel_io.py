@@ -173,10 +173,10 @@ def test_io_pool_worker_names_feed_attribution():
 
 @pytest.fixture(scope="module")
 def vcf_world(tmp_path_factory):
-    import bench
+    from variantcalling_tpu.synthetic import make_fixtures
 
     d = str(tmp_path_factory.mktemp("pario"))
-    bench.make_fixtures(d, n=5000, genome_len=250_000)
+    make_fixtures(d, n=5000, genome_len=250_000)
     with open(f"{d}/calls.vcf", "rb") as fh:
         text = fh.read()
     with bgzf_mod.BgzfWriter(f"{d}/calls.vcf.gz") as w:

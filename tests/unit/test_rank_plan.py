@@ -127,12 +127,11 @@ def test_output_header_records_and_strips_ranks_line():
 
 @pytest.fixture(scope="module")
 def world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("rankplan"))
-    bench.make_fixtures(d, n=2500, genome_len=150_000)
+    make_fixtures(d, n=2500, genome_len=150_000)
     with open(f"{d}/calls.vcf", "rb") as fh:
         text = fh.read()
     with bgzf_mod.BgzfWriter(f"{d}/calls.vcf.gz") as w:
@@ -252,7 +251,7 @@ def test_merge_recarries_bgzf_seams_like_a_serial_writer(tmp_path,
 
 def _norm(data: bytes) -> bytes:
     # the ONE provenance-normalization spelling (chaoshunt shares it
-    # with loadhunt, the bench digest legs and these suites)
+    # with loadhunt and these suites)
     from tools.chaoshunt.harness import normalize_output
 
     return normalize_output(data)

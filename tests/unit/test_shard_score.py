@@ -44,12 +44,11 @@ def _engine_cache_isolated():
 
 @pytest.fixture(scope="module")
 def mesh_world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("meshscore"))
-    bench.make_fixtures(d, n=5000, genome_len=250_000)
+    make_fixtures(d, n=5000, genome_len=250_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)

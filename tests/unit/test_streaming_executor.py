@@ -454,9 +454,8 @@ def test_streaming_peak_rss_flat_vs_input_size(tmp_path):
     import subprocess
     import sys
 
-    import bench as bench_mod
     from variantcalling_tpu.models import registry
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures_fast, synthetic_forest
 
     sizes = {"small": 150_000, "big": 1_200_000}
     model = synthetic_forest(np.random.default_rng(0), n_trees=10, depth=5)
@@ -464,7 +463,7 @@ def test_streaming_peak_rss_flat_vs_input_size(tmp_path):
     for name, n in sizes.items():
         d = tmp_path / name
         d.mkdir()
-        bench_mod.make_fixtures_fast(str(d), n=n, genome_len=4_000_000, n_contigs=2)
+        make_fixtures_fast(str(d), n=n, genome_len=4_000_000, n_contigs=2)
         registry.save_models(str(d / "model.pkl"), {"m": model})
         code = f"""
 import resource, sys

@@ -194,12 +194,11 @@ def test_watchdog_disabled_with_zero_timeout():
 
 @pytest.fixture(scope="module")
 def stream_fault_world(tmp_path_factory):
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     d = str(tmp_path_factory.mktemp("faults"))
-    bench.make_fixtures(d, n=4000, genome_len=200_000)
+    make_fixtures(d, n=4000, genome_len=200_000)
     model = synthetic_forest(np.random.default_rng(0), n_trees=8, depth=4)
     with open(f"{d}/model.pkl", "wb") as fh:
         pickle.dump({"m": model}, fh)
@@ -228,8 +227,6 @@ def _run_stream(w, out, monkeypatch, chunk_bytes=1 << 15):
 @pytest.fixture(scope="module")
 def clean_bytes(stream_fault_world, tmp_path_factory):
     """One fault-free streaming run — the byte oracle for every fault leg."""
-    import bench  # noqa: F401 — fixtures dir already built
-
     from variantcalling_tpu.io import vcf as vcf_mod
     from variantcalling_tpu.pipelines.filter_variants import run_streaming
 

@@ -307,12 +307,12 @@ def test_vct006_monotonic_sleep_and_nonlibrary_exempt():
         deadline = time.monotonic() + 5
         time.sleep(0.1)
         ''') == []
-    # only library code is in scope: bench/tools/tests own their stopwatches
+    # only library code is in scope: benchmarks/tools/tests own their stopwatches
     src = '''
         import time
         t0 = time.perf_counter()
         '''
-    assert codes(src, path="bench.py") == []
+    assert codes(src, path="benchmarks/run_cell.py") == []
     assert codes(src, path="tools/podrun.py") == []
     # the obs subsystem and trace.py ARE the timing layer
     assert codes(src, path="variantcalling_tpu/obs/__init__.py") == []

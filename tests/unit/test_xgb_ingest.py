@@ -212,14 +212,14 @@ def test_unsupported_models_raise(model):
 def test_fused_pipeline_scores_xgboost_model(tmp_path):
     """An ingested xgboost model runs through the fused featurize+score
     program end to end (the path the reference's production pickles take)."""
-    import bench
     from variantcalling_tpu.featurize import BASE_FEATURES, host_featurize
     from variantcalling_tpu.io.fasta import FastaReader
     from variantcalling_tpu.io.vcf import read_vcf
     from variantcalling_tpu.pipelines.filter_variants import fused_featurize_score
+    from variantcalling_tpu.synthetic import make_fixtures
 
     d = str(tmp_path)
-    bench.make_fixtures(d, n=1200, genome_len=50_000)
+    make_fixtures(d, n=1200, genome_len=50_000)
     table = read_vcf(f"{d}/calls.vcf")
     fasta = FastaReader(f"{d}/ref.fa")
     # a model over real pipeline features: qual / gc_content / dp

@@ -26,7 +26,7 @@ reduce times, drop the kill, simplify the layout — while the violation
 persists) and written as a JSON file the suite can replay
 (``python -m tools.chaoshunt --replay repro.json``).
 
-CLI contract (shared with ``vctpu-lint`` / ``bench_gate``): exit 0 when
+CLI contract (shared with ``vctpu-lint``): exit 0 when
 every invariant held, 1 on a violation, 2 on usage errors. ``--json``
 emits the machine-readable campaign report. ``run_tests.sh`` runs a
 bounded 10-seed smoke behind ``VCTPU_CHAOS=1``.

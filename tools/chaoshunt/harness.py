@@ -2,7 +2,7 @@
 
 Every leg is a SUBPROCESS running the real CLI entry
 (``pipelines/filter_variants.run``) against small synthetic fixtures
-(``bench.make_fixtures``), with the schedule's faults armed through
+(``synthetic.make_fixtures``), with the schedule's faults armed through
 ``VCTPU_FAULTS`` (the env grammar exists precisely so harnesses need no
 test API) and the layout pinned through the knob registry. A tiny driver
 wrapper maps exceptions to exit code 1, then self-reports leaked
@@ -275,8 +275,7 @@ def normalize_output(data: bytes) -> bytes:
     identical by the byte-parity contract, so these lines are the ONLY
     tolerated delta. The ONE normalization spelling (prefix, not an
     enumerated list — a NEW provenance line must never silently diverge
-    the comparators), shared by loadhunt, the bench ``scaleout`` digest
-    legs and the scale-out test suites."""
+    the comparators), shared by loadhunt and the scale-out test suites."""
     return b"\n".join(
         ln for ln in data.split(b"\n")
         if not ln.startswith(b"##vctpu_"))
@@ -330,12 +329,11 @@ def build_fixtures(workdir: str, records: int = 2000,
 
     import numpy as np
 
-    import bench
-    from variantcalling_tpu.synthetic import synthetic_dan, synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_dan, synthetic_forest
 
     d = os.path.join(workdir, "fixtures")
     os.makedirs(d, exist_ok=True)
-    bench.make_fixtures(d, n=records, genome_len=150_000)
+    make_fixtures(d, n=records, genome_len=150_000)
     if model_family == "dan":
         from variantcalling_tpu.featurize import BASE_FEATURES
 

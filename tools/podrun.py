@@ -23,8 +23,7 @@ merged bytes are identical to the single-rank run whatever the final
 span plan looks like.
 
 Exit codes are DISTINCT per failure class, so harnesses (chaoshunt's
-``rank_kill``/elastic fault classes, the bench ``scaleout``/
-``straggler`` phases) can tell what died:
+``rank_kill``/elastic fault classes) can tell what died:
 
 - ``0``  — every worker completed and the merge committed;
 - ``2``  — usage/configuration error (bad flags, no --output_file);
@@ -53,8 +52,8 @@ harness use it to find a specific worker. Elastic state files carry
 SIGTERM/SIGINT and drains the fleet router-first. Obs logs land in the
 sibling shape ``vctpu obs`` merges into one timeline: the router at
 ``<base>.obs.jsonl``, backend H at ``<base>.obs.jsonl.backendH``. The
-bench ``fabric`` phase and the loadhunt ``backend_kill`` campaign use
-the importable :func:`start_fabric`/:func:`stop_fabric` pair directly.
+loadhunt ``backend_kill`` campaign uses the importable
+:func:`start_fabric`/:func:`stop_fabric` pair directly.
 """
 
 from __future__ import annotations
@@ -289,8 +288,8 @@ def _run_fabric(args) -> int:
 
 
 def _parse_worker_env(specs: list[str]) -> dict[int, list[tuple[str, str]]]:
-    """``IDX:KEY=VAL`` per-worker env overrides (the bench straggler
-    phase slows exactly one initial worker this way; replacement workers
+    """``IDX:KEY=VAL`` per-worker env overrides (a straggler harness
+    slows exactly one initial worker this way; replacement workers
     spawned by the coordinator get NO overrides — slot is None)."""
     out: dict[int, list[tuple[str, str]]] = {}
     for spec in specs:

@@ -30,13 +30,12 @@ def _fvp_args(vcf_in: str, out_path: str):
 def main() -> int:
     import numpy as np
 
-    import bench
     from variantcalling_tpu.io.fasta import FastaReader
     from variantcalling_tpu.pipelines.filter_variants import run_streaming
-    from variantcalling_tpu.synthetic import synthetic_forest
+    from variantcalling_tpu.synthetic import make_fixtures, synthetic_forest
 
     with tempfile.TemporaryDirectory(prefix="prof_smoke_") as d:
-        bench.make_fixtures(d, n=50_000, genome_len=400_000)
+        make_fixtures(d, n=50_000, genome_len=400_000)
         model = synthetic_forest(np.random.default_rng(0), n_trees=40,
                                  depth=6)
         fasta = FastaReader(os.path.join(d, "ref.fa"))
@@ -59,7 +58,7 @@ def main() -> int:
         # the smoke run lasts well under a second: the conservative
         # default rate could miss it entirely — this is a FUNCTIONAL
         # smoke, not an overhead measurement, so sample fast
-        os.environ["VCTPU_OBS_CPUPROF_HZ"] = "97"  # vctpu-lint: disable=VCT001 — harness pins a fast rate; the overhead budget is the bench's job
+        os.environ["VCTPU_OBS_CPUPROF_HZ"] = "97"  # vctpu-lint: disable=VCT001 — harness pins a fast rate; this is a smoke, not an overhead measurement
         os.environ.pop("VCTPU_OBS_PATH", None)  # vctpu-lint: disable=VCT001 — harness clears a stale override so the log lands next to the output
         try:
             run_streaming(_fvp_args(vcf_in, prof), model, fasta, {}, None)

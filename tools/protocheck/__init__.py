@@ -24,7 +24,7 @@ Three parts:
   index: change the code without the model and the stage fails.
 * :mod:`tools.protocheck.__main__` — the tier-0 CLI (lint exit-code
   contract: 0 clean, 1 violation/drift, 2 usage), ``--json`` for the
-  bench-gate-style record, ``--trace`` to print violating interleavings.
+  machine-readable record, ``--trace`` to print violating interleavings.
 
 Run as ``python -m tools.protocheck``; docs/static_analysis.md
 ("Protocol model checking") documents the model <-> code anchoring and

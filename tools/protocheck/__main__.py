@@ -4,7 +4,7 @@ Exit codes follow the lint contract: 0 clean (all invariants hold on
 the anchored model, exploration complete), 1 an invariant violation or
 model/code anchor drift, 2 usage error.
 
-``--json`` emits the bench-gate-style record::
+``--json`` emits the machine-readable record::
 
     {"states": N, "complete": true, "wall_s": ..., "anchors": [...],
      "violations": [{"invariant": ..., "trace": [...]}, ...],

@@ -424,7 +424,7 @@ class RawTimingChecker(Checker):
     Incident class: before the obs subsystem (ISSUE 5) the tree had grown
     four disconnected timing idioms — ``trace.py`` spans, the reference's
     broken decorator, per-module ``time.time()`` deltas logged as free
-    text, and bench's own stopwatches. A raw ``time.time()`` /
+    text, and a benchmark script's own stopwatches. A raw ``time.time()`` /
     ``time.perf_counter()`` measurement in library code is invisible to
     ``vctpu obs``: it cannot land in the run stream, the summary, or the
     Perfetto export, and it silently re-fragments the telemetry layer.
@@ -455,7 +455,7 @@ class RawTimingChecker(Checker):
 
     def applies_to(self, path: str) -> bool:
         if not path.startswith("variantcalling_tpu/"):
-            return False  # tools/tests/bench own their stopwatches
+            return False  # tools/tests/benchmarks own their stopwatches
         return not any(path.startswith(x) or path.endswith(x)
                        for x in _TIMING_EXEMPT)
 

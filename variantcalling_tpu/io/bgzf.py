@@ -89,9 +89,8 @@ def scan_block_spans(buf) -> list[tuple[int, int, int]] | None:
 
 def group_spans(spans, shard_bytes: int) -> list[list[tuple[int, int, int]]]:
     """Group consecutive BGZF member spans into inflate shards of
-    ~``shard_bytes`` decompressed bytes — the ONE shard-packing rule,
-    shared by the parallel ingest stream and the bench ``io`` phase so
-    the microbench always measures the production shard shape."""
+    ~``shard_bytes`` decompressed bytes — the ONE shard-packing rule of
+    the parallel ingest stream."""
     groups: list[list[tuple[int, int, int]]] = []
     cur: list[tuple[int, int, int]] = []
     acc = 0

@@ -101,7 +101,7 @@ def commit_partial(out_path: str, token: str | None) -> None:
 def list_partials(out_path: str) -> list[str]:
     """Every partial next to ``out_path`` — the legacy fixed name plus
     all unique-suffix partials. The ONE spelling of that glob, shared by
-    the chaos/load harnesses, the bench cleanup and the test sentinels,
+    the chaos/load harnesses and the test sentinels,
     so a future change to the naming scheme cannot strand a copy."""
     import glob
 

@@ -1339,8 +1339,8 @@ class VcfChunkReader:
         these on the IO pool, so a chunk is parsed immediately before it
         scores inside ONE task: no parsed table ever sits in a queue
         between a parse worker and a score worker (the
-        ``score_stage.wait`` critical-path edge that dominated
-        BENCH_r12's p95). Boundaries are the same serial rule as
+        ``score_stage.wait`` critical-path edge that dominated the p95
+        of the layout before it). Boundaries are the same serial rule as
         :meth:`__iter__` — byte parity and the journal resume identity
         are unchanged. gz inputs still inflate shard-parallel inside the
         raw generator. One-shot, like iteration; the same close

@@ -333,12 +333,6 @@ REGISTRY: dict[str, Knob] = {k.name: k for k in (
        "Prometheus textfile-collector path: every periodic snapshot "
        "atomically rewrites this file with the text exposition "
        "(vctpu obs prom is the offline sibling)"),
-    _k("VCTPU_BENCH_GATE", "bool", False,
-       "run_tests.sh: run the opt-in bench regression gate stage "
-       "(tools/bench_gate.py) before pytest"),
-    _k("VCTPU_BENCH_BASELINE", "str", "",
-       "bench_gate baseline JSON path; default: newest committed "
-       "BENCH_r*.json"),
     _k("VCTPU_TRACE", "bool", False,
        "print every closed trace span at INFO level"),
     _k("VCTPU_FAULTS", "str", "",

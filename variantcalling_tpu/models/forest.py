@@ -75,9 +75,9 @@ def _packed_node_table(forest: FlatForest) -> np.ndarray:
     so the bitcast round-trip is exact). One table -> ONE gather per
     traversal level instead of four or five — on XLA:CPU each rank-2
     gather lowers to its own scalar loop nest, and collapsing them (plus
-    flattening the (T, M) indexing into 1-D takes) measured ~2.5x on the
-    gather strategy (docs/perf_notes.md "Closing the XLA:CPU gather
-    gap"). Built at trace time from host arrays, so it lands in the
+    flattening the (T, M) indexing into 1-D takes) read ~2.5x on the
+    gather strategy there (docs/perf_notes.md "The packed node table").
+    Built at trace time from host arrays, so it lands in the
     compiled program as one constant.
     """
     def i32_as_f32(a):
@@ -364,17 +364,16 @@ def _int_env(name: str) -> int | None:
 def default_tree_block(n_internal: int) -> int:
     """G such that the routing contraction dim G*I fills one 128-lane MXU
     tile: the block-diagonal operand wastes O(G^2) dense FLOPs, so G grows
-    only until the contraction lanes are full (docs/perf_notes.md roofline:
-    G=4 for I=31 -> K=124, 97% lane fill vs 24% for the per-tree scan)."""
+    only until the contraction lanes are full (docs/perf_notes.md "Forest
+    operand shapes": G=4 for I=31 -> K=124, 97% lane fill vs 24% for the
+    per-tree scan)."""
     return max(1, 128 // max(n_internal, 1))
 
 
 def resolved_tree_block(n_internal: int, n_trees: int,
                         tree_block: int | None = None) -> int:
     """The G :func:`to_wide` will actually pack with (arg beats the
-    VCTPU_WIDE_BLOCK env beats the MXU-fill default; clamped to T) —
-    shared with bench's FLOP attribution so MFU math cannot drift from
-    the packing."""
+    VCTPU_WIDE_BLOCK env beats the MXU-fill default; clamped to T)."""
     if tree_block is None:
         tree_block = _int_env(WIDE_BLOCK_ENV) or default_tree_block(n_internal)
     return max(1, min(int(tree_block), n_trees))
@@ -530,8 +529,7 @@ def predict_score_wide(wf: WideGemmForest, x: jnp.ndarray) -> jnp.ndarray:
 
 
 #: Strategy built by the most recent make_predictor/make_margin_predictor
-#: call ("native-cpp" when the C++ engine scored) — bench and the obs cost
-#: attribution label their rows with it.
+#: call ("native-cpp" when the C++ engine scored).
 last_strategy: str = "none"
 
 #: explicit strategy override: {auto,gather,gemm,wide,pallas}
@@ -685,7 +683,7 @@ def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
 
 def make_predictor(forest: FlatForest, n_features: int | None = None,
                    strategy: str | None = None):
-    """Device-finalized fn(x) -> scores (accelerator/bench convenience):
+    """Device-finalized fn(x) -> scores (accelerator convenience):
     the strategy-resolved margin program plus the on-device finalize.
     Engine-parity callers (the filter pipeline) use
     :func:`make_margin_predictor` + host :func:`finalize_margin` instead,

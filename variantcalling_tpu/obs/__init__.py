@@ -25,8 +25,8 @@ Contract (locked by ``tests/unit/test_obs.py``):
 - **output-neutral**: with ``VCTPU_OBS`` on or off, every pipeline's
   output bytes are identical — obs writes only its own sidecar;
 - **cheap when off**: every hook bottoms out in one module-bool check
-  (:func:`active`); hot-path overhead when ON stays under the 2% budget
-  (bench ``obs_overhead_pct``);
+  (:func:`active`); what it costs when ON is measured on the chip
+  (PERF.md section 5, "Cost of tracing");
 - **one ordered stream**: events from any thread serialize through one
   lock that also takes the timestamp, so file order, ``seq`` order and
   ``ts`` order agree.

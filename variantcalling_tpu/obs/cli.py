@@ -18,7 +18,7 @@ of stack-tracing (``tail --follow`` is built on exactly that).
 Exit codes follow the repo-wide CLI contract: 0 success, 2 usage error /
 unreadable or malformed log (argparse's own usage failures also exit 2).
 ``diff`` additionally exits 1 when the candidate regresses beyond the
-noise band — the sentry contract shared with ``tools/bench_gate.py``.
+noise band.
 Covered by ``tests/unit/test_obs.py`` / ``test_obs_profile.py`` /
 ``test_obs_trace.py``.
 """

@@ -207,7 +207,7 @@ def critical_path(events: list[dict]) -> dict:
     # obs v3 reconciliation: when the run carried the continuous CPU
     # profiler, answer "what were the cores DOING during the dominant
     # wait edges" by overlap-joining CPU-sample windows against the wait
-    # intervals collected above — the measured explanation the round-13
+    # intervals collected above — the measured explanation the
     # `writeback.wait` diagnosis needed (docs/perf_notes.md)
     from variantcalling_tpu.obs import sampler as sampler_mod
 
@@ -224,33 +224,6 @@ def critical_path(events: list[dict]) -> dict:
         wait_cpu = sampler_mod.explain_waits(events, intervals)
         if wait_cpu:
             out["wait_cpu"] = wait_cpu
-    return out
-
-
-def compact(cp: dict) -> dict:
-    """The compact roll-up the bench ``e2e`` row commits next to its
-    ``attribution`` blob (the full edge table stays in the obs log)."""
-    if cp.get("chunks", 0) == 0:
-        return {"chunks": 0}
-    out = {
-        "chunks": cp["chunks"],
-        "latency_p50_s": cp["latency_p50_s"],
-        "latency_p95_s": cp["latency_p95_s"],
-        "dominant_edge": cp["dominant_edge"],
-        "dominant_p95_edge": cp["dominant_p95_edge"],
-        "p95_edge_share_pct": {
-            name: d["share_pct"]
-            for name, d in list(cp["p95_edges"].items())[:5]},
-    }
-    # the "cores were running X" answer for the dominant wait edge
-    # (obs v3 reconciliation) rides into the committed bench row
-    dom = cp.get("dominant_p95_edge")
-    wc = (cp.get("wait_cpu") or {}).get(dom)
-    if wc:
-        out["dominant_p95_wait_cpu"] = {
-            "edge": dom,
-            "frames": wc["frames"][:3],
-        }
     return out
 
 

@@ -2,12 +2,9 @@
 and device FLOPs actually go.
 
 PR 5's event stream records *what happened*; this module records *what
-it cost*. The post-PR-5 diagnosis (ROADMAP) is that the system is
-host-IO-bound — streaming e2e ~0.7–0.86M v/s against a 2.25M v/s hot
-path — but nothing could attribute the gap. The GPU-cluster
-variant-calling pipeline work (arXiv 2509.09058, PAPERS.md) gets its
-speedups from per-stage utilization profiling *before* parallelizing;
-this is that layer:
+it cost*. The GPU-cluster variant-calling pipeline work (arXiv
+2509.09058, PAPERS.md) gets its speedups from per-stage utilization
+profiling *before* parallelizing; this is that layer:
 
 - :class:`StageProfiler` / :class:`StageStats` — per-stage wall-clock
   attribution for the streaming executor: **work** (inside the stage
@@ -22,21 +19,14 @@ this is that layer:
   host-CPU utilization every ``VCTPU_OBS_SAMPLE_S`` seconds into run
   gauges (``proc.rss_mb`` / ``proc.cpu_pct``, peaks kept by the gauge),
   with a final ``profile``/``resources`` watermark event.
-- :func:`xla_cost_analysis` — FLOPs from the XLA compiler's
-  ``cost_analysis`` on a *compiled* program, for ``bench.py``'s rows. No
-  run calls it: a pipeline run asks the compiler for nothing that an
-  untraced run would not.
 
 Everything here is gated on ``enabled()`` — obs recording must be on
 (``VCTPU_OBS=1``) AND profiling not opted out (``VCTPU_OBS_PROFILE``,
-default on). The PR 5 contracts hold with profiling enabled: output
-bytes are identical, and total obs+profile overhead stays inside the 2%
-budget (bench ``obs_overhead_pct``, now median-of-5 paired runs — since
-the live-telemetry plane the measured legs also carry causal tracing
-and periodic rolling-window snapshots, and the sampler's gauges ride
-those ``snapshot`` events mid-run, so an external ``vctpu obs
-tail``/``prom`` reader sees fresh RSS/CPU watermarks while the run is
-in flight, not just at ``run_end``).
+default on). The PR 5 contract holds with profiling enabled: output
+bytes are identical. The sampler's gauges ride the periodic ``snapshot``
+events mid-run, so an external ``vctpu obs tail``/``prom`` reader sees
+fresh RSS/CPU watermarks while the run is in flight, not just at
+``run_end``. What obs costs when it is on is PERF.md section 5's.
 """
 
 from __future__ import annotations
@@ -203,7 +193,7 @@ class ResourceSampler(threading.Thread):
     high-water marks even though only the last sample's value survives.
     ``cpu_pct`` is process CPU time over wall time — >100 means multiple
     cores busy (the streaming executor's whole point), so the watermark
-    doubles as a parallelism check against the ``scaling`` bench rows.
+    doubles as a parallelism check.
     ``proc.cpu_pct.<family>`` gauges break the same utilization down by
     THREAD FAMILY (io pool, pipeline stages, committer, prefetch, obs)
     from the per-task CPU clocks in ``/proc/self/task`` — the obs v3
@@ -299,54 +289,3 @@ class ResourceSampler(threading.Thread):
         obs.event("profile", "resources", rss_peak_mb=g_rss.peak,
                   cpu_peak_pct=g_cpu.peak, samples=self.samples,
                   interval_s=self.interval_s)
-
-
-# ---------------------------------------------------------------------------
-# runtime MFU / roofline attribution (XLA cost_analysis)
-# ---------------------------------------------------------------------------
-
-#: Published per-chip peaks, keyed by ``jax.devices()[0].device_kind`` —
-#: the ONE table every utilization/roofline figure divides by (bench.py
-#: reads it too). A device that is not listed gets no such figure: a
-#: number derived from another chip's peak is not a measurement of this
-#: one. Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
-#: 16 GB HBM at 819 GB/s per chip; the chip reports itself as
-#: "TPU v5 lite".
-DEVICE_PEAKS: dict[str, dict[str, float]] = {
-    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9},
-}
-
-
-def device_peaks() -> dict | None:
-    """``{"device_kind", "flops_bf16", "hbm_bytes_per_s"}`` for the device
-    this process runs on, or None when it has no published entry."""
-    import jax
-
-    kind = jax.devices()[0].device_kind
-    peaks = DEVICE_PEAKS.get(kind)
-    return dict(peaks, device_kind=kind) if peaks else None
-
-
-def xla_cost_analysis(jitted, *args) -> dict | None:
-    """FLOPs/bytes from the XLA compiler for ``jitted(*args)``.
-
-    ``args`` may be real arrays or ``jax.ShapeDtypeStruct``\\ s — only
-    shapes/dtypes matter. Returns ``{"flops": float, "bytes_accessed":
-    float}`` or None when the backend/build has no cost model (recorded
-    as a degradation, never raised: attribution is telemetry).
-    """
-    from variantcalling_tpu.utils import degrade
-
-    try:
-        compiled = jitted.lower(*args).compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        out = {"flops": float(ca.get("flops", 0.0) or 0.0)}
-        if ca.get("bytes accessed"):
-            out["bytes_accessed"] = float(ca["bytes accessed"])
-        return out
-    except Exception as e:  # noqa: BLE001 — attribution is telemetry, never fatal
-        degrade.record("obs.cost_analysis", e,
-                       fallback="no runtime FLOP attribution for this run")
-        return None

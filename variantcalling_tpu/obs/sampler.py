@@ -3,9 +3,9 @@ truth.
 
 Everything before this module *derives* where the cores go: the PR 6
 stage profiler attributes wall-clock to stage bodies it was told about,
-the PR 11 critical-path engine walks per-chunk wait edges, and
-docs/perf_notes.md carries an *analytic* cpu-budget table built from a
-one-off cProfile. None of them can answer the round-13 question — the
+the PR 11 critical-path engine walks per-chunk wait edges, and a
+one-off cProfile gives an *analytic* cpu-budget table of the work it
+was pointed at. None of them can answer the round-13 question — the
 dominant p95 edge is ``writeback.wait`` (ordered-commit turn-taking),
 so **what were the cores actually doing while the committed chunk's
 successors waited?** — because nothing in the tree samples the process.
@@ -52,23 +52,20 @@ the GPU-cluster pipeline work (arXiv 2509.09058, PAPERS.md) builds on:
   critical-path engine surfaces);
 - exporters: :func:`to_speedscope` / :func:`collapsed_lines`
   (``vctpu obs flame``), :func:`diff_folds` (``obs flame --diff A B``,
-  the before/after bench comparison), and :func:`cpuledger` — the
+  the before/after comparison), and :func:`cpuledger` — the
   **measured** cpu-seconds-per-1M-variants-per-stage ledger
-  (``vctpu obs cpuledger``) that bench.py commits into the e2e row and
-  ``tools/bench_gate.py`` gates, turning docs/perf_notes.md's analytic
-  budget table into a regression-gated artifact.
+  (``vctpu obs cpuledger``; docs/perf_notes.md says what it was built
+  to answer).
 
 Knobs: ``VCTPU_OBS_CPUPROF=1`` (with ``VCTPU_OBS=1``) starts the
 sampler for the run; ``VCTPU_OBS_CPUPROF_HZ`` sets the rate. The
 default (7 Hz) is deliberately conservative: every tick must hold the
-GIL briefly, and on a SATURATED 2-core host the measured tax grows
-~linearly with rate (47 Hz cost ~10% e2e on this container) — the
-bench ``obs`` phase pairs plane-only legs against plane+sampler legs
-and gates the sampler's marginal cost at ≤2%
-(``obs.cpuprof_overhead_pct``), with output bytes asserted identical.
-Hosts with spare cores can raise the rate freely — the sampler's own
-thread then rides an idle core. Off, the only cost anywhere is one
-module-bool check at the native-span sites.
+GIL briefly, and on a host with no spare core the tax grows ~linearly
+with rate; output bytes are identical with the sampler on or off
+(``tools/prof_smoke.py``). Hosts with spare cores can raise the rate
+freely — the sampler's own thread then rides an idle core. What the
+sampler costs on the chip host: not measured. Off, the only cost
+anywhere is one module-bool check at the native-span sites.
 
 Lock discipline: the family registry is written under ``_REG_LOCK``;
 the native-span table is per-thread-key dict item assignment (the
@@ -668,7 +665,7 @@ def diff_folds(candidate: list[dict], baseline: list[dict],
     """The ``obs flame --diff A B`` report: per-frame CPU self-share in
     the candidate vs the baseline (shares, so runs of different length
     compare), ranked by absolute share delta. An attribution report,
-    not a gate — ``tools/bench_gate.py`` owns pass/fail."""
+    not a gate."""
     cw, ct = _frame_weights(candidate)
     bw, bt = _frame_weights(baseline)
     if not ct or not bt:
@@ -707,7 +704,7 @@ def render_diff(report: dict) -> str:
 
 #: stage attribution markers, matched LEAF-FIRST against each stack's
 #: frames: the first frame (from the leaf) matching a pattern names the
-#: stage. Mirrors the docs/perf_notes.md budget-table rows; frames that
+#: stage. The stages are docs/perf_notes.md's ledger rows; frames that
 #: match nothing book under their thread family as ``other.<family>``.
 STAGE_MARKERS: tuple[tuple[str, re.Pattern], ...] = tuple(
     (stage, re.compile(pat)) for stage, pat in (
@@ -768,8 +765,8 @@ def cpuledger(events: list[dict]) -> dict | None:
     """The measured cpu-budget ledger: CPU seconds per stage (samples in
     CPU categories / hz, attributed by :data:`STAGE_MARKERS`) and —
     when the log records how many variants the run processed —
-    **cpu-s per 1M variants per stage**, the unit docs/perf_notes.md's
-    budget table is written in. None when the log holds no samples."""
+    **cpu-s per 1M variants per stage**. None when the log holds no
+    samples."""
     rate = profiled_rate(events)
     fold = fold_events(events)
     if rate is None or not fold:
@@ -841,19 +838,6 @@ def render_cpuledger(ledger: dict) -> str:
         lines.append("  (no record count in this log — per-1M column "
                      "unavailable)")
     return "\n".join(lines)
-
-
-def compact_ledger(ledger: dict) -> dict:
-    """The bench-row shape (``e2e.cpuledger``) tools/bench_gate.py
-    gates: flat per-stage cpu-s/1M numbers plus the total."""
-    out = {"hz": ledger["hz"], "cpu_samples": ledger["cpu_samples"],
-           "records": ledger["records"]}
-    if "stages" in ledger:
-        out["total_cpu_s_per_1m"] = ledger["total_cpu_s_per_1m"]
-        out["stages"] = dict(ledger["stages"])
-    else:
-        out["total_cpu_s"] = ledger["total_cpu_s"]
-    return out
 
 
 # -- wait-edge reconciliation ----------------------------------------------

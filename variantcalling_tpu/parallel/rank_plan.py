@@ -39,8 +39,7 @@ The pieces:
 Byte contract: the merged output is identical to the single-rank run
 modulo the ``##vctpu_*`` provenance headers (the ``##vctpu_ranks=``
 line exists only when N > 1) — locked by the parity matrix in
-``tests/unit/test_rank_plan.py`` / ``tests/system/test_scaleout.py``
-and by the bench ``scaleout`` digest tripwire.
+``tests/unit/test_rank_plan.py`` / ``tests/system/test_scaleout.py``.
 
 Launchers: ``tools/podrun`` spawns N local workers with
 ``VCTPU_RANK``/``VCTPU_NUM_PROCESSES`` set and commits the merge;

@@ -108,7 +108,7 @@ def resolve_plan(engine_name: str) -> MeshPlan:
     Policy (mirrors ``forest.resolve_strategy``): an EXPLICIT
     ``VCTPU_MESH_DEVICES`` is honored or the run dies loudly
     (EngineError, exit 2) — never silently clamped. Auto keeps one
-    device on the cpu backend (forced-host CPU meshes are a test/bench
+    device on the cpu backend (forced-host CPU meshes are a test
     construct, opted into explicitly) and takes every local device on
     accelerators. The native C++ engine scores on the host — it has no
     XLA program to shard, so any requested mesh resolves to 1 with the
@@ -236,7 +236,7 @@ def megabatch_stream(prepped, ctx, profiler=None):
     scores, this generator keeps pulling ``prepped`` and PACKS group
     N+1, so the dispatch never sits idle waiting for the slowest member
     of the next group to featurize (``score_stage.wait`` was the
-    dominant p95 critical-path edge before the overlap, BENCH_r12).
+    dominant p95 critical-path edge before the overlap).
     Results still yield strictly in canonical chunk order: group N's
     scores are drained before group N+1's dispatch is submitted, and
     memory stays bounded at two groups (one in flight + one packing).

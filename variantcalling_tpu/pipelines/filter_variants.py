@@ -530,8 +530,7 @@ def _native_cpu_featurize_score(model, hf, flow_order: str, table, fasta) -> np.
         if x is None:  # unsupported column dtype: numpy assembly
             x = np.stack([c.astype(np.float32, copy=False) for c in raw], axis=1)
         score = nf(x)
-    # no XLA program exists on this path — record that for perf evidence
-    # (bench distinguishes real jit compile from plain warmup by this)
+    # no XLA program exists on this path — record that
     forest_mod.last_strategy = "native-cpp"  # vctpu-lint: disable=VCT010 — run-scoped diagnostic; GIL-atomic store, every concurrent chunk writes the same value
     return score
 
@@ -1425,7 +1424,7 @@ def run_streaming(args, model, fasta: FastaReader, annotate, blacklist,
 
     # telemetry: callers that came through run() already opened the obs
     # run (start_run returns None and events just join it); direct
-    # callers (bench legs, tests) get their own stream here
+    # callers (tools, tests) get their own stream here
     inputs = {"input": args.input_file}
     if getattr(args, "model_file", None):
         inputs["model"] = args.model_file
@@ -1602,9 +1601,9 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
         (``VcfChunkReader.iter_raw``). A chunk is parsed immediately
         before it scores on the same worker, so no parsed table ever
         waits in a queue between a parse task and a score task — the
-        ``score_stage.wait`` edge that dominated the p95 critical path
-        (BENCH_r12) is gone structurally, not hidden. Parse rides inside
-        the chunk's retry budget (it is a pure function of the held
+        ``score_stage.wait`` edge that dominated the p95 critical path of
+        the layout before it is gone structurally, not hidden. Parse rides
+        inside the chunk's retry budget (it is a pure function of the held
         buffer, so re-dispatch cannot change bytes; its own transient-IO
         retry stays inside ``parse_chunk``). Trace ids were allocated at
         the raw feed in canonical chunk order; the ingest span is

@@ -24,7 +24,7 @@ shape (PRs 16/18) into one online system:
   merge the batch pod uses (``elastic.merge_spans`` ->
   ``rank_plan.splice_segments``): clients receive bytes identical to
   the single-host batch CLI modulo ``##vctpu_*`` provenance headers —
-  sha256-locked by the fabric tests and the bench digest tripwire.
+  sha256-locked by the fabric tests.
 - **Distributed admission**: the PR 11/14 rolling-SLO shed decides
   from the AGGREGATED backend series (the fleet's worst live rolling
   p50), not just local state; bearer-token auth

@@ -375,7 +375,7 @@ class PrincipalQuota:
 
 
 # ---------------------------------------------------------------------------
-# the front-door client (tests, loadhunt, bench, operators)
+# the front-door client (tests, loadhunt, operators)
 # ---------------------------------------------------------------------------
 
 
