@@ -258,9 +258,7 @@ def test_streaming_run_emits_every_span_once_a_chunk(streamed):
         by_name.setdefault(e["name"], []).append(e)
     for name in STAGE_LEVEL + SCORE_PARTS:
         assert name in by_name, f"no {name} span"
-        if name == "dispatch_feed":  # the concatenation, then each bucket
-            assert len(by_name[name]) == 2 * chunks
-        elif name == "score_finalize":  # the margins, then the FILTER column
+        if name == "score_finalize":  # the margins, then the FILTER column
             assert len(by_name[name]) == 2 * chunks
         else:
             assert len(by_name[name]) == chunks, name

@@ -449,18 +449,20 @@ def test_called_sizes_go_with_the_program():
     assert all(k is not None for k in fv._CALLED_AT)
 
 
-def test_dispatch_gates_each_bucket_on_size_genome_shape_and_dtypes(
+def test_dispatch_gates_each_bucket_on_size_and_window_shape(
         fresh_predictor_cache, monkeypatch):
     """``_dispatch_fused`` hands ``_enqueue`` the bucket size and what else
-    makes jax trace anew, under a ``dispatch_enqueue`` span."""
-    from variantcalling_tpu.featurize import _bucket as featurize_bucket
+    makes jax trace anew, under a ``dispatch_enqueue`` span. The wire's
+    layout is static, so no dtype of a chunk's columns is part of it."""
+    from variantcalling_tpu.featurize import (AlleleColumns, HostFeatures,
+                                              _bucket as featurize_bucket)
     from variantcalling_tpu.parallel import shard_score
 
     seen = []
     real = fv._enqueue
 
     def spy(fn, sig, call_args):
-        seen.append((sig, trace.current_span()))
+        seen.append((sig, trace.current_span(), call_args))
         return real(fn, sig, call_args)
 
     monkeypatch.setattr(fv, "_enqueue", spy)
@@ -469,26 +471,29 @@ def test_dispatch_gates_each_bucket_on_size_genome_shape_and_dtypes(
     windows = rng.integers(0, 4, size=(n, 41)).astype(np.uint8)
     model = _forest()
     program = fv._fused_program(model, NAMES, "TGCA")
-    hosts = program[1]
-    cols = tuple(fv._narrow_column(rng.integers(0, 2, n).astype(np.float32))
-                 if i % 2 else rng.uniform(0, 50, n).astype(np.float32)
-                 for i in range(len(hosts)))
-
-    class Alle:
-        is_indel = np.zeros(n, bool)
-        indel_nuc = np.full(n, 4, np.int8)
-        ref_code = np.zeros(n, np.int8)
-        alt_code = np.ones(n, np.int8)
-        is_snp = np.ones(n, bool)
-
-    fi = fv._FusedInputs(n, program, None, None, 0, windows, cols, Alle, model)
+    layout = program[1]
+    cols = {f: rng.integers(0, 2, n).astype(np.float32) if i % 2
+            else rng.uniform(0, 50, n).astype(np.float32)
+            for i, f in enumerate(layout.host_names)}
+    alle = AlleleColumns(is_snp=np.ones(n, bool), is_indel=np.zeros(n, bool),
+                         is_ins=np.zeros(n, bool),
+                         indel_length=np.zeros(n, np.int32),
+                         indel_nuc=np.full(n, 4, np.int32),
+                         ref_code=np.zeros(n, np.int32),
+                         alt_code=np.ones(n, np.int32),
+                         n_alts=np.ones(n, np.int32))
+    hf = HostFeatures(alle=alle, windows=windows, cols=cols, names=list(NAMES))
+    fi = fv._FusedInputs(n, program, None, 0, windows, None, hf)
     plan = shard_score.resolve_plan("jit")
     if plan.devices != 1:
         pytest.skip("single-device dispatch only")
     scores = fv._dispatch_fused([fi], plan)
     assert scores.shape == (n,)
-    (sig, _span), = seen
-    assert sig == (featurize_bucket(n), ((41,), tuple(c.dtype.char for c in cols)))
+    (sig, _span, call_args), = seen
+    assert sig == (featurize_bucket(n), (41,))
+    # one buffer beside the windows: the whole dispatch is two arrays
+    assert [tuple(a.shape) for a in call_args] \
+        == [(featurize_bucket(n), 41), (featurize_bucket(n), layout.words)]
 
 
 # ---------------------------------------------------------------------------

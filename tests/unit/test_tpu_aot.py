@@ -142,6 +142,45 @@ def test_window_gather_compiles_for_v5e_at_hg38_size(v5e):
         assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
+@pytest.mark.parametrize("family", ["dan", "forest"])
+def test_fused_program_over_the_wire_compiles_for_v5e_at_the_cells_size(v5e, family):
+    """The whole fused program as a dispatch calls it since the wire — the
+    resident genome's rows and ONE ``uint32[262144, 10]`` buffer — for one
+    chip and as a pure map over dp=4: the unpack (column slices, shifts,
+    bitcasts) is accepted by the chip's compiler, adds no collective, and
+    the buffer's narrow minor dimension does not blow its device footprint
+    up (10 words a row are stored as 16, not 128)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from variantcalling_tpu.featurize import BASE_FEATURES, GENOME_ROW_WORDS
+    from variantcalling_tpu.pipelines import filter_variants as fv
+    from variantcalling_tpu.synthetic import synthetic_dan, synthetic_forest
+
+    single, mesh, dp_sharded = v5e
+    names = list(BASE_FEATURES)
+    rng = np.random.default_rng(0)
+    model, strategy = (synthetic_dan(rng, names), None) if family == "dan" \
+        else (synthetic_forest(rng, n_trees=40, depth=6), "wide")
+    n_rows, bucket = 6_055_937, 262_144
+    for use_mesh, genome_sharding, wire_sharding in (
+            (None, single, single), (mesh, NamedSharding(mesh, P()), dp_sharded)):
+        fn, layout, _fin = fv._build_fused_program(
+            model, names, "TGCA", True, strategy, use_mesh)
+        assert layout.words == 10
+        compiled = fn.lower(
+            jax.ShapeDtypeStruct((n_rows, GENOME_ROW_WORDS), jnp.uint32,
+                                 sharding=genome_sharding),
+            jax.ShapeDtypeStruct((bucket, layout.words), jnp.uint32,
+                                 sharding=wire_sharding)).compile()
+        text = compiled.as_text()
+        for collective in ("all-gather", "all-reduce", "collective-permute", "all-to-all"):
+            assert collective not in text
+        mem = compiled.memory_analysis()
+        genome_bytes = -(-n_rows // 8) * 8 * GENOME_ROW_WORDS * 4  # whole tiles of 8 rows
+        assert mem.argument_size_in_bytes - genome_bytes <= bucket * 16 * 4
+        assert mem.temp_size_in_bytes < (1 << 30)
+
+
 # ---------------------------------------------------------------------------
 # compile-cache placement
 # ---------------------------------------------------------------------------

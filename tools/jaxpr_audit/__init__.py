@@ -183,8 +183,7 @@ def build_fused_programs(contract: dict) -> list[tuple[str, object, tuple, str]]
     import jax.numpy as jnp
     import numpy as np
 
-    from variantcalling_tpu.featurize import (DEVICE_FEATURES, GENOME_ROW_WORDS,
-                                              WINDOW_RADIUS)
+    from variantcalling_tpu.featurize import GENOME_ROW_WORDS, WINDOW_RADIUS
     from variantcalling_tpu.models.forest import FlatForest
     from variantcalling_tpu.parallel import shard_score
     from variantcalling_tpu.pipelines import filter_variants as fv
@@ -204,13 +203,8 @@ def build_fused_programs(contract: dict) -> list[tuple[str, object, tuple, str]]
         value=base.value, max_depth=base.max_depth,
         aggregation=base.aggregation, feature_names=names)
     rows = int(contract["batch_rows"])
-    host_names = [f for f in names if f not in DEVICE_FEATURES]
-    host_avals = tuple(jax.ShapeDtypeStruct((rows,), jnp.float32)
-                       for _ in host_names)
-    aux = tuple(jax.ShapeDtypeStruct((rows,), jnp.uint8) for _ in range(5))
     win_aval = jax.ShapeDtypeStruct((rows, 2 * WINDOW_RADIUS + 1), jnp.uint8)
     genome_aval = jax.ShapeDtypeStruct((64, GENOME_ROW_WORDS), jnp.uint32)
-    gpos_aval = jax.ShapeDtypeStruct((rows,), jnp.uint32)
     programs: list[tuple[str, object, tuple, str]] = []
     for variant in spec["variants"]:
         for dp in spec["mesh_device_counts"]:
@@ -218,11 +212,12 @@ def build_fused_programs(contract: dict) -> list[tuple[str, object, tuple, str]]
             if dp > 1:
                 plan = shard_score.MeshPlan(dp, str(dp), "jaxpr audit")
                 mesh = shard_score.mesh_for(plan)
-            fn, _hosts, _fin = fv._fused_program(
+            fn, layout, _fin = fv._fused_program(
                 forest, names, "TGCA", genome_resident=(variant == "genome"),
                 strategy="gather", mesh=mesh)
-            avals = ((genome_aval, gpos_aval) if variant == "genome"
-                     else (win_aval,)) + (host_avals,) + aux
+            # the dispatch's one buffer, in the program's wire layout
+            wire_aval = jax.ShapeDtypeStruct((rows, layout.words), jnp.uint32)
+            avals = (genome_aval if variant == "genome" else win_aval, wire_aval)
             programs.append((f"fused/{variant}/dp={dp}", fn, avals, "margin"))
     return programs
 
@@ -242,8 +237,7 @@ def build_dan_programs(contract: dict) -> list[tuple[str, object, tuple, str]]:
     import jax
     import jax.numpy as jnp
 
-    from variantcalling_tpu.featurize import (BASE_FEATURES, DEVICE_FEATURES,
-                                              WINDOW_RADIUS)
+    from variantcalling_tpu.featurize import BASE_FEATURES, WINDOW_RADIUS
     from variantcalling_tpu.models import dan as dan_mod
     from variantcalling_tpu.parallel import shard_score
     from variantcalling_tpu.pipelines import filter_variants as fv
@@ -259,10 +253,6 @@ def build_dan_programs(contract: dict) -> list[tuple[str, object, tuple, str]]:
                           n_layers=int(spec["n_layers"]))
     rows = int(contract["batch_rows"])
     x_aval = jax.ShapeDtypeStruct((rows, len(names)), jnp.float32)
-    host_names = [f for f in names if f not in DEVICE_FEATURES]
-    host_avals = tuple(jax.ShapeDtypeStruct((rows,), jnp.float32)
-                       for _ in host_names)
-    aux = tuple(jax.ShapeDtypeStruct((rows,), jnp.uint8) for _ in range(5))
     win_aval = jax.ShapeDtypeStruct((rows, 2 * WINDOW_RADIUS + 1), jnp.uint8)
     programs: list[tuple[str, object, tuple, str]] = []
     for dp in spec["mesh_device_counts"]:
@@ -274,10 +264,11 @@ def build_dan_programs(contract: dict) -> list[tuple[str, object, tuple, str]]:
         if mesh is not None:
             fn = shard_score.shard_program(fn, mesh, n_data_args=1)
         programs.append((f"dan/score/dp={dp}", fn, (x_aval,), "dan"))
-        fused, _hosts, _fin = fv._fused_program(model, names, "TGCA",
+        fused, layout, _fin = fv._fused_program(model, names, "TGCA",
                                                 mesh=mesh)
+        wire_aval = jax.ShapeDtypeStruct((rows, layout.words), jnp.uint32)
         programs.append((f"dan/fused/windows/dp={dp}", fused,
-                         (win_aval, host_avals) + aux, "dan"))
+                         (win_aval, wire_aval), "dan"))
     return programs
 
 

@@ -571,13 +571,18 @@ class HostFeatures:
     ``names`` is the FULL feature order (host + device columns interleaved
     per BASE_FEATURES); consumers either run the device program to fill the
     device columns (featurize) or fuse them into a larger device program
-    (filter_variants' featurize+score fusion).
+    (filter_variants' featurize+score fusion). Made with
+    ``base_columns=False`` it holds only what Python alone can make —
+    ``alle`` is None and ``cols`` has the extra INFO and interval columns —
+    for the wire's native fill (:mod:`variantcalling_tpu.wire`), which makes
+    the rest from the scan's arrays under the same ``keep_nan``.
     """
 
-    alle: AlleleColumns
+    alle: AlleleColumns | None
     windows: np.ndarray  # (N, 2*WINDOW_RADIUS+1) uint8
     cols: dict[str, np.ndarray]  # host columns only
     names: list[str]  # full feature order, incl. DEVICE_FEATURES
+    keep_nan: bool = False
 
 
 def host_featurize(
@@ -587,6 +592,7 @@ def host_featurize(
     extra_info_fields: list[str] | None = None,
     compute_windows: bool = True,
     keep_nan: bool = False,
+    base_columns: bool = True,
 ) -> HostFeatures:
     """``compute_windows=False`` skips the host window gather — for the
     device-resident-genome scoring path, where windows are gathered in HBM.
@@ -595,32 +601,36 @@ def host_featurize(
     instead of zero-filling — required when the scoring model carries
     xgboost default_left routing, whose semantics are defined ON the
     missing values (the reference feeds raw NaN into predict_proba).
-    """
-    alle = classify_alleles(table)
-    windows = gather_windows(table, fasta) if compute_windows else None
 
-    gts = table.genotypes()
-    is_het = (gts[:, 0] != gts[:, 1]) & (gts[:, 1] >= 0)
-    gq = table.format_numeric("GQ", max_len=1, missing=np.nan)[:, 0]
+    ``base_columns=False`` leaves out the allele classification and the 13
+    base columns (see :class:`HostFeatures`).
+    """
+    windows = gather_windows(table, fasta) if compute_windows else None
 
     def missing(a):
         return a if keep_nan else np.nan_to_num(a, nan=0.0)
 
-    cols: dict[str, np.ndarray] = {
-        "qual": missing(table.qual),
-        "dp": missing(table.info_field("DP")),
-        "sor": missing(table.info_field("SOR")),
-        "af": missing(_compute_af(table)),
-        "gq": missing(gq),
-        "is_het": is_het.astype(np.float32),
-        "is_snp": alle.is_snp.astype(np.float32),
-        "is_indel": alle.is_indel.astype(np.float32),
-        "is_ins": alle.is_ins.astype(np.float32),
-        "indel_length": alle.indel_length,
-        "ref_code": alle.ref_code,
-        "alt_code": alle.alt_code,
-        "n_alts": alle.n_alts,
-    }
+    alle, cols = None, {}
+    if base_columns:
+        alle = classify_alleles(table)
+        gts = table.genotypes()
+        is_het = (gts[:, 0] != gts[:, 1]) & (gts[:, 1] >= 0)
+        gq = table.format_numeric("GQ", max_len=1, missing=np.nan)[:, 0]
+        cols = {
+            "qual": missing(table.qual),
+            "dp": missing(table.info_field("DP")),
+            "sor": missing(table.info_field("SOR")),
+            "af": missing(_compute_af(table)),
+            "gq": missing(gq),
+            "is_het": is_het.astype(np.float32),
+            "is_snp": alle.is_snp.astype(np.float32),
+            "is_indel": alle.is_indel.astype(np.float32),
+            "is_ins": alle.is_ins.astype(np.float32),
+            "indel_length": alle.indel_length,
+            "ref_code": alle.ref_code,
+            "alt_code": alle.alt_code,
+            "n_alts": alle.n_alts,
+        }
     names = list(BASE_FEATURES)
 
     for f in extra_info_fields or []:
@@ -638,7 +648,8 @@ def host_featurize(
             cols[name] = iops.membership(gpos, gs, ge).astype(np.float32)
             names.append(name)
 
-    return HostFeatures(alle=alle, windows=windows, cols=cols, names=names)
+    return HostFeatures(alle=alle, windows=windows, cols=cols, names=names,
+                        keep_nan=keep_nan)
 
 
 def standard_genome_sharding(mesh=None):

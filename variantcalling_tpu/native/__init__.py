@@ -29,6 +29,9 @@ _SRC_MATCH = os.path.join(_DIR, "src", "vctpu_match.cc")
 _SRC_GBT = os.path.join(_DIR, "src", "vctpu_gbt.cc")
 _SRC_FEAT = os.path.join(_DIR, "src", "vctpu_features.cc")
 _SRC_FUSED = os.path.join(_DIR, "src", "vctpu_fused.cc")
+_SRC_WIRE = os.path.join(_DIR, "src", "vctpu_wire.cc")
+#: every translation unit of the library, in link order
+_SRCS = (_SRC, _SRC_CRAM, _SRC_MATCH, _SRC_GBT, _SRC_FEAT, _SRC_FUSED, _SRC_WIRE)
 #: shared inline headers — hashed into the build key (an edit must
 #: rebuild every TU that includes them) but not compiled standalone
 _HDRS = (os.path.join(_DIR, "src", "vctpu_threads.h"),
@@ -68,8 +71,7 @@ def _build() -> str | None:
     hasher = hashlib.sha256()
     hasher.update(" ".join(_CXXFLAGS).encode())  # flag changes rebuild too
     hasher.update(_cpu_tag().encode())  # so does a different host ISA
-    for src in (_SRC, _SRC_CRAM, _SRC_MATCH, _SRC_GBT, _SRC_FEAT, _SRC_FUSED,
-                *_HDRS):
+    for src in (*_SRCS, *_HDRS):
         with open(src, "rb") as fh:
             hasher.update(fh.read())
     tag = hasher.hexdigest()[:12]
@@ -78,8 +80,7 @@ def _build() -> str | None:
         return out
     # per-process tmp name keeps os.replace atomic under concurrent builds
     tmp = f"{out}.{os.getpid()}.tmp"
-    cmd = ["g++", *_CXXFLAGS, "-o", tmp,
-           _SRC, _SRC_CRAM, _SRC_MATCH, _SRC_GBT, _SRC_FEAT, _SRC_FUSED, "-lz"]
+    cmd = ["g++", *_CXXFLAGS, "-o", tmp, *_SRCS, "-lz"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=300)
         os.replace(tmp, out)
@@ -252,6 +253,16 @@ def get_lib() -> ctypes.CDLL | None:
             ctypes.c_int32, ctypes.c_int32,
             ctypes.c_float, ctypes.c_float, ctypes.c_float, ctypes.c_float,
             _i32p, _i32p, _f32p,
+        ]
+        _vp = ctypes.c_void_p  # addresses: the wire fill runs once a dispatch
+        lib.vctpu_wire_fill.restype = _i64
+        lib.vctpu_wire_fill.argtypes = [
+            _vp, _i64, _i64, _i64, _i64, _vp, _i64,
+            _vp, _vp, _vp, _vp, _i64, _i64, ctypes.c_uint32,
+            _vp, _vp, _vp, _vp,
+            _vp, _i64, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+            _vp, _vp, _vp, _vp, _vp, _vp,
+            _vp, _i64, ctypes.c_int32,
         ]
         _LIB = lib
         return _LIB
@@ -1020,6 +1031,51 @@ def fused_chunk_score(run_seqs: list[np.ndarray], run_bounds: np.ndarray,
             out.ctypes.data_as(_f32p),
         )
     return out if rc == 0 else None
+
+
+def wire_fill(dst: np.ndarray, dst_row0: int, lo: int, hi: int,
+              fields: np.ndarray, *, pos, chrom_codes, contig_off, contig_len,
+              radius: int, pos_fill: int, qual, gt, gq, ad, info_vals,
+              info_cols: tuple[int, int, int], aclass, indel_length, indel_nuc,
+              ref_code, alt_code, n_alts, extras: list[np.ndarray],
+              keep_nan: bool) -> bool:
+    """Rows ``[lo, hi)`` of the native scan's arrays into rows
+    ``[dst_row0, dst_row0 + hi - lo)`` of ``dst`` (``uint32[rows, W/4]``, a
+    staging buffer of :mod:`variantcalling_tpu.wire`), in ONE call with the
+    interpreter released. ``fields`` is ``int32[k, 3]``: kind, byte offset,
+    argument (``src/vctpu_wire.cc``); ``info_cols`` the DP, SOR and AF
+    columns of ``info_vals``. False when the library is missing."""
+    lib = get_lib()
+    if lib is None:
+        return False
+
+    keep: list[np.ndarray] = []  # alive until the call returns
+
+    def c(a, dtype):
+        a = np.ascontiguousarray(a, dtype=dtype)
+        keep.append(a)
+        return a.ctypes.data
+
+    if not (dst.dtype == np.uint32 and dst.ndim == 2 and dst.flags.c_contiguous
+            and 0 <= dst_row0 and dst_row0 + (hi - lo) <= dst.shape[0]
+            and hi <= len(pos)):
+        raise ValueError("wire_fill: rows outside the staging buffer or the table")
+    ex = (ctypes.c_void_p * max(len(extras), 1))(
+        *[c(e, np.float32) for e in extras])
+    rc = lib.vctpu_wire_fill(
+        dst.ctypes.data, dst_row0, 4 * dst.shape[1], lo, hi,
+        c(fields, np.int32), len(fields),
+        c(pos, np.int64), c(chrom_codes, np.int32),
+        c(contig_off, np.int64), c(contig_len, np.int64), len(contig_off),
+        radius, pos_fill,
+        c(qual, np.float64), c(gt, np.int8), c(gq, np.float32), c(ad, np.float32),
+        c(info_vals, np.float64), info_vals.shape[1], *info_cols,
+        c(aclass, np.uint8), c(indel_length, np.int32), c(indel_nuc, np.int32),
+        c(ref_code, np.int32), c(alt_code, np.int32), c(n_alts, np.int32),
+        ctypes.addressof(ex), len(extras), int(keep_nan))
+    if rc != hi - lo:
+        raise ValueError(f"wire_fill: the native fill refused its arguments ({rc})")
+    return True
 
 
 def fasta_encode(raw: np.ndarray, line_bases: int, line_width: int,

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from variantcalling_tpu import engine as engine_mod
-from variantcalling_tpu import knobs, logger, obs
+from variantcalling_tpu import knobs, logger, obs, wire
 from variantcalling_tpu.engine import EngineError
 from variantcalling_tpu.utils import degrade, keyed_cache
 from variantcalling_tpu.utils.trace import note, stage, timed
@@ -329,20 +329,28 @@ def _host_names(feature_names: list[str]) -> list[str]:
 def _fused_program(model, feature_names: list[str], flow_order: str,
                    genome_resident: bool = False, strategy: str | None = None,
                    mesh=None):
-    """One jitted device program: windows + host columns -> TREE_SCORE.
+    """One jitted device program: the wire -> TREE_SCORE; returns
+    ``(jitted, layout, finalize)``.
 
     Fuses the window featurization kernels (gc/hmer/motif/cycle-skip) with
-    forest inference so only the per-variant score crosses back to the host
-    — on TPU the feature tensors never leave HBM. Host columns arrive as a
-    TUPLE of 1-D arrays in ``host_names`` order, each in whatever narrow
-    dtype the caller chose (uint8 for integral flag/code columns) — the
-    f32 feature matrix is assembled on device, so the host-to-device copy
-    carries 1 byte instead of 4 for most columns.
+    model inference so only the per-variant score crosses back to the host
+    — on TPU the feature tensors never leave HBM. Its arguments:
 
-    ``genome_resident=True``: the first two arguments become the
-    HBM-resident global genome and the uint32 PACKED per-variant global
-    position — windows are gathered on device, so per-run transfer is
-    4 bytes a variant instead of the 41-byte window row.
+    - ``genome_resident=True``: ``(genome_rows, words)`` — the HBM-resident
+      genome (``featurize.DeviceGenome.rows``, already on the device) and
+      the dispatch's ONE buffer, ``uint32[rows, W/4]`` in the program's
+      :class:`variantcalling_tpu.wire.WireLayout`: the packed position and
+      every host column once, float32 or int32 where values need it, one
+      byte for flags and base codes because of what they are. Windows are
+      gathered on the device from the positions.
+    - ``genome_resident=False``: ``(windows, words)`` — the ``(rows, 41)``
+      uint8 host windows ride beside the same buffer (no position column).
+
+    The layout is a function of the program's host columns
+    (:func:`_host_names`) and of ``genome_resident``, both part of this
+    cache key — never of a chunk's contents, so no data can cause a trace.
+    The program unpacks the buffer (slices, shifts, same-width bitcasts) and
+    assembles the f32 feature matrix on the device.
     """
     key = ("fused", registry_mod.content_digest(model), tuple(feature_names),
            flow_order, genome_resident, _strategy_token(strategy), mesh)
@@ -352,7 +360,7 @@ def _fused_program(model, feature_names: list[str], flow_order: str,
 
 def _build_fused_program(model, feature_names, flow_order, genome_resident,
                          strategy, mesh):
-    """A miss of :func:`_fused_program`: ``(jitted, host_names, finalize)``."""
+    """A miss of :func:`_fused_program`: ``(jitted, layout, finalize)``."""
     from variantcalling_tpu.featurize import (CENTER, device_feature_dict,
                                               windows_from_packed)
 
@@ -363,23 +371,21 @@ def _build_fused_program(model, feature_names, flow_order, genome_resident,
     # FlatForest programs return margins and `finalize` (shared with the
     # native engine) produces the final score bits on the host.
     predictor, finalize = _raw_predictor(model, feature_names, strategy=strategy)
-    host_names = _host_names(feature_names)
-    host_idx = {f: i for i, f in enumerate(host_names)}
+    layout = wire.layout_for(tuple(_host_names(feature_names)), genome_resident)
 
     # The three parts carry names of their own into the compiled program
     # (jax.named_scope is metadata: no operation, byte or program moves),
     # so a device trace groups operations by part whatever their shapes.
-    def body(windows, host_cols, is_indel, indel_nuc, ref_code, alt_code, is_snp):
+    def body(windows, col):
         with jax.named_scope(SCOPE_WINDOW_FEATURES):
-            dev = device_feature_dict(windows, is_indel.astype(bool),
-                                      indel_nuc.astype(jnp.int32),
-                                      ref_code.astype(jnp.int32),
-                                      alt_code.astype(jnp.int32),
-                                      is_snp.astype(bool),
+            dev = device_feature_dict(windows, col["is_indel"].astype(bool),
+                                      col["indel_nuc"].astype(jnp.int32),
+                                      col["ref_code"].astype(jnp.int32),
+                                      col["alt_code"].astype(jnp.int32),
+                                      col["is_snp"].astype(bool),
                                       center=CENTER, flow_order=flow_order)
             cols = [
-                dev[f].astype(jnp.float32) if f in dev
-                else host_cols[host_idx[f]].astype(jnp.float32)
+                (dev[f] if f in dev else col[f]).astype(jnp.float32)
                 for f in feature_names
             ]
             x = jnp.stack(cols, axis=1)
@@ -387,50 +393,28 @@ def _build_fused_program(model, feature_names, flow_order, genome_resident,
             return predictor(x)
 
     if genome_resident:
-        def fn(genome_rows, gpos, host_cols, is_indel, indel_nuc,
-               ref_code, alt_code, is_snp):
+        def fn(genome_rows, words):
+            col = wire.unpack(layout, words)
             with jax.named_scope(SCOPE_WINDOW_GATHER):
-                windows = windows_from_packed(genome_rows, gpos)
-            return body(windows, host_cols,
-                        is_indel, indel_nuc, ref_code, alt_code, is_snp)
+                windows = windows_from_packed(genome_rows, col["pos"])
+            return body(windows, col)
     else:
-        fn = body
+        def fn(windows, words):
+            return body(windows, wire.unpack(layout, words))
 
     if mesh is not None:
         # the mesh-sharded layout: the SAME fused body runs per device
-        # over its dp shard (genome replicated, every data argument's
-        # leading axis sharded) — a pure map with no collectives, so
-        # per-row score bits cannot depend on the device count
+        # over its dp shard (genome replicated, the buffer's — and the
+        # windows' — leading axis sharded) — a pure map with no
+        # collectives, so per-row score bits cannot depend on the device
+        # count
         from variantcalling_tpu.parallel import shard_score
 
         fn = shard_score.shard_program(
-            fn, mesh, n_data_args=7,
+            fn, mesh, n_data_args=1 if genome_resident else 2,
             replicated_leading=1 if genome_resident else 0)
 
-    return jax.jit(fn), host_names, finalize
-
-
-def _narrow_column(a: np.ndarray) -> np.ndarray:
-    """Cheapest exact wire dtype for a host feature column.
-
-    uint8 when every value is an exact small non-negative integer (flags,
-    base codes, interval membership, n_alts), else float32. Exactness is
-    checked, not assumed — scores must be bit-identical to the f32 path.
-    """
-    a = np.asarray(a)
-    if a.dtype == np.uint8 or a.dtype == np.bool_:
-        return a
-    small = a.astype(np.uint8, copy=True) if a.dtype.kind in "iu" else None
-    if small is None and a.dtype.kind == "f":
-        if not np.isfinite(a).all():  # NaN/inf: the uint8 probe cast is UB
-            return a.astype(np.float32, copy=False)
-        small = a.astype(np.uint8)
-        if not np.array_equal(small.astype(a.dtype), a):
-            return a.astype(np.float32, copy=False)
-        return small
-    if small is not None and np.array_equal(small.astype(a.dtype), a):
-        return small
-    return a.astype(np.float32, copy=False)
+    return jax.jit(fn), layout, finalize
 
 
 def _fused_native_chunk_score(ordered, hf, fo: np.ndarray, table,
@@ -539,23 +523,42 @@ class _FusedInputs:
     """One chunk's prepared inputs for the fused featurize+score program —
     the unit :func:`_dispatch_fused` packs into device megabatches
     (parallel/shard_score.py). ``program`` is the cached
-    ``(_fused_program)`` triple; chunks sharing it concatenate into one
-    megabatch, chunks that resolved a different layout dispatch alone."""
+    ``(_fused_program)`` triple; chunks sharing it fill consecutive rows of
+    one staging buffer, chunks that resolved a different layout dispatch
+    alone. ``table`` feeds the wire's native fill (``hf.alle`` is None:
+    ``hf.cols`` holds only the Python-made columns); a complete ``hf``
+    feeds the numpy fill."""
 
-    __slots__ = ("n", "program", "genome", "gpos", "gpos_fill", "windows",
-                 "host_cols", "alle", "model")
+    __slots__ = ("n", "program", "genome", "gpos_fill", "windows", "table",
+                 "hf", "_columns")
 
-    def __init__(self, n, program, genome, gpos, gpos_fill, windows,
-                 host_cols, alle, model):
+    def __init__(self, n, program, genome, gpos_fill, windows, table, hf):
         self.n = n
         self.program = program
         self.genome = genome
-        self.gpos = gpos
         self.gpos_fill = gpos_fill
         self.windows = windows
-        self.host_cols = host_cols
-        self.alle = alle
-        self.model = model
+        self.table = table
+        self.hf = hf
+        self._columns = None
+
+    def fill(self, buf, row0: int, lo: int, hi: int) -> bool:
+        """Rows ``[lo, hi)`` of this chunk into ``buf`` from row ``row0``;
+        True when the native fill wrote them."""
+        if buf.windows is not None:
+            buf.windows[row0:row0 + (hi - lo)] = self.windows[lo:hi]
+        if self.hf.alle is None:
+            wire.fill_native(buf, row0, self.table, lo, hi, self.hf.cols,
+                             self.genome, self.hf.keep_nan)
+            return True
+        if self._columns is None:  # once a chunk, however many buckets it spans
+            from variantcalling_tpu.featurize import globalize_positions
+
+            gpos = globalize_positions(self.table, self.genome) \
+                if self.genome is not None else None
+            self._columns = wire.numpy_columns(buf.layout, self.hf, gpos)
+        wire.fill_numpy(buf, row0, self._columns, lo, hi)
+        return False
 
 
 def _prepare_fused_inputs(model, hf, flow_order: str,
@@ -564,8 +567,9 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
                           strategy: str | None = None,
                           plan=None) -> _FusedInputs:
     """Host half of the fused scoring path for ONE chunk: window/genome
-    layout decision, program build (strategy + mesh pinned), narrowed
-    host columns.
+    layout decision and program build (strategy + mesh pinned). The rows
+    themselves are written by :func:`_dispatch_fused`, straight into the
+    dispatch's staging buffer.
 
     With ``table``+``fasta`` and no precomputed host windows, the
     device-resident-genome path runs: the encoded genome lives in HBM
@@ -581,13 +585,12 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
     mesh = shard_score.mesh_for(plan)
     with stage("prepare_inputs"):
         windows = hf.windows
-        genome = gpos_all = None
+        genome = None
         gpos_fill = 0
         genome_resident = windows is None and table is not None and fasta is not None
         if genome_resident:
             from variantcalling_tpu.featurize import (device_genome, gather_windows,
                                                       genome_packable,
-                                                      globalize_positions,
                                                       packed_position_fill)
 
             if not genome_packable(fasta):
@@ -604,20 +607,16 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
 
                 genome = device_genome(
                     fasta, sharding=standard_genome_sharding(mesh))
-                gpos_all = globalize_positions(table, genome)
                 gpos_fill = packed_position_fill(genome)
         if genome_resident:
             obs.request_note(genome_resident=True)  # serve counts such requests
-        host_cols = tuple(_narrow_column(hf.cols[f])
-                          for f in _host_names(hf.names))
 
     with stage("fused_program", built=False, waited=False):
         program = _fused_program(model, hf.names, flow_order,
                                  genome_resident=genome_resident,
                                  strategy=strategy, mesh=mesh)
     n = len(table) if table is not None else len(windows)
-    return _FusedInputs(n, program, genome, gpos_all, gpos_fill, windows,
-                        host_cols, hf.alle, model)
+    return _FusedInputs(n, program, genome, gpos_fill, windows, table, hf)
 
 
 #: argument signatures each live jit object has been called at. A jit
@@ -666,42 +665,35 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     unpack. Scoring is row-local, so the packed scores are bit-identical
     to per-chunk dispatch at any device count (the mesh parity matrix in
     tests/unit/test_shard_score.py locks this).
+
+    A dispatch is ONE host-to-device copy (two with host windows): the
+    chunks' rows are written in one pass each into a bucket-sized staging
+    buffer of the program's wire layout (:mod:`variantcalling_tpu.wire`),
+    which goes back to the pool with the array whose readiness frees it.
+    Counters: ``feed.dispatches``, ``feed.h2d_arrays`` (arrays handed to
+    the device, the genome excluded), ``feed.native_fills`` /
+    ``feed.numpy_fills`` (dispatches whose rows every chunk's native fill
+    wrote / the rest).
     """
     from variantcalling_tpu.featurize import _bucket
     from variantcalling_tpu.parallel import shard_score
     from variantcalling_tpu.parallel.mesh import data_sharding
 
     first = inputs[0]
-    fn, _host_names, finalize = first.program
+    fn, layout, finalize = first.program
     mesh = shard_score.mesh_for(plan)
     n_dev = plan.devices
-    shard2 = data_sharding(mesh, 2) if mesh is not None else None
+    sharding = data_sharding(mesh, 2) if mesh is not None else None
     chunk_size = max(CHUNK, n_dev) - (CHUNK % n_dev if n_dev > 1 else 0)
-
-    def cat(arrs):
-        return np.asarray(arrs[0]) if len(arrs) == 1 else \
-            np.concatenate([np.asarray(a) for a in arrs])
-
-    genome_resident = first.gpos is not None
     genome = first.genome
-    gpos_fill = first.gpos_fill
-    with stage("dispatch_feed"):
-        if genome_resident:
-            gpos_all, windows = cat([i.gpos for i in inputs]), None
-        else:
-            gpos_all, windows = None, cat([i.windows for i in inputs])
-        host_cols = tuple(cat([i.host_cols[k] for i in inputs])
-                          for k in range(len(first.host_cols)))
-        is_indel = cat([i.alle.is_indel for i in inputs])
-        indel_nuc = cat([i.alle.indel_nuc for i in inputs])
-        ref_code = cat([i.alle.ref_code for i in inputs])
-        alt_code = cat([i.alle.alt_code for i in inputs])
-        is_snp = cat([i.alle.is_snp for i in inputs])
+    # where the backend reads host memory in place, the buffer is busy
+    # until the program has run; elsewhere until its copy has landed
+    until_result = wire.put_reads_host_memory()
     # what, besides the bucket size, makes jax trace this program anew
-    shapes = (genome.rows.shape if genome_resident else windows.shape[1:],
-              tuple(c.dtype.char for c in host_cols))
+    shapes = genome.rows.shape if layout.resident else first.windows.shape[1:]
 
-    n = sum(i.n for i in inputs)
+    spans = shard_score.pack_lengths([i.n for i in inputs])
+    n = spans[-1][1]
     out = np.empty(n, dtype=np.float32)
     pending: list[tuple[int, int, object]] = []
 
@@ -722,36 +714,28 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
         # power-of-two bucket (rounded up to a dp multiple) so distinct batch
         # sizes reuse the same compiled program instead of retracing
         target = min(chunk_size, -(-_bucket(hi - lo) // n_dev) * n_dev)
-        pad = target - (hi - lo)
-
-        def prep(a, fill=0):
-            c = np.asarray(a)[lo:hi]
-            if pad:
-                c = np.pad(c, [(0, pad)] + [(0, 0)] * (c.ndim - 1), constant_values=fill)
-            if shard2 is not None:
-                return jax.device_put(c, shard2 if c.ndim == 2 else data_sharding(mesh, 1))
-            return jnp.asarray(c)
 
         # async dispatch overlaps chunk i+1's upload with chunk i's compute;
         # the bounded in-flight window keeps device residency at O(chunk)
         # (plus the resident genome) instead of the whole dataset
         with stage("dispatch_feed", rows=target):
-            common = (
-                tuple(prep(c) for c in host_cols),
-                prep(is_indel),
-                prep(indel_nuc, fill=4),
-                prep(ref_code, fill=4),
-                prep(alt_code, fill=4),
-                prep(is_snp),
-            )
-            if genome_resident:
-                # padding positions sit past the genome end -> all-N windows
-                call_args = (genome.rows, prep(gpos_all, fill=gpos_fill), *common)
-            else:
-                call_args = (prep(windows, fill=4), *common)
+            buf = wire.POOL.take(target, layout)
+            native = True
+            for inp, (start, stop) in zip(inputs, spans):
+                a, b = max(lo, start), min(hi, stop)
+                if a < b:
+                    native &= inp.fill(buf, a - lo, a - start, b - start)
+            # padding positions sit past the genome end -> all-N windows
+            buf.pad_from(hi - lo, first.gpos_fill)
+            sent = tuple(jax.device_put(a, sharding) for a in buf.arrays())
+        obs.counter("feed.dispatches").add(1)
+        obs.counter("feed.h2d_arrays").add(len(sent))
+        obs.counter("feed.native_fills" if native else "feed.numpy_fills").add(1)
+        call_args = (genome.rows, *sent) if layout.resident else sent
         # the enqueue; on a first call also trace + lower + cache load or compile
         with stage("dispatch_enqueue", rows=target, waited=False):
             res = _enqueue(fn, (target, shapes), call_args)
+        wire.POOL.give(buf, (res,) if until_result else sent)
         pending.append((lo, hi, res))
         while len(pending) > 2:
             plo, phi, res = pending.pop(0)
@@ -1053,9 +1037,17 @@ class FilterContext:
             or not isinstance(model, _FUSED_MODEL_TYPES)
             or not _genome_resident_worthwhile(table, fasta, sharding=genome_sharding)
         )
+        # a chunk bound for the fused jit program whose table came through
+        # the native parser needs no base column made here: the wire's
+        # native fill writes them from the scan's arrays, straight into the
+        # dispatch's staging buffer (variantcalling_tpu/wire.py)
+        wire_native = (self.engine.name == "jit"
+                       and isinstance(model, _FUSED_MODEL_TYPES)
+                       and wire.native_fillable(table))
         hf = host_featurize(table, fasta, annotate_intervals=self.annotate_intervals,
                             extra_info_fields=self.extra_info,
-                            compute_windows=needs_host_windows, keep_nan=self.keep_nan)
+                            compute_windows=needs_host_windows, keep_nan=self.keep_nan,
+                            base_columns=not wire_native)
         if self.is_mutect and "TLOD" in hf.cols:
             hf.cols["tlod"] = hf.cols.pop("TLOD")
             hf.names[hf.names.index("TLOD")] = "tlod"
@@ -1517,6 +1509,8 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     obs.counter("predictor.builds").add(0)
     obs.counter("predictor.reuses").add(0)
     obs.counter("predictor.waits").add(0)
+    for name in ("dispatches", "h2d_arrays", "native_fills", "numpy_fills"):
+        obs.counter(f"feed.{name}").add(0)
     # continuous-profiler attribution (obs v3): this thread runs the
     # sequenced single-writer commit loop for the duration of the run
     sampler_mod.register_current("committer")
