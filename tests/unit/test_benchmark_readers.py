@@ -206,9 +206,10 @@ def test_the_serve_cells_metrics_name_readers_and_list_only_that_cell(bench):
                   encoding="utf-8") as fh:
             how = json.load(fh)
         assert callable(bench.load("readers", how["reader"]).read)
-    # the cell is appended, one chip, with nothing cut, and no roofline lists it
-    assert bm["workloads"][-1]["name"] == cell and bm["workloads"][-1]["chips"] == 1
-    assert bm["configs"][-1]["reduced"] == []
+    # the cell has one chip, with nothing cut, and no roofline lists it
+    (entry,) = [w for w in bm["workloads"] if w["name"] == cell]
+    (config,) = [c for c in bm["configs"] if c["name"] == entry["config"]]
+    assert entry["chips"] == 1 and config["reduced"] == []
     assert cell not in by_name["forest_wide_block_roofline"]["workloads"]
     with open(os.path.join(BENCH, "traffic", "serve-c4.json"), encoding="utf-8") as fh:
         traffic = json.load(fh)
@@ -242,3 +243,92 @@ def test_forest_required_flops_match_the_programs_gemm_shapes(bench):
                                config["n_internal"], config["n_leaves"])
     assert bench.load("families", "forest").flops_per_variant(config) \
         == 2 * t * (fdim * i + i * l + l) == 111_680
+
+
+# -- the four-chip host (ISSUE 34) ---------------------------------------------
+
+#: four chips over a 10 s interval: busy 1, 2, 2 (two operations that
+#: overlap for half a second count once) and 0.5 s
+FOUR_CHIPS = [[("fusion.1", 1 * S, 1 * S)],
+              [("fusion.1", 1 * S, 2 * S)],
+              [("fusion.1", 1 * S, 1.5 * S), ("fusion.2", 2 * S, 1 * S)],
+              [("fusion.1", 9 * S, 0.5 * S)]]
+
+
+@pytest.mark.parametrize("devices, what, want", [
+    (FOUR_CHIPS, "least", 5.0), (FOUR_CHIPS, "spread", 15.0),
+    (FOUR_CHIPS[:2], "spread", 10.0),
+    (FOUR_CHIPS[:1], "least", None),   # one chip: no balance to read
+    (FOUR_CHIPS[:1], "spread", None),
+])
+def test_chip_busy_reads_each_chips_share_of_the_traced_interval(
+        bench, devices, what, want):
+    reader = bench.load("readers", "chip_busy")
+    got = reader.read(context(device_events=devices, traced_s=10.0), what)
+    assert got == (pytest.approx(want) if want is not None else None)
+    if len(devices) == 4:
+        assert reader.shares(context(device_events=devices, traced_s=10.0)) \
+            == pytest.approx([10.0, 20.0, 20.0, 5.0])
+
+
+HOST4_CELL = "forest-t40d6-hg38x2-host4.wgs-batch"
+HOST4_METRICS = {
+    "mesh_devices": ("scoring mesh", "obs_counter", final(**{"mesh.devices": 4}), 4.0),
+    "megabatch_fill_share": (
+        "scoring mesh", "counter_ratio",
+        final(**{"mesh.rows": 2_000_000, "mesh.padded_rows": 14 * 262_144}),
+        100 * 2_000_000 / (14 * 262_144)),
+    "mesh_dispatches_per_file": ("scoring mesh", "obs_counter",
+                                 final(**{"mesh.dispatches": 14}), 14.0),
+    "mesh_chunks_per_file": ("scoring mesh", "obs_counter",
+                             final(**{"mesh.chunks": 14}), 14.0),
+    "megabatch_pack_work_share": ("scoring mesh", "stage_share", None, 25.0),
+    "chip_busy_min_share": ("device", "chip_busy", None, 5.0),
+    "chip_busy_spread": ("device", "chip_busy", None, 15.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(HOST4_METRICS))
+def test_the_host4_cells_metrics_read_what_the_program_writes(bench, name):
+    """Each metric of the four-chip cell lists only that cell, names its
+    reader, reads a hand-made context, and reads nothing (and does not
+    raise) from a program that lacks the counter or span, as the parent does."""
+    layer, reader, snapshot, want = HOST4_METRICS[name]
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    (m,) = [m for m in bm["per_layer"] if m["name"] == name]
+    assert (m["workloads"], m["moves"], m["layer"]) == ([HOST4_CELL], "variants_per_s", layer)
+    with open(os.path.join(BENCH, "layer_metrics", name + ".json"), encoding="utf-8") as fh:
+        how = json.load(fh)
+    assert how["reader"] == reader
+    read = bench.load("readers", reader).read
+    events = [snapshot] if snapshot else [
+        {"kind": "profile", "name": "stage", "stage": "megabatch_pack", "work_s": 0.125},
+        {"kind": "profile", "name": "pipeline", "wall_s": 0.5}]
+    ctx = context(device_events=FOUR_CHIPS, traced_s=10.0, obs_events=events)
+    assert read(ctx, **how["args"]) == pytest.approx(want)
+    parent = context(obs_events=[final(**{"feed.dispatches": 14}),
+                                 {"kind": "profile", "name": "pipeline", "wall_s": 0.5}])
+    assert read(parent, **how["args"]) is None
+
+
+def test_the_host4_configuration_is_the_one_chip_one_on_another_cluster():
+    """Key for key the one-chip file, except what states the deployment; one
+    four-chip cell on the existing traffic mix."""
+    def load(name):
+        with open(os.path.join(BENCH, "configs", name + ".json"), encoding="utf-8") as fh:
+            return json.load(fh)
+
+    one, four = load("forest-t40d6-hg38x2"), load("forest-t40d6-hg38x2-host4")
+    differ = {"name", "source", "deployment", "mesh", "guarantees", "assumed"}
+    assert set(four) - set(one) == {"mesh", "guarantees"} and set(one) <= set(four)
+    assert {k for k in one if one[k] != four[k]} == differ - {"mesh", "guarantees"}
+    assert four["assumed"][1:] == one["assumed"]  # the cut's reason, then the same
+    assert four["mesh"]["chips"] == four["mesh"]["dp"] == 4 and four["mesh"]["mp"] == 1
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    (cell,) = [w for w in bm["workloads"] if w["config"] == four["name"]]
+    assert (cell["name"], cell["traffic"], cell["chips"]) == (HOST4_CELL, "wgs-batch", 4)
+    (entry,) = [c for c in bm["configs"] if c["name"] == four["name"]]
+    assert entry["source"] == four["source"] and entry["reduced"] == four["reduced"]
+    assert [w["name"] for w in bm["workloads"] if w["chips"] == 4] == [HOST4_CELL]

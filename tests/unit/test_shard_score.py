@@ -16,10 +16,18 @@ Locks the contracts the mesh dispatch must keep:
 - **Forced-host route**: ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
   in a fresh subprocess produces the same record bytes as the in-process
   mesh (the container-visible path to multi-device testing).
+- **The four-chip host** (ISSUE 34, the benchmark's
+  ``forest-t40d6-hg38x2-host4`` at a small size): whole ``main()`` runs at
+  dp=4 over a resident, replicated genome and the native wire fill agree
+  with the benchmark's plain numpy reference and equal the dp=1 run's bytes
+  modulo the one ``##vctpu_mesh=`` line; the ``mesh.*`` counters add up, and
+  ``megabatch_pack`` / ``genome_upload`` / the dispatch worker's feed spans
+  are where the per-layer metrics read them.
 """
 
 from __future__ import annotations
 
+import json
 import os
 import pickle
 import subprocess
@@ -263,6 +271,34 @@ def test_serial_io_mesh_layout_attribution_not_double_counted(mesh_world,
     assert stages["ingest"]["items"] == stats["chunks"]
 
 
+def test_a_runs_tail_chunk_takes_the_layout_of_the_chunks_before_it(
+        mesh_world, monkeypatch):
+    """The mesh layout featurizes a file's chunks ahead of the dispatch that
+    uploads the genome: asked alone, a tail chunk under the threshold would
+    gather on the host in a reference's first file and on the device ever
+    after (a second program, compiled in the second file). The feed notes
+    each chunk in order, and the tail follows the chunks before it."""
+    from variantcalling_tpu import featurize
+    from variantcalling_tpu.io.vcf import VcfChunkReader
+
+    w = mesh_world
+    tables = list(VcfChunkReader(f"{w['dir']}/calls.vcf",
+                                 chunk_bytes=1 << 15, io_threads=1))
+    head, tail = tables[0], tables[-1]
+    assert len(tail) < len(head)
+    monkeypatch.setattr(featurize, "GENOME_RESIDENT_MIN_VARIANTS", len(head))
+    featurize._DEVICE_GENOME_CACHE.clear()
+    ctx = _filter_context(w, monkeypatch, devices=4)
+    assert ctx.host_features(tail).windows is not None  # alone: host gather
+    ctx.note_chunk(tail)
+    assert not ctx.genome_wanted
+    ctx.note_chunk(head)
+    assert ctx.genome_wanted
+    assert ctx.host_features(head).windows is None
+    assert ctx.host_features(tail).windows is None  # after a large chunk: the device
+    assert featurize.device_genome_stats()["entries"] == 0  # nothing uploaded yet
+
+
 # ---------------------------------------------------------------------------
 # acceptance: byte parity at forced device counts x engine x strategy
 # ---------------------------------------------------------------------------
@@ -349,3 +385,182 @@ def test_forced_host_device_count_subprocess_parity(mesh_world, monkeypatch,
     data = open(out, "rb").read()
     assert b"##vctpu_mesh=dp=4" in data
     assert _modulo_header(data) == _modulo_header(open(out_ref, "rb").read())
+
+
+# ---------------------------------------------------------------------------
+# the four-chip host as the benchmark's configuration states it, small
+# ---------------------------------------------------------------------------
+
+_BENCH = os.path.join(_REPO, "benchmarks")
+_HOST4_GENOME, _HOST4_N, _HOST4_SEED = 240_000, 6000, 34
+_FEED_SPANS = ("dispatch_feed", "dispatch_enqueue", "dispatch_wait",
+               "score_finalize")
+
+
+def _obs_events(out):
+    with open(out + ".obs.jsonl", encoding="utf-8") as fh:
+        return [json.loads(ln) for ln in fh if ln.strip()]
+
+
+def _final(events):
+    (snap,) = [e for e in events
+               if e.get("kind") == "metrics" and e.get("name") == "final"]
+    return snap
+
+
+def _spans(events, name):
+    return [e for e in events if e.get("kind") == "span" and e.get("name") == name]
+
+
+@pytest.fixture(scope="module")
+def host4(tmp_path_factory):
+    """Both references of ``forest-t40d6-hg38x2-host4`` at 240 kbp, a callset
+    each, the configuration's forest from its ``weights_seed``; every file
+    through ``main()`` as the cell's driver calls it, under obs: at dp=4
+    (each reference, the first again), then at dp=1. Chunks of about 1,500
+    rows count as large enough for the resident genome, two to a megabatch."""
+    from variantcalling_tpu import engine as engine_mod
+    from variantcalling_tpu import featurize
+    from variantcalling_tpu.__main__ import main
+    from variantcalling_tpu.models import registry
+
+    mp = pytest.MonkeyPatch()
+    mp.syspath_prepend(_BENCH)
+    import fixtures
+    import lookup
+
+    with open(os.path.join(_BENCH, "configs", "forest-t40d6-hg38x2-host4.json"),
+              encoding="utf-8") as fh:
+        config = json.load(fh)
+    for k, v in {"VCTPU_ENGINE": "jit", "VCTPU_OBS": "1",
+                 "VCTPU_STREAM_CHUNK_BYTES": str(1 << 17),
+                 "VCTPU_MESH_MEGABATCH_ROWS": "2500"}.items():
+        mp.setenv(k, v)
+    mp.setattr(featurize, "GENOME_RESIDENT_MIN_VARIANTS", 1000)
+    saved = engine_mod._RESOLVED
+    engine_mod.reset_for_tests()
+    featurize._DEVICE_GENOME_CACHE.clear()
+    d = str(tmp_path_factory.mktemp("host4"))
+    family = lookup.load("families", config["family"])
+    weights = family.arrays(config["weights_seed"], config)
+    models = os.path.join(d, "models.pkl")
+    registry.save_models(models, {config["model_name"]:
+                                  family.to_program(config, weights)})
+    w = {"config": config, "weights": weights, "refs": []}
+    for r in config["references"]:
+        seed, nc = r["reference_seed"], r["n_contigs"]
+        ref = {"seed": seed, "n_contigs": nc, "path": f"{d}/ref_{seed}.fa",
+               "calls": f"{d}/calls_{seed}.vcf"}
+        fixtures.write_reference(ref["path"], seed, _HOST4_GENOME, nc)
+        fixtures.write_callset(ref["calls"], seed, _HOST4_GENOME, nc,
+                               _HOST4_N, _HOST4_SEED)
+        w["refs"].append(ref)
+
+    def run(ref, tag, devices):
+        mp.setenv("VCTPU_MESH_DEVICES", str(devices))
+        out = f"{d}/{tag}_{ref['seed']}.vcf"
+        assert main(["filter_variants_pipeline", "--input_file", ref["calls"],
+                     "--model_file", models, "--model_name",
+                     config["model_name"], "--reference_file", ref["path"],
+                     "--output_file", out]) == 0
+        return out
+
+    for ref in w["refs"]:
+        ref["dp4"] = run(ref, "dp4", 4)
+    w["refs"][0]["dp4_again"] = run(w["refs"][0], "dp4again", 4)
+    for ref in w["refs"]:
+        ref["dp1"] = run(ref, "dp1", 1)
+    yield w
+    featurize._DEVICE_GENOME_CACHE.clear()
+    engine_mod._RESOLVED = saved
+    mp.undo()
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_host4_main_agrees_with_the_plain_reference(host4, which):
+    import fixtures
+    import reference
+    import run_cell
+
+    ref, config = host4["refs"][which], host4["config"]
+    with open(ref["calls"], "rb") as fh:
+        lines_in = run_cell.body_lines(fh.read())
+    with open(ref["dp4"], "rb") as fh:
+        lines_out = run_cell.body_lines(fh.read())
+    got = reference.compare(
+        lines_in, lines_out, family=config["family"], weights=host4["weights"],
+        body=fixtures.contig_body(ref["seed"], _HOST4_GENOME // ref["n_contigs"]),
+        n_contigs=ref["n_contigs"], score_limit=config["limits"]["score_gap_max"])
+    assert got["records"] == _HOST4_N
+    assert all(got[k] <= v for k, v in config["limits"].items()), got
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_host4_bytes_equal_one_device_modulo_the_mesh_line(host4, which):
+    ref = host4["refs"][which]
+    with open(ref["dp4"], "rb") as fh:
+        dp4 = fh.read().split(b"\n")
+    with open(ref["dp1"], "rb") as fh:
+        dp1 = fh.read().split(b"\n")
+    assert dp4.count(b"##vctpu_mesh=dp=4") == 1
+    assert not any(ln.startswith(b"##vctpu_mesh=") for ln in dp1)
+    assert [ln for ln in dp4 if ln != b"##vctpu_mesh=dp=4"] == dp1
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_mesh_counters_add_up_on_four_devices(host4, which):
+    events = _obs_events(host4["refs"][which]["dp4"])
+    snap = _final(events)
+    c = snap["counters"]
+    chunks = snap["histograms"]["chunk.records"]["count"]
+    assert chunks >= 3  # two groups: a chunk at the target alone, then two chunks
+    assert c["mesh.devices"] == 4
+    assert c["mesh.rows"] == c["records"] == _HOST4_N
+    assert c["mesh.chunks"] == chunks
+    assert c["mesh.padded_rows"] % 4 == 0 and c["mesh.padded_rows"] >= c["mesh.rows"]
+    assert c["mesh.dispatches"] == c["feed.dispatches"] < chunks
+    assert c["feed.native_fills"] == c["feed.dispatches"]
+    # the tail chunk (under the resident threshold) gathered from the
+    # device like the chunks before it, in a reference's first file too:
+    # one array a dispatch, two with host windows
+    assert c["feed.h2d_arrays"] == c["feed.dispatches"]
+    assert [e["value"] for e in events
+            if e.get("kind") == "resolve" and e.get("name") == "mesh"] == ["4"]
+    assert c.get("recovery.dp_degrades", 0) == 0
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_one_device_run_declares_the_mesh_counters_zero(host4, which):
+    c = _final(_obs_events(host4["refs"][which]["dp1"]))["counters"]
+    assert [c[f"mesh.{k}"] for k in ("dispatches", "chunks", "rows", "padded_rows")] \
+        == [0, 0, 0, 0]
+    assert c["mesh.devices"] == 1 and c["feed.dispatches"] >= 1
+
+
+@pytest.mark.parametrize("which", [0, 1])
+def test_one_pack_span_a_group_and_one_upload_a_genome(host4, which):
+    from variantcalling_tpu import featurize
+
+    events = _obs_events(host4["refs"][which]["dp4"])
+    packs, groups = _spans(events, "megabatch_pack"), _spans(events, "score_stage")
+    assert len(packs) == len(groups) >= 2
+    assert sorted(p["chunks"] for p in packs) == sorted(g["chunks"] for g in groups)
+    assert sum(p["rows"] for p in packs) == _HOST4_N
+    assert {p["thread"] for p in packs}.isdisjoint({g["thread"] for g in groups})
+    (up,) = _spans(events, "genome_upload")
+    n_rows = -(-(_HOST4_GENOME + 40 * (host4["refs"][which]["n_contigs"] + 1))
+               // featurize.GENOME_ROW_BYTES) + 1
+    assert up["devices"] == 4 and up["bytes"] == n_rows * featurize.GENOME_ROW_BYTES
+    if which == 0:  # the same reference again: the genome is on the chips
+        assert not _spans(_obs_events(host4["refs"][0]["dp4_again"]), "genome_upload")
+
+
+@pytest.mark.parametrize("span", _FEED_SPANS)
+def test_feed_spans_hang_under_the_dispatch_workers_score_stage(host4, span):
+    events = _obs_events(host4["refs"][0]["dp4"])
+    got = _spans(events, span)
+    assert got and {e.get("parent") for e in got} == {"score_stage"}
+    assert {e["thread"] for e in got} == {"vctpu-mesh-dispatch-w0"}
+    rows = [e for e in events if e.get("kind") == "profile"
+            and e.get("name") == "stage" and e.get("stage") == span + ".w0"]
+    assert rows and all(r.get("parent") == "score_stage" for r in rows)

@@ -17,12 +17,14 @@ import numpy as np
 
 import jax
 
+from variantcalling_tpu import obs
 from variantcalling_tpu.io.bed import IntervalSet
 from variantcalling_tpu.io.fasta import FastaReader, encode_seq
 from variantcalling_tpu.io.vcf import VariantTable
 from variantcalling_tpu.ops import features as fops
 from variantcalling_tpu.ops import intervals as iops
 from variantcalling_tpu.utils.keyed_cache import KeyedCache
+from variantcalling_tpu.utils.trace import stage
 
 WINDOW_RADIUS = 20  # bases either side of the anchor in the gathered window
 CENTER = WINDOW_RADIUS
@@ -225,7 +227,14 @@ def _build_device_genome(fasta: FastaReader, radius: int,
         cur += len(seq) + len(gap)
     rows = _genome_rows(parts)
     del parts
-    arr = jax.device_put(rows, sharding) if sharding is not None else jax.device_put(rows)
+    # the part of set-up a mesh multiplies by its devices (a replicated
+    # sharding sends every chip the whole genome); under obs the span
+    # holds the copy to its end, not just the enqueue
+    n_dev = len(sharding.device_set) if sharding is not None else 1
+    with stage("genome_upload", bytes=int(rows.nbytes), devices=n_dev):
+        arr = jax.device_put(rows, sharding) if sharding is not None else jax.device_put(rows)
+        if obs.active():
+            arr.block_until_ready()
     return DeviceGenome(arr, offsets, lengths)
 
 

@@ -241,7 +241,12 @@ def megabatch_stream(prepped, ctx, profiler=None):
     scores are drained before group N+1's dispatch is submitted, and
     memory stays bounded at two groups (one in flight + one packing).
     ``VCTPU_MESH_OVERLAP=0`` restores the synchronous pack-then-score
-    loop. Recovery semantics are unchanged — the whole ladder runs
+    loop. The span ``megabatch_pack`` (this generator's thread) runs from
+    a group's first chunk to its hand-over: the pulls of its further
+    chunks from ``prepped`` and, in overlap mode, the drain of the group
+    before it with the hand-down of that group's results — what the
+    dispatch worker stands idle for, less the part of the drain it was
+    still scoring in. Recovery semantics are unchanged — the whole ladder runs
     inside the dispatched body, and its escalations
     (:class:`MeshDegradeRestart`) surface when the group is drained.
 
@@ -385,6 +390,15 @@ def megabatch_stream(prepped, ctx, profiler=None):
 
     group: list = []
     rows = 0
+    pack = None  # the open ``megabatch_pack`` span of the group being packed
+
+    def packed():
+        """Close the group's pack span: the group is handed over now."""
+        nonlocal pack
+        pack.set(rows=rows, chunks=len(group))
+        pack.__exit__(None, None, None)
+        pack = None
+
     try:
         for table, hf in prepped:
             if hf is None:
@@ -394,26 +408,35 @@ def megabatch_stream(prepped, ctx, profiler=None):
                 # through to the render/quarantine path
                 yield from drain()
                 if group:
+                    packed()
                     yield from flush(group)
                     group, rows = [], 0
                 yield (table, None, None)
                 continue
+            if not group:
+                pack = stage("megabatch_pack")
+                pack.__enter__()
             group.append((table, hf))
             rows += len(table)
             if rows >= state["target"]:
                 if pool is None:
+                    packed()
                     yield from flush(group)
                 else:
                     # overlap: drain group N's results, hand group N+1 to
                     # the dispatch worker, keep packing group N+2 from
                     # ``prepped`` while it scores
                     yield from drain()
+                    packed()
                     pending = pool.submit(flush, group)
                 group, rows = [], 0
         yield from drain()
         if group:
+            packed()
             yield from flush(group)
     finally:
+        if pack is not None:  # the stream ended inside a group: no span
+            pack.__exit__(GeneratorExit, None, None)
         if pool is not None:
             pool.shutdown()
 
@@ -424,5 +447,8 @@ def log_plan(plan: MeshPlan) -> None:
     if obs.active():
         obs.event("resolve", "mesh", value=str(plan.devices),
                   requested=plan.requested, reason=plan.reason)
+        # once a run: a run that fell to dp=1 (or restarted there: 4 + 1)
+        # cannot pass for a four-chip reading
+        obs.counter("mesh.devices").add(plan.devices)
     if plan.devices > 1:
         logger.info("scoring mesh: dp=%d (%s)", plan.devices, plan.reason)

@@ -673,7 +673,9 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     Counters: ``feed.dispatches``, ``feed.h2d_arrays`` (arrays handed to
     the device, the genome excluded), ``feed.native_fills`` /
     ``feed.numpy_fills`` (dispatches whose rows every chunk's native fill
-    wrote / the rest).
+    wrote / the rest); under a mesh plan also ``mesh.dispatches``,
+    ``mesh.chunks`` (chunks packed into them), ``mesh.rows`` (real rows
+    sent) and ``mesh.padded_rows`` (rows of the buckets sent).
     """
     from variantcalling_tpu.featurize import _bucket
     from variantcalling_tpu.parallel import shard_score
@@ -694,6 +696,8 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
 
     spans = shard_score.pack_lengths([i.n for i in inputs])
     n = spans[-1][1]
+    if mesh is not None:
+        obs.counter("mesh.chunks").add(len(inputs))
     out = np.empty(n, dtype=np.float32)
     pending: list[tuple[int, int, object]] = []
 
@@ -731,6 +735,10 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
         obs.counter("feed.dispatches").add(1)
         obs.counter("feed.h2d_arrays").add(len(sent))
         obs.counter("feed.native_fills" if native else "feed.numpy_fills").add(1)
+        if mesh is not None:
+            obs.counter("mesh.dispatches").add(1)
+            obs.counter("mesh.rows").add(hi - lo)
+            obs.counter("mesh.padded_rows").add(target)
         call_args = (genome.rows, *sent) if layout.resident else sent
         # the enqueue; on a first call also trace + lower + cache load or compile
         with stage("dispatch_enqueue", rows=target, waited=False):
@@ -968,6 +976,9 @@ class FilterContext:
         self.rank_plan = rank_plan if rank_plan is not None \
             else rank_plan_mod.resolve()
         rank_plan_mod.log_plan(self.rank_plan)
+        # set by note_chunk: a chunk of this run was large enough to send
+        # the genome to the device
+        self.genome_wanted = False
         self.model = model
         self.fasta = fasta
         self.hpol_length = hpol_length
@@ -1017,6 +1028,22 @@ class FilterContext:
         return self.forest_strategy \
             if self.forest_strategy in forest_mod.FOREST_STRATEGIES else None
 
+    def note_chunk(self, table: VariantTable) -> None:
+        """The mesh layout's feed calls this for every chunk, in canonical
+        order on its own thread, before the chunk goes to the pool. The
+        pool featurizes a file's chunks side by side and ahead of the first
+        dispatch, which is what uploads the genome: a short tail chunk
+        asked alone (:func:`featurize._genome_resident_worthwhile`) finds
+        no genome there yet in a process's first file of a reference and
+        every later time does, so the first file took another program for
+        its tail than the files after it, and the second file compiled.
+        Once a chunk of the run is large enough for the upload, the chunks
+        after it gather from the device too: they are dispatched after it."""
+        from variantcalling_tpu.featurize import GENOME_RESIDENT_MIN_VARIANTS
+
+        if len(table) >= GENOME_RESIDENT_MIN_VARIANTS:
+            self.genome_wanted = True
+
     @timed(name="host_featurize")
     def host_features(self, table: VariantTable):
         """Host featurization for one table/chunk — the CPU half of
@@ -1035,7 +1062,8 @@ class FilterContext:
         needs_host_windows = (
             self.blacklist_cg_insertions
             or not isinstance(model, _FUSED_MODEL_TYPES)
-            or not _genome_resident_worthwhile(table, fasta, sharding=genome_sharding)
+            or not (self.genome_wanted or _genome_resident_worthwhile(
+                table, fasta, sharding=genome_sharding))
         )
         # a chunk bound for the fused jit program whose table came through
         # the native parser needs no base column made here: the wire's
@@ -1511,6 +1539,8 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     obs.counter("predictor.waits").add(0)
     for name in ("dispatches", "h2d_arrays", "native_fills", "numpy_fills"):
         obs.counter(f"feed.{name}").add(0)
+    for name in ("dispatches", "chunks", "rows", "padded_rows"):
+        obs.counter(f"mesh.{name}").add(0)
     # continuous-profiler attribution (obs v3): this thread runs the
     # sequenced single-writer commit loop for the duration of the run
     sampler_mod.register_current("committer")
@@ -1896,10 +1926,15 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                                    records=len(table))
             return out
 
+        def noted(tables):
+            for table in tables:
+                ctx.note_chunk(table)
+                yield table
+
         if source_pooled:
             window = reader.io_threads + 2
             prepped = imap_ordered(reader.shared_pool(), prep_worker,
-                                   _traced_chunks(reader), window=window)
+                                   noted(_traced_chunks(reader)), window=window)
             scored = shard_score.megabatch_stream(prepped, ctx, profiler=prof)
             source = imap_ordered(reader.shared_pool(), render_stage,
                                   scored, window=window)
@@ -1927,7 +1962,7 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                     yield table
 
             source = shard_score.megabatch_stream(
-                map(prep_worker, _traced_chunks(timed_tables())), ctx,
+                map(prep_worker, noted(_traced_chunks(timed_tables()))), ctx,
                 profiler=prof)
             stages = [render_stage]
     elif source_pooled:
