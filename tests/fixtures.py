@@ -210,3 +210,26 @@ def strip_vctpu_header(data: bytes) -> bytes:
     byte-parity test."""
     return b"\n".join(ln for ln in data.split(b"\n")
                       if not ln.startswith(b"##vctpu_"))
+
+
+def fused_inputs_with_host_windows(n: int, program, names: list[str], seed: int = 0):
+    """One chunk of ``n`` random rows with host windows, ready for
+    ``filter_variants._dispatch_fused`` under ``program`` (the
+    ``(jitted, layout, finalize)`` of ``_fused_program(..., genome_resident=False)``)."""
+    from variantcalling_tpu.featurize import AlleleColumns, HostFeatures
+    from variantcalling_tpu.pipelines.filter_variants import _FusedInputs
+
+    rng = np.random.default_rng(seed)
+    windows = rng.integers(0, 4, size=(n, 41)).astype(np.uint8)
+    cols = {f: rng.integers(0, 2, n).astype(np.float32) if i % 2
+            else rng.uniform(0, 50, n).astype(np.float32)
+            for i, f in enumerate(program[1].host_names)}
+    is_snp = rng.random(n) < 0.7
+    alle = AlleleColumns(is_snp=is_snp, is_indel=~is_snp, is_ins=~is_snp,
+                         indel_length=(~is_snp).astype(np.int32),
+                         indel_nuc=np.where(is_snp, 4, rng.integers(0, 4, n)).astype(np.int32),
+                         ref_code=rng.integers(0, 4, n).astype(np.int32),
+                         alt_code=rng.integers(0, 4, n).astype(np.int32),
+                         n_alts=np.ones(n, np.int32))
+    hf = HostFeatures(alle=alle, windows=windows, cols=cols, names=list(names))
+    return _FusedInputs(n, program, None, 0, windows, None, hf)

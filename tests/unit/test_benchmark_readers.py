@@ -312,6 +312,32 @@ def test_the_host4_cells_metrics_read_what_the_program_writes(bench, name):
     assert read(parent, **how["args"]) is None
 
 
+@pytest.mark.parametrize("rows,padded,want", [
+    (2_000_000, 13 * 163_840 + 98_304, 89.757),  # a wgs file on the rungs
+    (2_000_000, 13 * 262_144 + 131_072, 56.514),  # the same file on powers of two
+    (50_000, 57_344, 87.193)])                    # an exome request
+def test_feed_fill_share_reads_the_feeds_two_counters(bench, rows, padded, want):
+    """PR 35's one metric: every cell lists it, the accepted reader reads it,
+    and a program without the counters (the parent) gives nothing to read."""
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    (m,) = [m for m in bm["per_layer"] if m["name"] == "feed_fill_share"]
+    assert bm["per_layer"][-1] is m
+    assert m["workloads"] == [w["name"] for w in bm["workloads"]]
+    assert (m["moves"], m["layer"], m["source"], m["unit"], m["better"]) == (
+        "variants_per_s", "streaming executor score stage", "program_counter", "%", "higher")
+    with open(os.path.join(BENCH, "layer_metrics", "feed_fill_share.json"),
+              encoding="utf-8") as fh:
+        how = json.load(fh)
+    assert how == {"reader": "counter_ratio",
+                   "args": {"part": "feed.rows", "whole": "feed.padded_rows"}}
+    read = bench.load("readers", how["reader"]).read
+    ctx = context(obs_events=[final(**{"feed.rows": rows, "feed.padded_rows": padded})])
+    assert read(ctx, **how["args"]) == pytest.approx(want, abs=1e-3)
+    parent = context(obs_events=[final(**{"feed.dispatches": 14, "mesh.rows": 0})])
+    assert read(parent, **how["args"]) is None
+
+
 def test_the_host4_configuration_is_the_one_chip_one_on_another_cluster():
     """Key for key the one-chip file, except what states the deployment; one
     four-chip cell on the existing traffic mix."""

@@ -192,8 +192,9 @@ def test_predictor_selects_columns_by_name():
 
 def test_predictor_bit_identical_across_pad_buckets():
     """f32 end-to-end determinism through the dispatch ladder: a chunk
-    zero-padded to ANY power-of-two bucket (what ``_dispatch_fused``
-    does to every batch) scores its real rows bit-identically — the
+    zero-padded to ANY bucket (``_dispatch_fused`` pads every batch to a
+    rung of ``featurize._bucket``: a power of two up to 32,768 rows, four
+    rungs an octave above) scores its real rows bit-identically — the
     bucket choice and the padding rows never perturb a score, under
     both the eager and the jitted program."""
     import jax
@@ -212,13 +213,15 @@ def test_predictor_bit_identical_across_pad_buckets():
     # zero-padding extra rows must not perturb the real rows' bits
     padded = np.asarray(program(np.pad(x, ((0, 24), (0, 0)))))[:1000]
     assert np.array_equal(padded, full)
-    # a 37-row chunk in its 64-bucket == the same chunk in a 128-bucket,
-    # eager and jitted (the ladder may pick either depending on history)
+    # a 37-row chunk in a 64-row bucket == the same chunk in a 128-row or
+    # an 80-row one (5 * 2**4: a rung that is no power of two), eager and
+    # jitted (the ladder may pick either depending on history)
     chunk = x[:37]
     for fn in (program, jax.jit(program)):
         b64 = np.asarray(fn(np.pad(chunk, ((0, 27), (0, 0)))))[:37]
         b128 = np.asarray(fn(np.pad(chunk, ((0, 91), (0, 0)))))[:37]
-        assert np.array_equal(b64, b128)
+        b80 = np.asarray(fn(np.pad(chunk, ((0, 43), (0, 0)))))[:37]
+        assert np.array_equal(b64, b128) and np.array_equal(b64, b80)
         assert len(np.unique(b64)) > 5  # varying, not trivially equal
 
 

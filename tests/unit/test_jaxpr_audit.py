@@ -147,10 +147,11 @@ def test_seeded_f64_margin_output_caught():
 
 
 def test_seeded_layout_budget_overrun_caught():
-    # a bucketing regression: linear 1000-row steps instead of the
-    # power-of-two ladder explodes the distinct-layout census
+    # a bucketing regression: linear 1000-row steps instead of the ladder
+    # (powers of two up to 32,768 rows, four rungs an octave above: 18
+    # layouts) explodes the distinct-layout census
     bad_bucket = lambda n: -(-n // 1000) * 1000
-    vs = ja.check_layout_budget(CONTRACT, bucket=bad_bucket, chunk=1 << 14)
+    vs = ja.check_layout_budget(CONTRACT, bucket=bad_bucket, chunk=1 << 15)
     assert rules(vs) == ["layout-budget"]
     # the production ladder fits the committed budget exactly
     assert ja.check_layout_budget(CONTRACT) == []

@@ -454,8 +454,8 @@ def test_dispatch_gates_each_bucket_on_size_and_window_shape(
     """``_dispatch_fused`` hands ``_enqueue`` the bucket size and what else
     makes jax trace anew, under a ``dispatch_enqueue`` span. The wire's
     layout is static, so no dtype of a chunk's columns is part of it."""
-    from variantcalling_tpu.featurize import (AlleleColumns, HostFeatures,
-                                              _bucket as featurize_bucket)
+    from tests.fixtures import fused_inputs_with_host_windows
+    from variantcalling_tpu.featurize import _bucket as featurize_bucket
     from variantcalling_tpu.parallel import shard_score
 
     seen = []
@@ -467,23 +467,10 @@ def test_dispatch_gates_each_bucket_on_size_and_window_shape(
 
     monkeypatch.setattr(fv, "_enqueue", spy)
     n = 300
-    rng = np.random.default_rng(0)
-    windows = rng.integers(0, 4, size=(n, 41)).astype(np.uint8)
     model = _forest()
     program = fv._fused_program(model, NAMES, "TGCA")
     layout = program[1]
-    cols = {f: rng.integers(0, 2, n).astype(np.float32) if i % 2
-            else rng.uniform(0, 50, n).astype(np.float32)
-            for i, f in enumerate(layout.host_names)}
-    alle = AlleleColumns(is_snp=np.ones(n, bool), is_indel=np.zeros(n, bool),
-                         is_ins=np.zeros(n, bool),
-                         indel_length=np.zeros(n, np.int32),
-                         indel_nuc=np.full(n, 4, np.int32),
-                         ref_code=np.zeros(n, np.int32),
-                         alt_code=np.ones(n, np.int32),
-                         n_alts=np.ones(n, np.int32))
-    hf = HostFeatures(alle=alle, windows=windows, cols=cols, names=list(NAMES))
-    fi = fv._FusedInputs(n, program, None, 0, windows, None, hf)
+    fi = fused_inputs_with_host_windows(n, program, NAMES)
     plan = shard_score.resolve_plan("jit")
     if plan.devices != 1:
         pytest.skip("single-device dispatch only")

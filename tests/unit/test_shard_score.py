@@ -519,6 +519,7 @@ def test_mesh_counters_add_up_on_four_devices(host4, which):
     assert c["mesh.chunks"] == chunks
     assert c["mesh.padded_rows"] % 4 == 0 and c["mesh.padded_rows"] >= c["mesh.rows"]
     assert c["mesh.dispatches"] == c["feed.dispatches"] < chunks
+    assert (c["feed.rows"], c["feed.padded_rows"]) == (c["mesh.rows"], c["mesh.padded_rows"])
     assert c["feed.native_fills"] == c["feed.dispatches"]
     # the tail chunk (under the resident threshold) gathered from the
     # device like the chunks before it, in a reference's first file too:
@@ -535,6 +536,7 @@ def test_one_device_run_declares_the_mesh_counters_zero(host4, which):
     assert [c[f"mesh.{k}"] for k in ("dispatches", "chunks", "rows", "padded_rows")] \
         == [0, 0, 0, 0]
     assert c["mesh.devices"] == 1 and c["feed.dispatches"] >= 1
+    assert c["feed.padded_rows"] >= c["feed.rows"] == c["records"] == _HOST4_N
 
 
 @pytest.mark.parametrize("which", [0, 1])

@@ -142,17 +142,21 @@ def test_window_gather_compiles_for_v5e_at_hg38_size(v5e):
         assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
 
 
+@pytest.mark.parametrize("bucket", [262_144, 163_840, 98_304])
 @pytest.mark.parametrize("family", ["dan", "forest"])
-def test_fused_program_over_the_wire_compiles_for_v5e_at_the_cells_size(v5e, family):
+def test_fused_program_over_the_wire_compiles_for_v5e_at_the_cells_size(v5e, family, bucket):
     """The whole fused program as a dispatch calls it since the wire — the
-    resident genome's rows and ONE ``uint32[262144, 10]`` buffer — for one
+    resident genome's rows and ONE ``uint32[bucket, 10]`` buffer, at the
+    ladder's cap and at the two rungs a wgs cell's chunks ride (5 * 2**15
+    and, the file's tail, 6 * 2**14: no powers of two) — for one
     chip and as a pure map over dp=4: the unpack (column slices, shifts,
     bitcasts) is accepted by the chip's compiler, adds no collective, and
     the buffer's narrow minor dimension does not blow its device footprint
     up (10 words a row are stored as 16, not 128)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from variantcalling_tpu.featurize import BASE_FEATURES, GENOME_ROW_WORDS
+    from variantcalling_tpu.featurize import (BASE_FEATURES, GENOME_ROW_WORDS,
+                                              _bucket)
     from variantcalling_tpu.pipelines import filter_variants as fv
     from variantcalling_tpu.synthetic import synthetic_dan, synthetic_forest
 
@@ -161,7 +165,8 @@ def test_fused_program_over_the_wire_compiles_for_v5e_at_the_cells_size(v5e, fam
     rng = np.random.default_rng(0)
     model, strategy = (synthetic_dan(rng, names), None) if family == "dan" \
         else (synthetic_forest(rng, n_trees=40, depth=6), "wide")
-    n_rows, bucket = 6_055_937, 262_144
+    n_rows = 6_055_937
+    assert _bucket(bucket) == bucket
     for use_mesh, genome_sharding, wire_sharding in (
             (None, single, single), (mesh, NamedSharding(mesh, P()), dp_sharded)):
         fn, layout, _fin = fv._build_fused_program(

@@ -355,6 +355,13 @@ def test_pipeline_output_equals_the_parents_pinned_digest(
     assert counters["feed.native_fills"] == counters["feed.dispatches"]
     assert counters["feed.numpy_fills"] == 0
     assert counters["feed.h2d_arrays"] == (1 if resident else 2) * counters["feed.dispatches"]
+    # real rows sent, and the rows of the buckets they rode: a bucket a chunk
+    from variantcalling_tpu.io.vcf import VcfChunkReader
+
+    chunks = [len(t) for t in VcfChunkReader(d + "/calls.vcf", chunk_bytes=chunk_bytes,
+                                             io_threads=1)]
+    assert counters["feed.rows"] == counters["records"] == sum(chunks) == 6000
+    assert counters["feed.padded_rows"] == sum(featurize._bucket(n) for n in chunks)
     assert os.path.getsize(out) > 0
 
 

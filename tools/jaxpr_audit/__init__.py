@@ -36,7 +36,8 @@ buried in tool code):
   must be float32 (the accumulator dtype both engines agree on).
 - **Program-layout census:** the distinct ``(dp, padded-batch)`` shapes
   the streaming dispatch can compile (mirroring ``_dispatch_fused``'s
-  power-of-two bucket-and-pad rule) gate against a committed budget —
+  bucket-and-pad rule over ``featurize._bucket``'s ladder) gate against a
+  committed budget —
   a change that breaks bucketing recompiles per chunk shape and fails
   here loudly, like a lint finding, instead of as a silent perf cliff.
 
@@ -420,8 +421,8 @@ def check_layout_budget(contract: dict, bucket=None,
                 "rule": "layout-budget",
                 "detail": f"{len(layouts)} distinct (dp, batch) program "
                           f"layouts at dp={dp} exceeds the committed "
-                          f"budget of {budget} — the power-of-two bucket "
-                          "ladder regressed; every extra layout is a "
+                          f"budget of {budget} — the bucket ladder "
+                          "(featurize._bucket) regressed; every extra layout is a "
                           "recompile in the scoring hot loop "
                           "(tools/jaxpr_audit/contract.json "
                           "layout_budget to extend, with justification)",

@@ -551,14 +551,26 @@ def _device_feature_program(windows, is_indel, indel_nuc, ref_code, alt_code, is
 
 
 _PAD_MIN = 1 << 10
+#: up to here a bucket is a power of two: a whole one costs the device about
+#: 2 ms, and its padding is not worth a compiled program
+_QUARTER_RUNGS_ABOVE = 1 << 15
 
 
 def _bucket(n: int) -> int:
-    """Next power-of-two batch size: bounds distinct compiled shapes to log2(N)."""
+    """Padded batch size for ``n`` rows: bounds the distinct compiled shapes.
+
+    The next power of two from ``_PAD_MIN`` up to 32,768 rows; above that
+    the next of four rungs an octave (``m * 2**k``, ``m`` in 4..7, each a
+    multiple of 8,192), so padding is under a fifth of a dispatch at any
+    row count. A function of ``n`` alone.
+    """
     b = _PAD_MIN
     while b < n:
         b <<= 1
-    return b
+    if b <= _QUARTER_RUNGS_ABOVE:
+        return b
+    step = b >> 3  # the octave (b/2, b] in four steps
+    return -(-n // step) * step
 
 
 # feature columns produced ON DEVICE by the window kernels; everything else

@@ -658,8 +658,9 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     split per chunk with ``shard_score.unpack_scores``).
 
     Every input must share the same compiled program (the caller groups
-    by ``program`` identity). The megabatch is cut into power-of-two
-    buckets rounded up to a dp multiple — ``shard_map`` requires
+    by ``program`` identity). The megabatch is cut into buckets of the
+    ladder (``featurize._bucket``: padding under a fifth of a dispatch
+    above 32,768 rows) rounded up to a dp multiple — ``shard_map`` requires
     dp-divisible shapes and distinct batch sizes must reuse compiled
     programs instead of retracing — and padding rows are dropped on
     unpack. Scoring is row-local, so the packed scores are bit-identical
@@ -670,12 +671,13 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     chunks' rows are written in one pass each into a bucket-sized staging
     buffer of the program's wire layout (:mod:`variantcalling_tpu.wire`),
     which goes back to the pool with the array whose readiness frees it.
-    Counters: ``feed.dispatches``, ``feed.h2d_arrays`` (arrays handed to
-    the device, the genome excluded), ``feed.native_fills`` /
-    ``feed.numpy_fills`` (dispatches whose rows every chunk's native fill
-    wrote / the rest); under a mesh plan also ``mesh.dispatches``,
-    ``mesh.chunks`` (chunks packed into them), ``mesh.rows`` (real rows
-    sent) and ``mesh.padded_rows`` (rows of the buckets sent).
+    Counters: ``feed.dispatches``, ``feed.rows`` (real rows sent),
+    ``feed.padded_rows`` (rows of the buckets sent), ``feed.h2d_arrays``
+    (arrays handed to the device, the genome excluded),
+    ``feed.native_fills`` / ``feed.numpy_fills`` (dispatches whose rows
+    every chunk's native fill wrote / the rest); under a mesh plan also
+    ``mesh.dispatches``, ``mesh.chunks`` (chunks packed into them),
+    ``mesh.rows`` and ``mesh.padded_rows`` (as the feed's two).
     """
     from variantcalling_tpu.featurize import _bucket
     from variantcalling_tpu.parallel import shard_score
@@ -715,8 +717,8 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
 
     for lo in range(0, n, chunk_size):
         hi = min(lo + chunk_size, n)
-        # power-of-two bucket (rounded up to a dp multiple) so distinct batch
-        # sizes reuse the same compiled program instead of retracing
+        # a rung of the bucket ladder (rounded up to a dp multiple) so distinct
+        # batch sizes reuse the same compiled program instead of retracing
         target = min(chunk_size, -(-_bucket(hi - lo) // n_dev) * n_dev)
 
         # async dispatch overlaps chunk i+1's upload with chunk i's compute;
@@ -733,6 +735,8 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
             buf.pad_from(hi - lo, first.gpos_fill)
             sent = tuple(jax.device_put(a, sharding) for a in buf.arrays())
         obs.counter("feed.dispatches").add(1)
+        obs.counter("feed.rows").add(hi - lo)
+        obs.counter("feed.padded_rows").add(target)
         obs.counter("feed.h2d_arrays").add(len(sent))
         obs.counter("feed.native_fills" if native else "feed.numpy_fills").add(1)
         if mesh is not None:
@@ -1537,7 +1541,8 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     obs.counter("predictor.builds").add(0)
     obs.counter("predictor.reuses").add(0)
     obs.counter("predictor.waits").add(0)
-    for name in ("dispatches", "h2d_arrays", "native_fills", "numpy_fills"):
+    for name in ("dispatches", "rows", "padded_rows", "h2d_arrays",
+                 "native_fills", "numpy_fills"):
         obs.counter(f"feed.{name}").add(0)
     for name in ("dispatches", "chunks", "rows", "padded_rows"):
         obs.counter(f"mesh.{name}").add(0)

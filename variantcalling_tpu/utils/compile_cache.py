@@ -3,7 +3,7 @@
 The flagship pipelines are CLI tools invoked once per file (reference
 docs/howto-callset-filter.md's per-callset invocations), so without a
 persistent cache each process re-pays the full jit compile of the fused
-featurize+score program — one program per power-of-two batch bucket —
+featurize+score program — one program per batch bucket it meets —
 before touching a single variant. JAX's compilation cache persists
 compiled executables on disk keyed by (HLO, jaxlib, flags, device kind);
 warm invocations deserialize instead of compiling.
