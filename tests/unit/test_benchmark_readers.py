@@ -317,13 +317,14 @@ def test_the_host4_cells_metrics_read_what_the_program_writes(bench, name):
     (2_000_000, 13 * 262_144 + 131_072, 56.514),  # the same file on powers of two
     (50_000, 57_344, 87.193)])                    # an exome request
 def test_feed_fill_share_reads_the_feeds_two_counters(bench, rows, padded, want):
-    """PR 35's one metric: every cell lists it, the accepted reader reads it,
+    """PR 35's one metric: every cell the benchmark had then lists it (a later
+    PR's entries come after it and its cells), the accepted reader reads it,
     and a program without the counters (the parent) gives nothing to read."""
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
         bm = json.load(fh)
     (m,) = [m for m in bm["per_layer"] if m["name"] == "feed_fill_share"]
-    assert bm["per_layer"][-1] is m
-    assert m["workloads"] == [w["name"] for w in bm["workloads"]]
+    assert bm["per_layer"][34] is m
+    assert m["workloads"] == [w["name"] for w in bm["workloads"]][:4]
     assert (m["moves"], m["layer"], m["source"], m["unit"], m["better"]) == (
         "variants_per_s", "streaming executor score stage", "program_counter", "%", "higher")
     with open(os.path.join(BENCH, "layer_metrics", "feed_fill_share.json"),
@@ -358,3 +359,76 @@ def test_the_host4_configuration_is_the_one_chip_one_on_another_cluster():
     (entry,) = [c for c in bm["configs"] if c["name"] == four["name"]]
     assert entry["source"] == four["source"] and entry["reduced"] == four["reduced"]
     assert [w["name"] for w in bm["workloads"] if w["chips"] == 4] == [HOST4_CELL]
+
+
+# -- the .vcf.gz cell's metrics (ISSUE 36) --------------------------------------
+
+VCFGZ_CELL = "forest-t40d6-hg38x2-vcfgz.wgs-batch-bgzf"
+VCFGZ_METRICS = {
+    "inflate_work_share": ("ingest and parse", "stage_share", "inflate.w3", 25.0),
+    "compress_work_share": ("render and commit", "stage_share", "compress_stage", 25.0),
+    "index_work_share": ("render and commit", "stage_share", "tabix_index", 25.0),
+    "bgzf_out_ratio": (
+        "render and commit", "counter_ratio",
+        final(**{"bgzf.out_bytes": 32_500_000, "bgzf.text_bytes_out": 140_000_000}),
+        100 * 32_500_000 / 140_000_000),
+    "bgzf_in_blocks_per_file": ("ingest and parse", "obs_counter",
+                                final(**{"bgzf.in_blocks": 1684}), 1684.0),
+    "tabix_index_skipped_per_file": ("render and commit", "obs_counter",
+                                     final(**{"tabix.index_skipped": 0}), 0.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(VCFGZ_METRICS))
+def test_the_vcfgz_cells_metrics_read_what_the_program_writes(bench, name):
+    """Each of the six lists only the new cell, comes after everything the
+    benchmark had, names a reader that was there, reads a hand-made context,
+    and reads nothing (and does not raise) from a plain-text run of a program
+    that lacks the span or the counter, as the parent does."""
+    layer, reader, given, want = VCFGZ_METRICS[name]
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    (m,) = [m for m in bm["per_layer"][35:] if m["name"] == name]
+    assert (m["workloads"], m["moves"], m["layer"]) == ([VCFGZ_CELL], "variants_per_s", layer)
+    (cell,) = [w for w in bm["workloads"][4:] if w["name"] == VCFGZ_CELL]
+    assert (cell["chips"], cell["traffic"]) == (1, "wgs-batch-bgzf")
+    with open(os.path.join(BENCH, "layer_metrics", name + ".json"), encoding="utf-8") as fh:
+        how = json.load(fh)
+    assert how["reader"] == reader
+    read = bench.load("readers", reader).read
+    events = [given] if isinstance(given, dict) else [
+        {"kind": "profile", "name": "stage", "stage": given, "work_s": 0.125},
+        {"kind": "profile", "name": "pipeline", "wall_s": 0.5}]
+    assert read(context(obs_events=events), **how["args"]) == pytest.approx(want)
+    parent = context(obs_events=[final(**{"feed.dispatches": 14}),
+                                 {"kind": "profile", "name": "stage",
+                                  "stage": "render_stage.w0", "work_s": 0.1},
+                                 {"kind": "profile", "name": "pipeline", "wall_s": 0.5}])
+    assert read(parent, **how["args"]) is None
+
+
+def test_the_vcfgz_configuration_is_the_forest_one_in_another_container():
+    """Key for key the plain-text forest file, except what states the
+    deployment; its traffic file differs from the plain-text one in the
+    driver alone; the container block is htslib's."""
+    def load(*parts):
+        with open(os.path.join(BENCH, *parts), encoding="utf-8") as fh:
+            return json.load(fh)
+
+    plain = load("configs", "forest-t40d6-hg38x2.json")
+    gz = load("configs", "forest-t40d6-hg38x2-vcfgz.json")
+    assert set(gz) - set(plain) == {"container", "guarantees"} and set(plain) <= set(gz)
+    assert {k for k in plain if plain[k] != gz[k]} == {"name", "source", "deployment", "assumed"}
+    assert gz["assumed"][1:1 + len(plain["assumed"])] == plain["assumed"]
+    box = gz["container"]
+    assert (box["input"], box["output"], box["block_payload"], box["level"],
+            box["index_regions_checked"]) == ("bgzf", "bgzf+tbi", 65280, 6, 64)
+    # between the program's reading (1.0000) and level 1's (1.28), room on both sides
+    assert 0.02 <= box["size_tolerance"] <= 0.15 and box["size_tolerance_why"]
+    a, b = load("traffic", "wgs-batch.json"), load("traffic", "wgs-batch-bgzf.json")
+    assert {k for k in a if a[k] != b[k]} == {"about", "driver"} and set(a) == set(b)
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    assert bm["configs"][-1]["name"] == gz["name"] == bm["workloads"][-1]["config"]
+    assert bm["configs"][-1]["source"] == gz["source"]
+    assert bm["configs"][-1]["reduced"] == gz["reduced"] == ["variants_per_file"]

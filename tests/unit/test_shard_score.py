@@ -299,6 +299,47 @@ def test_a_runs_tail_chunk_takes_the_layout_of_the_chunks_before_it(
     assert featurize.device_genome_stats()["entries"] == 0  # nothing uploaded yet
 
 
+@pytest.mark.parametrize("suffix", ["", ".gz"])
+def test_the_raw_feed_notes_its_chunks_as_the_mesh_feed_does(
+        mesh_world, monkeypatch, tmp_path, suffix):
+    """The pooled layout's feed holds raw text, so the same rule is asked of
+    each chunk's lines, in canonical order: a tail under the threshold
+    follows the chunks before it, whatever the pool's timing (a ``.vcf.gz``
+    input's chunks all start side by side: ISSUE 36). Nothing is counted
+    once the answer is yes, nor while the genome is on the device."""
+    from variantcalling_tpu import featurize
+    from variantcalling_tpu.io import bgzf as bgzf_mod
+    from variantcalling_tpu.io.vcf import VcfChunkReader
+
+    w = mesh_world
+    path = f"{w['dir']}/calls.vcf"
+    if suffix:
+        path = str(tmp_path / "calls.vcf.gz")
+        with open(f"{w['dir']}/calls.vcf", "rb") as fh, bgzf_mod.BgzfWriter(path) as out:
+            out.write(fh.read())
+    raws = [buf for buf, _ in VcfChunkReader(path, chunk_bytes=1 << 15,
+                                             io_threads=1).iter_raw()]
+    lines = [int(np.count_nonzero(b == 0x0A)) for b in raws]
+    assert len(raws) > 2 and lines[-1] < min(lines[:-1])
+    monkeypatch.setattr(featurize, "GENOME_RESIDENT_MIN_VARIANTS", min(lines[:-1]))
+    featurize._DEVICE_GENOME_CACHE.clear()
+    ctx = _filter_context(w, monkeypatch, devices=1)
+    ctx.note_raw_chunk(raws[-1])
+    assert not ctx.genome_wanted  # alone, the tail is too short for the upload
+    ctx.note_raw_chunk(raws[0])
+    assert ctx.genome_wanted
+
+    def never(*a, **k):
+        raise AssertionError("counted a chunk's lines with the answer known")
+
+    monkeypatch.setattr(np, "count_nonzero", never)
+    ctx.note_raw_chunk(raws[-1])  # the answer is yes already
+    ctx.genome_wanted = False
+    monkeypatch.setattr(featurize, "_genome_resident_worthwhile", lambda *a, **k: True)
+    ctx.note_raw_chunk(raws[0])  # the genome is on the device: nothing to decide
+    assert not ctx.genome_wanted
+
+
 # ---------------------------------------------------------------------------
 # acceptance: byte parity at forced device counts x engine x strategy
 # ---------------------------------------------------------------------------

@@ -21,6 +21,8 @@ from __future__ import annotations
 import struct
 import zlib
 
+from variantcalling_tpu import obs
+
 MAX_BLOCK_DATA = 65280  # uncompressed payload per block (htslib convention)
 BGZF_EOF = bytes.fromhex("1f8b08040000000000ff0600424302001b0003000000000000000000")
 
@@ -108,13 +110,20 @@ def group_spans(spans, shard_bytes: int) -> list[list[tuple[int, int, int]]]:
 def inflate_spans(buf, spans) -> bytes:
     """Inflate a run of BGZF members of ``buf`` (one ingest shard's work;
     each member is an independent raw-deflate stream). zlib releases the
-    GIL, so shards genuinely overlap on the IO worker pool."""
+    GIL, so shards genuinely overlap on the IO worker pool. Counts what
+    it read and made (``bgzf.in_blocks`` / ``in_bytes`` /
+    ``text_bytes_in``; live only under obs)."""
     mv = memoryview(buf)
     out = []
     for off, bsize, _isize in spans:
         (xlen,) = struct.unpack("<H", mv[off + 10:off + 12])
         out.append(zlib.decompress(mv[off + 12 + xlen:off + bsize - 8], wbits=-15))
-    return b"".join(out)
+    text = b"".join(out)
+    if obs.active():
+        obs.counter("bgzf.in_blocks").add(len(spans))
+        obs.counter("bgzf.in_bytes").add(sum(s[1] for s in spans))
+        obs.counter("bgzf.text_bytes_in").add(len(text))
+    return text
 
 
 def _compress_full_blocks(chunk, level: int, pool=None) -> bytes:
@@ -188,6 +197,15 @@ class BgzfWriter:
         self.close()
 
 
+def _count_out(text: int, compressed: int, blocks: int) -> None:
+    """The compress stage's counters (live only under obs): text taken
+    in, container bytes and members handed to the committer."""
+    if obs.active():
+        obs.counter("bgzf.text_bytes_out").add(text)
+        obs.counter("bgzf.out_bytes").add(compressed)
+        obs.counter("bgzf.out_blocks").add(blocks)
+
+
 class BgzfChunkCompressor:
     """Deterministic BGZF framing for the streaming writeback's compress
     stage (docs/streaming_executor.md "Parallel host IO").
@@ -220,6 +238,13 @@ class BgzfChunkCompressor:
         faults.check("io.shard_compress")
         view = memoryview(body) if not isinstance(body, memoryview) else body
         self.bytes_in += len(view)
+        before = len(self._carry)
+        out = self._add(view)
+        _count_out(len(view), len(out),
+                   (before + len(view) - len(self._carry)) // MAX_BLOCK_DATA)
+        return out
+
+    def _add(self, view) -> bytes:
         if not self._carry:
             n_full = (len(view) // MAX_BLOCK_DATA) * MAX_BLOCK_DATA
             out = _compress_full_blocks(view[:n_full], self._level,
@@ -250,6 +275,9 @@ class BgzfChunkCompressor:
         if self._carry:
             out = compress_block(bytes(self._carry), self._level)
             self._carry.clear()
+        # the EOF member is a block of the file too: ``bgzf.out_bytes``
+        # ends up the committed file's size
+        _count_out(0, len(out) + len(BGZF_EOF), (1 if out else 0) + 1)
         return out + BGZF_EOF
 
 

@@ -18,6 +18,7 @@ import zlib
 
 import numpy as np
 
+from variantcalling_tpu import obs
 from variantcalling_tpu.io.bgzf import BgzfWriter, compress_block
 
 TBI_MAGIC = b"TBI\x01"
@@ -70,8 +71,10 @@ class _RefIndex:
     def __init__(self):
         self.bins: dict[int, list[tuple[int, int]]] = {}
         self.linear: dict[int, int] = {}
+        self.records = 0
 
     def add(self, beg: int, end: int, v_start: int, v_end: int) -> None:
+        self.records += 1
         b = reg2bin(beg, end)
         chunks = self.bins.setdefault(b, [])
         # merge adjacent chunks (htslib does the same compaction)
@@ -95,6 +98,7 @@ def build_tabix_index(
     """Build ``<path>.tbi`` for a BGZF VCF/BED; returns the index path.
 
     Record spans: VCF preset uses POS .. POS+len(REF); BED uses cols 2/3.
+    Counter ``tabix.records`` (live only under obs): the records indexed.
     """
     names: list[str] = []
     refs: dict[str, _RefIndex] = {}
@@ -136,6 +140,7 @@ def build_tabix_index(
             segments = kept
     out = path + ".tbi"
     _write_tbi(out, names, refs, preset, col_seq, col_beg, col_end, meta_char)
+    obs.counter("tabix.records").add(sum(r.records for r in refs.values()))
     return out
 
 

@@ -1048,6 +1048,29 @@ class FilterContext:
         if len(table) >= GENOME_RESIDENT_MIN_VARIANTS:
             self.genome_wanted = True
 
+    def note_raw_chunk(self, buf) -> None:
+        """:meth:`note_chunk` for the layouts whose feed holds raw text
+        (the pooled one and the cached serial one): the same rule in the
+        same canonical order, asked of the chunk's lines, since nothing is
+        parsed yet. Without it the layout of a short tail chunk is a race
+        in a process's first file of a reference: a ``.vcf.gz`` input's
+        first chunk is two reads long, so its thirteen chunks start side by
+        side on thirteen workers, the tail finds no genome on the device
+        and takes the host-window program, and the next file of that
+        reference compiles the resident one at the tail's rung inside a
+        measured window (PERF.md section 6, PR 36). Lines are counted only
+        until the answer is yes and only while the genome is not on the
+        device: a file of a resident process counts nothing."""
+        from variantcalling_tpu.featurize import (GENOME_RESIDENT_MIN_VARIANTS,
+                                                  _genome_resident_worthwhile,
+                                                  standard_genome_sharding)
+
+        if self.genome_wanted or _genome_resident_worthwhile(
+                (), self.fasta, sharding=standard_genome_sharding(self.mesh)):
+            return
+        if int(np.count_nonzero(buf == 0x0A)) >= GENOME_RESIDENT_MIN_VARIANTS:
+            self.genome_wanted = True
+
     @timed(name="host_featurize")
     def host_features(self, table: VariantTable):
         """Host featurization for one table/chunk — the CPU half of
@@ -1546,6 +1569,11 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
         obs.counter(f"feed.{name}").add(0)
     for name in ("dispatches", "chunks", "rows", "padded_rows"):
         obs.counter(f"mesh.{name}").add(0)
+    for name in ("in_bytes", "in_blocks", "inflate_shards", "text_bytes_in",
+                 "text_bytes_out", "out_bytes", "out_blocks"):
+        obs.counter(f"bgzf.{name}").add(0)
+    obs.counter("tabix.records").add(0)
+    obs.counter("tabix.index_skipped").add(0)
     # continuous-profiler attribution (obs v3): this thread runs the
     # sequenced single-writer commit loop for the duration of the run
     sampler_mod.register_current("committer")
@@ -1691,6 +1719,7 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
         chunk-cache staging key, matched against the committer's chunk
         counter at publish time (both count post-skip delivery order)."""
         for seq, (buf_np, lazy_buf) in enumerate(raws):
+            ctx.note_raw_chunk(buf_np)
             yield seq, buf_np, lazy_buf, obs.new_trace()
 
     def render_stage(item):
@@ -1746,8 +1775,9 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                 return b"", k, p, q, tid
             data = memoryview(body) if isinstance(body, np.ndarray) else body
             with stage("compress_stage", trace=tid, causal=True,
-                       bytes_in=len(data)):
+                       bytes_in=len(data)) as sp:
                 out = compressor.add(data)
+                sp.set(bytes_out=len(out))
             return out, k, p, q, tid
 
         compress_stage.self_timed = True
@@ -2196,6 +2226,19 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
         logger.warning("quarantine: %d chunk(s), %d record(s) diverted to %s "
                        "— the main output is INCOMPLETE by that many records",
                        n_quar_chunks, n_quar_records, q_path)
+    if gz:
+        # the index is a second pass over the committed file, and part of
+        # what the user waits for: inside the run's wall, under a span
+        from variantcalling_tpu.io.tabix import build_tabix_index
+
+        try:
+            with stage("tabix_index", records=n_total,
+                       bytes=os.path.getsize(out_path)):
+                build_tabix_index(out_path)
+        except (ValueError, OSError) as e:
+            # unsorted/odd inputs: the VCF itself is still valid
+            logger.warning("no tabix index beside %s: %s", out_path, e)
+            obs.counter("tabix.index_skipped").add(1)
     if prof is not None:
         # ingest byte attribution: the reader consumes chunk_bytes of
         # (decompressed) text per chunk; cap at the file size only when
@@ -2205,13 +2248,6 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
             min(approx, input_bytes) if bytes_comparable else approx
         prof.emit(wall_s=_time.perf_counter() - t_start,  # vctpu-lint: disable=VCT006 — obs profile wall clock
                   records=n_total - resumed_records)
-    if gz:
-        from variantcalling_tpu.io.tabix import build_tabix_index
-
-        try:
-            build_tabix_index(out_path)
-        except (ValueError, OSError):
-            pass  # unsorted/odd inputs: the VCF itself is still valid
     return {"n": n_total, "n_pass": n_pass, "chunks": n_chunks,
             "engine": ctx.engine.name,
             "resumed_chunks": resume.chunks if resume is not None else 0,
