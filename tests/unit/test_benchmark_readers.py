@@ -376,6 +376,9 @@ VCFGZ_METRICS = {
                                 final(**{"bgzf.in_blocks": 1684}), 1684.0),
     "tabix_index_skipped_per_file": ("render and commit", "obs_counter",
                                      final(**{"tabix.index_skipped": 0}), 0.0),
+    # ISSUE 37: the index gathered inside the pipeline, one a file
+    "tabix_index_streamed_per_file": ("render and commit", "obs_counter",
+                                      final(**{"tabix.index_streamed": 1}), 1.0),
 }
 
 

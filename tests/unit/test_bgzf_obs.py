@@ -1,7 +1,10 @@
 """What a ``.vcf.gz`` run tells obs (ISSUE 36): the ``inflate``,
 ``compress_stage`` and ``tabix_index`` spans, the ``bgzf.*`` and ``tabix.*``
 counters, the index inside the pipeline's wall, and an index that cannot be
-written made visible. A plain-text run emits none of the spans and reads 0."""
+written made visible. A plain-text run emits none of the spans and reads 0.
+Since ISSUE 37 the index is gathered in the pipeline: ``tabix_index`` rows on
+the workers that render, one on the ordered threads, and two counters that say
+whether a file's index came from there or from the second pass."""
 
 from __future__ import annotations
 
@@ -21,7 +24,8 @@ N = 6000
 SPANS = ("inflate", "compress_stage", "tabix_index")
 COUNTERS = ("bgzf.in_bytes", "bgzf.in_blocks", "bgzf.inflate_shards",
             "bgzf.text_bytes_in", "bgzf.text_bytes_out", "bgzf.out_bytes",
-            "bgzf.out_blocks", "tabix.records", "tabix.index_skipped")
+            "bgzf.out_blocks", "tabix.records", "tabix.index_skipped",
+            "tabix.index_streamed", "tabix.index_second_pass")
 
 
 @pytest.fixture(autouse=True)
@@ -105,11 +109,18 @@ def test_a_gz_run_emits_the_span_and_its_row(gz_run, name):
         assert all(s["bytes_in"] > 0 and "bytes_out" in s for s in spans)
         assert sum(r["bytes_in"] for r in rows) > 0 < sum(r["bytes_out"] for r in rows)
     else:
-        (span,) = spans
+        # a chunk's facts on the pooled worker that rendered it, their place
+        # in the file on the compress stage's thread, the write on the
+        # committer's, the only one with `bytes`
+        pooled = {k: r for k, r in gz_run["rows"].items() if k.startswith("tabix_index.w")}
+        assert pooled and sum(r["records"] for r in pooled.values()) == N
+        assert gz_run["rows"]["tabix_index"]["work_s"] > 0
+        (span,) = [s for s in spans if "bytes" in s]
         assert span["records"] == N and span["bytes"] == os.path.getsize(gz_run["out"])
         # the index is part of what the user waited for: inside the wall
         (pipe,) = gz_run["pipeline"]
-        assert "tabix_index" in pipe["stages"] and pipe["wall_s"] > span["dur"]
+        assert "tabix_index" in pipe["stages"] and set(pooled) <= set(pipe["stages"])
+        assert pipe["wall_s"] > gz_run["rows"]["tabix_index"]["work_s"]
         assert span["start"] + span["dur"] <= max(
             s["start"] + s["dur"] for s in gz_run["spans"]) + 1e-6
 
@@ -130,6 +141,7 @@ def test_a_gz_runs_counters_agree_with_the_files(world, gz_run, plain_run):
     assert c["bgzf.text_bytes_out"] == os.path.getsize(plain_run["out"])
     assert gzip.decompress(data) == open(plain_run["out"], "rb").read()
     assert c["tabix.records"] == N and c["tabix.index_skipped"] == 0
+    assert (c["tabix.index_streamed"], c["tabix.index_second_pass"]) == (1, 0)
     assert os.path.exists(gz_run["out"] + ".tbi")
 
 
@@ -141,6 +153,9 @@ def test_an_output_that_cannot_be_indexed_is_counted_and_still_exits_0(
     assert got["rc"] == 0
     assert got["counters"]["tabix.index_skipped"] == 1
     assert got["counters"]["tabix.records"] == 0
-    assert not [s for s in got["spans"] if s["name"] == "tabix_index"]
+    assert (got["counters"]["tabix.index_streamed"],
+            got["counters"]["tabix.index_second_pass"]) == (0, 0)
+    # the write that failed left no span; the gathering before it did
+    assert not [s for s in got["spans"] if s["name"] == "tabix_index" and "bytes" in s]
     assert "no tabix index beside" in caplog.text
     assert len(gzip.decompress(open(out, "rb").read()).splitlines()) > N

@@ -15,11 +15,13 @@ from __future__ import annotations
 
 import struct
 import zlib
+from dataclasses import dataclass
 
 import numpy as np
 
 from variantcalling_tpu import obs
-from variantcalling_tpu.io.bgzf import BgzfWriter, compress_block
+from variantcalling_tpu.io.bgzf import (BGZF_EOF, MAX_BLOCK_DATA, BgzfWriter,
+                                        compress_block, scan_block_spans)
 
 TBI_MAGIC = b"TBI\x01"
 FMT_VCF = 2
@@ -165,13 +167,16 @@ def _index_line(line, names, refs, v_start, v_end, preset, col_seq, col_beg, col
     refs[chrom].add(beg, end, v_start, v_end)
 
 
-def _write_tbi(out, names, refs, preset, col_seq, col_beg, col_end, meta_char):
-    payload = bytearray()
-    payload += TBI_MAGIC
-    payload += struct.pack("<i", len(names))
-    payload += struct.pack("<6i", preset, col_seq, col_beg, col_end, ord(meta_char), 0)
+def _tbi_header(names, preset=FMT_VCF, col_seq=1, col_beg=2, col_end=0, meta_char="#") -> bytes:
+    """The payload up to the first contig: magic, format block, names."""
     nm = b"".join(n.encode() + b"\x00" for n in names)
-    payload += struct.pack("<i", len(nm)) + nm
+    return (TBI_MAGIC + struct.pack("<i", len(names))
+            + struct.pack("<6i", preset, col_seq, col_beg, col_end, ord(meta_char), 0)
+            + struct.pack("<i", len(nm)) + nm)
+
+
+def _write_tbi(out, names, refs, preset, col_seq, col_beg, col_end, meta_char):
+    payload = bytearray(_tbi_header(names, preset, col_seq, col_beg, col_end, meta_char))
     for name in names:
         ref = refs[name]
         payload += struct.pack("<i", len(ref.bins))
@@ -194,8 +199,6 @@ def _write_tbi(out, names, refs, preset, col_seq, col_beg, col_end, meta_char):
         data = bytes(payload)
         for i in range(0, max(len(data), 1), 65280):
             fh.write(compress_block(data[i : i + 65280]))
-        from variantcalling_tpu.io.bgzf import BGZF_EOF
-
         fh.write(BGZF_EOF)
 
 
@@ -204,6 +207,194 @@ def write_indexed_vcf(path: str, write_fn) -> str:
     with BgzfWriter(path) as fh:
         write_fn(fh)
     return build_tabix_index(path)
+
+
+# -------------------------------------------------- the index of a run ---
+#
+# A run that writes a ``.vcf.gz`` itself knows everything the second pass
+# of :func:`build_tabix_index` would find out: each record's contig, POS
+# and len(REF) from the parser, its bytes from the renderer, and the
+# members from the compressor, which frames the text into consecutive
+# MAX_BLOCK_DATA payloads whatever the chunk borders are. So the ``.tbi``
+# is gathered in two halves, :func:`chunk_index_facts` on the workers that
+# render a chunk (native/src/vctpu_tabix.cc) and :class:`StreamedIndex`
+# where the chunks pass in file order, and comes out byte for byte
+# (inflated) what the second pass writes, which stays the oracle of
+# tests/unit/test_tabix_streamed.py.
+
+
+@dataclass
+class ChunkIndexFacts:
+    """What one rendered chunk alone says of the index: record numbers
+    within the chunk, text offsets from the chunk's first byte."""
+
+    names: list[str]  # the chunk's contigs, in order, none twice
+    first_beg: int  # its first and last record's 0-based start
+    last_beg: int
+    ends: np.ndarray  # (n,) where each record's line ends in the body
+    run_first: np.ndarray  # first record of each run of one contig and bin
+    run_contig: np.ndarray  # ... its contig, an index into ``names``
+    run_bin: np.ndarray
+    win_contig: np.ndarray  # the 16 kb windows the chunk touches ...
+    win: np.ndarray
+    win_start: np.ndarray  # ... and where the first record over each starts
+
+
+def chunk_index_facts(body, table) -> ChunkIndexFacts | None:
+    """The worker's half: ``table``'s records as :func:`_index_line` reads
+    them, laid on the lines of the rendered ``body`` (what is written, so
+    whichever renderer made it), in one native pass and never a Python
+    step a record. None where the chunk cannot vouch for the index and
+    the second pass has to: a table without the native scan's columns, a
+    body that is not one line a record, records out of order (a position
+    before the one before it, a contig twice), a start before 0, a name
+    the scan's 64-byte dictionary cut short."""
+    from variantcalling_tpu import native
+
+    codes, aux = table.chrom_codes, table.aux
+    if codes is None or aux is None or "ref_len" not in aux.alle or not len(table):
+        return None
+    got = native.tabix_chunk_facts(body, codes, table.pos, aux.alle["ref_len"])
+    if got is None:
+        return None
+    ends, contig_first, run_first, run_contig, run_bin, win_contig, win, win_start = got
+    names = [str(c) for c in table.chrom_names[codes[contig_first]]]
+    if len(set(names)) != len(names) or max(len(c.encode()) for c in names) >= 63:
+        return None
+    return ChunkIndexFacts(
+        names=names, first_beg=int(table.pos[0]) - 1, last_beg=int(table.pos[-1]) - 1,
+        ends=ends, run_first=run_first, run_contig=run_contig, run_bin=run_bin,
+        win_contig=win_contig, win=win, win_start=win_start)
+
+
+class StreamedIndex:
+    """The ordered half: chunks' facts and the compressor's members in
+    file order, then the ``.tbi``. One thread at a time calls it (the
+    committer before the pipeline starts and after it ends, the compress
+    stage between). ``complete`` turns False, for good, once something
+    passed that the facts do not cover; the caller then indexes the
+    committed file by the second pass."""
+
+    def __init__(self):
+        self.complete = True
+        self.records = 0
+        self._contigs: dict[str, int] = {}  # name -> number, by first appearance
+        self._last_beg = 0
+        self._runs: list[tuple] = []  # a chunk: (contig, bin, text start, text end)
+        self._wins: list[tuple] = []  # a chunk: (contig, window, text start)
+        # (compressed offset, payload bytes) of each member that holds text
+        self._members: list[tuple[int, int]] = []
+        self._file_bytes = 0
+
+    def add_blocks(self, blob) -> None:
+        """What ``BgzfChunkCompressor.add`` / ``finish`` returned, as written."""
+        if not self.complete or not blob:
+            return
+        spans = scan_block_spans(blob)
+        if spans is None:
+            self.complete = False
+            return
+        self._members += [(self._file_bytes + off, isize)
+                          for off, _bsize, isize in spans if isize]  # not the EOF member
+        self._file_bytes += len(blob)
+
+    def add_chunk(self, facts: ChunkIndexFacts | None, base: int) -> None:
+        """A chunk whose body starts at text offset ``base`` (the header's
+        bytes come first). None: a body with no facts (a cached one)."""
+        if not self.complete:
+            return
+        if facts is None:
+            self.complete = False
+            return
+        contig = np.empty(len(facts.names), dtype=np.int64)
+        for j, name in enumerate(facts.names):
+            known = self._contigs.get(name)
+            if known is None:
+                known = self._contigs[name] = len(self._contigs)
+            elif j or known != len(self._contigs) - 1 or facts.first_beg < self._last_beg:
+                self.complete = False  # a contig again, or a position going back
+                return
+            contig[j] = known
+        self._last_beg = facts.last_beg
+        ends = facts.ends
+        # a record that ends with its member ends at (member << 16 | payload)
+        # and the next starts at (next member << 16): no chunk of a bin
+        # crosses that, so a run is cut where a record starts a member
+        cut = np.flatnonzero((ends[:-1] + base) % MAX_BLOCK_DATA == 0) + 1
+        first = np.union1d(facts.run_first, cut)
+        run = np.searchsorted(facts.run_first, first, "right") - 1
+        last = np.append(first[1:], len(ends)) - 1
+        self._runs.append((contig[facts.run_contig[run]], facts.run_bin[run],
+                           base + np.where(first > 0, ends[first - 1], 0),
+                           base + ends[last]))
+        self._wins.append((contig[facts.win_contig], facts.win, base + facts.win_start))
+        self.records += len(ends)
+
+    def write(self, path: str) -> bool:
+        """Write ``path`` (a ``.tbi``) and say so; False, and nothing
+        written, where the members are not consecutive MAX_BLOCK_DATA
+        payloads that hold every record."""
+        coff, sizes = np.array(self._members, dtype=np.int64).reshape(-1, 2).T
+        runs = [np.concatenate(c) for c in zip(*self._runs)] or [np.empty(0, np.int64)] * 4
+        contig, bins, u_start, u_end = runs
+        if (not self.complete or np.any(sizes[:-1] != MAX_BLOCK_DATA)
+                or (len(u_end) and u_end[-1] > sizes.sum())):
+            return False
+
+        def voffset(u, is_end):
+            # an end is named in the member that holds its newline
+            k = (u - is_end) // MAX_BLOCK_DATA
+            return ((coff[k] << 16) | (u - k * MAX_BLOCK_DATA)).astype("<u8")
+
+        # neighbours of one bin on either side of a chunk border are one
+        # chunk of the index, as _RefIndex.add merges them
+        joined = np.zeros(len(bins), dtype=bool)
+        joined[1:] = ((contig[1:] == contig[:-1]) & (bins[1:] == bins[:-1])
+                      & (u_start[1:] == u_end[:-1]) & (u_end[:-1] % MAX_BLOCK_DATA != 0))
+        head = np.flatnonzero(~joined)
+        tail = np.append(head[1:], len(bins)) - 1
+        contig, bins = contig[head], bins[head]
+        v_start, v_end = voffset(u_start[head], 0), voffset(u_end[tail], 1)
+        order = np.lexsort((bins, contig))  # stable: file order within a bin
+        contig, bins, v_start, v_end = contig[order], bins[order], v_start[order], v_end[order]
+        wins = [np.concatenate(c) for c in zip(*self._wins)] or [np.empty(0, np.int64)] * 3
+        win_contig, win, win_start = wins
+        parts = [_tbi_header(list(self._contigs))]
+        for number in range(len(self._contigs)):
+            lo, hi = np.searchsorted(contig, [number, number + 1])
+            parts.append(_bins_payload(bins[lo:hi], v_start[lo:hi], v_end[lo:hi]))
+            mine = win_contig == number
+            parts.append(_linear_payload(win[mine], voffset(win_start[mine], 0)))
+        with BgzfWriter(path) as fh:
+            fh.write(b"".join(parts))
+        return True
+
+
+def _bins_payload(bins, v_start, v_end) -> bytes:
+    """One contig's ``n_bin`` and bins, from its chunks sorted by bin:
+    32-bit words, a bin's two before its chunks' four each."""
+    n = len(bins)
+    new = np.ones(n, dtype=bool)
+    new[1:] = bins[1:] != bins[:-1]
+    first = np.flatnonzero(new)
+    words = np.empty(1 + 2 * len(first) + 4 * n, dtype="<u4")
+    words[0] = len(first)
+    at = 1 + 2 * np.arange(len(first)) + 4 * first
+    words[at] = bins[first]
+    words[at + 1] = np.diff(np.append(first, n))
+    at = 3 + 2 * (np.cumsum(new) - 1) + 4 * np.arange(n)
+    for k, half in enumerate((v_start & 0xFFFFFFFF, v_start >> 32, v_end & 0xFFFFFFFF, v_end >> 32)):
+        words[at + k] = half
+    return words.tobytes()
+
+
+def _linear_payload(win, v_start) -> bytes:
+    """One contig's ``n_intv`` and linear index: each window's first
+    record in file order, a window no record touches filled forward."""
+    uniq, where = np.unique(win, return_index=True)  # first appearances
+    at = np.searchsorted(uniq, np.arange(uniq[-1] + 1), "right") - 1
+    ioff = np.where(at >= 0, v_start[where][at], 0).astype("<u8")
+    return struct.pack("<i", len(ioff)) + ioff.tobytes()
 
 
 # ---------------------------------------------------------------- reader ---

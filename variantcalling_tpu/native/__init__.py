@@ -30,8 +30,10 @@ _SRC_GBT = os.path.join(_DIR, "src", "vctpu_gbt.cc")
 _SRC_FEAT = os.path.join(_DIR, "src", "vctpu_features.cc")
 _SRC_FUSED = os.path.join(_DIR, "src", "vctpu_fused.cc")
 _SRC_WIRE = os.path.join(_DIR, "src", "vctpu_wire.cc")
+_SRC_TABIX = os.path.join(_DIR, "src", "vctpu_tabix.cc")
 #: every translation unit of the library, in link order
-_SRCS = (_SRC, _SRC_CRAM, _SRC_MATCH, _SRC_GBT, _SRC_FEAT, _SRC_FUSED, _SRC_WIRE)
+_SRCS = (_SRC, _SRC_CRAM, _SRC_MATCH, _SRC_GBT, _SRC_FEAT, _SRC_FUSED, _SRC_WIRE,
+         _SRC_TABIX)
 #: shared inline headers — hashed into the build key (an edit must
 #: rebuild every TU that includes them) but not compiled standalone
 _HDRS = (os.path.join(_DIR, "src", "vctpu_threads.h"),
@@ -264,6 +266,10 @@ def get_lib() -> ctypes.CDLL | None:
             _vp, _vp, _vp, _vp, _vp, _vp,
             _vp, _i64, ctypes.c_int32,
         ]
+        lib.vctpu_tabix_chunk_facts.restype = _i64
+        lib.vctpu_tabix_chunk_facts.argtypes = [
+            _vp, _i64, _i64, _vp, _vp, _vp,
+            _vp, _vp, _vp, _vp, _vp, _vp, _vp, _vp, _i64, _vp]
         _LIB = lib
         return _LIB
 
@@ -1076,6 +1082,40 @@ def wire_fill(dst: np.ndarray, dst_row0: int, lo: int, hi: int,
     if rc != hi - lo:
         raise ValueError(f"wire_fill: the native fill refused its arguments ({rc})")
     return True
+
+
+def tabix_chunk_facts(body, chrom_codes, pos, ref_len):
+    """A rendered chunk's share of a tabix index (``src/vctpu_tabix.cc``;
+    :func:`variantcalling_tpu.io.tabix.chunk_index_facts` names the parts),
+    in ONE call with the interpreter released: ``(ends, contig_first,
+    run_first, run_contig, run_bin, win_contig, win, win_start)``, all
+    int64. None when the library is missing or the chunk cannot vouch for
+    the index (``body`` is not one line a record, records out of order)."""
+    lib = get_lib()
+    if lib is None:
+        return None
+    n = len(pos)
+    text = np.ascontiguousarray(_u8view(body))
+    codes = np.ascontiguousarray(chrom_codes, dtype=np.int32)
+    pos = np.ascontiguousarray(pos, dtype=np.int64)
+    ref_len = np.ascontiguousarray(ref_len, dtype=np.int32)
+    if not len(codes) == len(ref_len) == n:
+        raise ValueError("tabix_chunk_facts: columns of different lengths")
+    # a window a record and its REF's further ones: a chunk whose REFs
+    # reach over more than this is left to the second pass
+    win_cap = 2 * n + 64
+    out = [np.empty(n, dtype=np.int64) for _ in range(5)] + \
+          [np.empty(win_cap, dtype=np.int64) for _ in range(3)]
+    counts = np.zeros(3, dtype=np.int64)
+    rc = lib.vctpu_tabix_chunk_facts(
+        text.ctypes.data, len(text), n, codes.ctypes.data, pos.ctypes.data,
+        ref_len.ctypes.data, *[a.ctypes.data for a in out], win_cap,
+        counts.ctypes.data)
+    if rc != 0:
+        return None
+    n_contig, n_run, n_win = counts.tolist()
+    sizes = (n, n_contig, n_run, n_run, n_run, n_win, n_win, n_win)
+    return tuple(a[:k].copy() if k < len(a) else a for a, k in zip(out, sizes))
 
 
 def fasta_encode(raw: np.ndarray, line_bases: int, line_width: int,

@@ -1550,6 +1550,8 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     from variantcalling_tpu.io import chunk_cache as chunk_cache_mod
     from variantcalling_tpu.io import identity as identity_mod
     from variantcalling_tpu.io import journal as journal_mod
+    from variantcalling_tpu.io.tabix import (StreamedIndex, build_tabix_index,
+                                             chunk_index_facts)
     from variantcalling_tpu.io.vcf import (VcfChunkReader, assemble_table_bytes,
                                            render_table_bytes_python)
     from variantcalling_tpu.parallel.pipeline import (StagePipeline,
@@ -1572,8 +1574,8 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     for name in ("in_bytes", "in_blocks", "inflate_shards", "text_bytes_in",
                  "text_bytes_out", "out_bytes", "out_blocks"):
         obs.counter(f"bgzf.{name}").add(0)
-    obs.counter("tabix.records").add(0)
-    obs.counter("tabix.index_skipped").add(0)
+    for name in ("records", "index_streamed", "index_second_pass", "index_skipped"):
+        obs.counter(f"tabix.{name}").add(0)
     # continuous-profiler attribution (obs v3): this thread runs the
     # sequenced single-writer commit loop for the duration of the run
     sampler_mod.register_current("committer")
@@ -1686,7 +1688,7 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                 cbody, k, p = hit
                 if tid is not None:
                     obs.trace_span(tid, "cache_hit", 0.0, records=k)
-                return cbody, k, p, None, tid
+                return cbody, k, p, None, tid, None
         ingest_span_emitted = [False]
 
         def body():
@@ -1736,15 +1738,22 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                 qbody = assemble_table_bytes(table)
                 if qbody is None:
                     qbody = render_table_bytes_python(table)
-                return b"", len(table), 0, bytes(qbody), tid
+                return b"", len(table), 0, bytes(qbody), tid, None
             extra = {"TREE_SCORE": np.round(score, 4)}
             body = assemble_table_bytes(table, new_filters=filters,
                                         extra_info=extra)
             if body is None:  # native hiccup mid-run: Python renderer, same bytes
                 body = render_table_bytes_python(table, new_filters=filters,
                                                  extra_info=extra)
-            return (body, len(table), int(np.sum(filters.codes == 0)), None,
-                    tid)
+        # a .gz output's last slot: what this chunk says of the .tbi, worked
+        # out here, beside the render, where the table still is (None for
+        # plain text, which scans nothing)
+        facts = None
+        if index is not None and index.complete:
+            with stage("tabix_index", records=len(table)):
+                facts = chunk_index_facts(body, table)
+        return (body, len(table), int(np.sum(filters.codes == 0)), None, tid,
+                facts)
 
     render_stage.self_timed = True
 
@@ -1760,7 +1769,7 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     # the consumer below is the sequenced single-writer merge: it drains
     # compressed chunks strictly in sequence order through the same
     # .partial + os.replace atomic path plain outputs use.
-    compressor = None
+    compressor = index = None
     if gz:
         from variantcalling_tpu.io.bgzf import BgzfChunkCompressor
         from variantcalling_tpu.parallel.pipeline import resolve_io_threads
@@ -1768,17 +1777,25 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
         compress_pool = (reader.shared_pool() if resolve_io_threads() > 1
                          else None)
         compressor = BgzfChunkCompressor(pool=compress_pool)
+        # the .tbi is gathered from what the run holds (io/tabix.py): a
+        # chunk's facts on the worker that renders it, their place in the
+        # file here, where chunks pass in order and the members appear
+        index = StreamedIndex()
 
         def compress_stage(item):
-            body, k, p, q, tid = item
+            body, k, p, q, tid, facts = item
             if not len(body):  # quarantined chunk: nothing to compress
-                return b"", k, p, q, tid
+                return b"", k, p, q, tid, None
             data = memoryview(body) if isinstance(body, np.ndarray) else body
+            text_start = compressor.bytes_in
             with stage("compress_stage", trace=tid, causal=True,
                        bytes_in=len(data)) as sp:
                 out = compressor.add(data)
                 sp.set(bytes_out=len(out))
-            return out, k, p, q, tid
+            with stage("tabix_index"):
+                index.add_chunk(facts, text_start)
+                index.add_blocks(out)
+            return out, k, p, q, tid, None
 
         compress_stage.self_timed = True
 
@@ -2066,10 +2083,12 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                     # serial BgzfWriter buffered it identically). Safe
                     # ordering: the compress stage has not started — the
                     # pipeline workers spin up on the first next() below.
-                    _sink_write(sink, compressor.add(header_bytes))
+                    head = compressor.add(header_bytes)
+                    index.add_blocks(head)
+                    _sink_write(sink, head)
                 else:
                     _sink_write(sink, header_bytes)
-            for body, k, p, qbody, trace_id in gen:
+            for body, k, p, qbody, trace_id, _facts in gen:
                 # cooperative per-request cancellation (vctpu serve
                 # deadlines/drain, docs/serving.md): chunk-granular by
                 # design — raising here unwinds through the normal
@@ -2152,7 +2171,9 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
             if compressor is not None:
                 # the final partial block + EOF sentinel — the committer
                 # (this thread) is the only writer, in sequence order
-                _sink_write(sink, compressor.finish())
+                tail = compressor.finish()
+                index.add_blocks(tail)
+                _sink_write(sink, tail)
         ok = True
     finally:
         # guaranteed teardown on EVERY exit path: stage workers drained and
@@ -2227,14 +2248,19 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                        "— the main output is INCOMPLETE by that many records",
                        n_quar_chunks, n_quar_records, q_path)
     if gz:
-        # the index is a second pass over the committed file, and part of
-        # what the user waits for: inside the run's wall, under a span
-        from variantcalling_tpu.io.tabix import build_tabix_index
-
+        # the index is part of what the user waits for: inside the run's
+        # wall, under a span. It is written from what the pipeline gathered;
+        # a file the facts do not cover (a replayed cached body, records out
+        # of order) is indexed by a second pass over the committed file
         try:
             with stage("tabix_index", records=n_total,
                        bytes=os.path.getsize(out_path)):
-                build_tabix_index(out_path)
+                if index.write(out_path + ".tbi"):
+                    obs.counter("tabix.index_streamed").add(1)
+                    obs.counter("tabix.records").add(index.records)
+                else:
+                    obs.counter("tabix.index_second_pass").add(1)
+                    build_tabix_index(out_path)
         except (ValueError, OSError) as e:
             # unsorted/odd inputs: the VCF itself is still valid
             logger.warning("no tabix index beside %s: %s", out_path, e)
