@@ -435,3 +435,196 @@ def test_the_vcfgz_configuration_is_the_forest_one_in_another_container():
     assert bm["configs"][-1]["name"] == gz["name"] == bm["workloads"][-1]["config"]
     assert bm["configs"][-1]["source"] == gz["source"]
     assert bm["configs"][-1]["reduced"] == gz["reduced"] == ["variants_per_file"]
+
+
+# -- idle time by the program's own layers, on-CPU shares, fixed work (ISSUE 38) --
+
+def layer_events(**layer_of):
+    """The obs ``span`` events a traced file would carry, one a name."""
+    return [{"kind": "span", "name": n, "dur": 1.0, "layer": layer}
+            for n, layer in layer_of.items()]
+
+
+#: THREADS' spans as the program names their layers; ``score_stage`` and
+#: ``dispatch_wait`` are of the layer that does not vote
+LAYERS = dict(score_stage="wait", dispatch_wait="wait", host_featurize="feed",
+              fused_program="program", parse="ingest")
+#: BY_HAND's pieces, with ``io`` now the layer ``ingest``
+BY_LAYER = {"program": 1.0, "feed": 0.75, "ingest": 0.75, "unnamed": 5.5}
+
+
+def test_idle_by_layer_attributes_the_hand_made_timeline_by_a_given_grouping(bench):
+    reader = bench.load("readers", "idle_by_layer")
+    got = reader.attribute(THREADS, BUSY, (0.0, 10 * S),
+                           lambda n: None if LAYERS[n] == "wait" else LAYERS[n])
+    assert got == pytest.approx(BY_LAYER)
+    assert sum(got.values()) == pytest.approx(8.0)  # all of the idle time
+    # the grouping is the caller's: one group for everything, waiters too
+    one = reader.attribute(THREADS, BUSY, (0.0, 10 * S), lambda n: "host")
+    assert one == pytest.approx({"host": 7.0, "unnamed": 1.0})  # [9,10]: no span open
+
+
+@pytest.fixture()
+def traced_threads(bench, monkeypatch):
+    import program_spans
+
+    monkeypatch.setattr(program_spans, "load",
+                        lambda *a: {"threads": THREADS, "devices": [], "on_tpu": True})
+    reader = bench.load("readers", "idle_by_layer")
+    monkeypatch.setattr(reader, "NOTES", os.devnull)
+    return reader
+
+
+def test_idle_by_layers_shares_add_up_to_the_idle_share(bench, traced_threads):
+    ctx = context(obs_events=layer_events(**LAYERS))
+    got = traced_threads.shares(ctx)
+    # the harness's interval is half a second longer than the files' spans:
+    # that half second is idle, and nobody's
+    assert got == pytest.approx({"program": 100 * 1.0 / 10.5, "feed": 100 * 0.75 / 10.5,
+                                 "ingest": 100 * 0.75 / 10.5, "unnamed": 100 * 6.0 / 10.5})
+    assert "wait" not in got
+    assert sum(got.values()) == pytest.approx(bench.load("readers", "device_idle").read(ctx))
+    assert traced_threads.read(ctx, None) == pytest.approx(100 * 6.0 / 10.5)
+    assert traced_threads.read(ctx, "feed") == pytest.approx(100 * 0.75 / 10.5)
+    assert traced_threads.read(ctx, "daemon") is None  # a layer no event names
+
+
+@pytest.mark.parametrize("layers, want", [
+    # a thread inside a `wait` span does not vote: were the waiter a feeder,
+    # [1,1.5] would be shared and [1.5,2], [3,3.5] its own
+    (dict(LAYERS, dispatch_wait="feed"),
+     {"program": 0.75, "feed": 2.25, "ingest": 0.5, "unnamed": 5.0}),
+    # the layer is the events', not the reader's: call parse `commit` there
+    # and its share follows
+    (dict(LAYERS, parse="commit"),
+     {"program": 1.0, "feed": 0.75, "commit": 0.75, "unnamed": 6.0}),
+    # a name no event gives a layer lands in unnamed
+    ({k: v for k, v in LAYERS.items() if k != "fused_program"},
+     {"feed": 0.75, "ingest": 0.75, "unnamed": 7.0}),
+    # a layer the events name and nobody was idle under reads 0, not nothing
+    (dict(LAYERS, obs_close="tracing"),
+     {"program": 1.0, "feed": 0.75, "ingest": 0.75, "tracing": 0.0, "unnamed": 6.0}),
+], ids=["wait-does-not-vote", "layer-from-the-events", "no-event-is-unnamed",
+        "named-and-never-idle"])
+def test_idle_by_layer_takes_the_layers_from_the_events(traced_threads, layers, want):
+    got = traced_threads.shares(context(obs_events=layer_events(**layers)))
+    assert got == pytest.approx({k: 100 * v / 10.5 for k, v in want.items()})
+
+
+@pytest.mark.parametrize("traced_s, unnamed, voted, beyond", [
+    # the harness's interval half a second longer than the files' spans:
+    # the remainder is what nobody was voted for and that half second
+    (10.5, 6.0, 5.5, 0.5),
+    # the interval exactly the files' window: counted both ways, one number
+    (10.0, 5.5, 5.5, 0.0),
+    # an interval shorter than the window the layers were voted in: they hold
+    # more idle time (2.5 s) than it has (2.0 s), so the remainder is no
+    # reading; the layers' own shares and the notes still say what was counted
+    (4.0, None, 5.5, -6.0),
+], ids=["beyond-the-window", "the-window-itself", "layers-over-the-idle-time"])
+def test_idle_by_layers_unnamed_is_counted_two_ways_and_both_are_noted(
+        traced_threads, monkeypatch, tmp_path, traced_s, unnamed, voted, beyond):
+    notes = tmp_path / "idle_by_layer.txt"
+    monkeypatch.setattr(traced_threads, "NOTES", str(notes))
+    ctx = context(obs_events=layer_events(**LAYERS), traced_s=traced_s)
+    share = 100 / traced_s
+    assert traced_threads.read(ctx, "program") == pytest.approx(1.0 * share)
+    got = traced_threads.read(ctx, None)
+    assert got == (pytest.approx(unnamed * share) if unnamed is not None else None)
+    noted = dict(ln.split("\t") for ln in notes.read_text().splitlines())
+    assert float(noted["unnamed_voted_in_window"]) == pytest.approx(voted * share, abs=1e-3)
+    assert float(noted["idle_beyond_window"]) == pytest.approx(beyond * share, abs=1e-3)
+    assert ("unnamed" in noted) == (unnamed is not None)
+    if unnamed is not None:  # the remainder is the sum of the two counts
+        assert float(noted["unnamed"]) == pytest.approx((voted + beyond) * share, abs=1e-3)
+
+
+def stage_row(stage, work, **kw):
+    return {"kind": "profile", "name": "stage", "stage": stage, "work_s": work, **kw}
+
+
+#: what the parent's program writes: spans without layers, rows without cpu_s
+PARENT_EVENTS = [{"kind": "span", "name": "stream", "dur": 2.0},
+                 {"kind": "span", "name": "parse", "dur": 0.5},
+                 stage_row("parse.w0", 0.5), stage_row("render_stage.w0", 0.25),
+                 {"kind": "profile", "name": "pipeline", "wall_s": 2.0}]
+
+
+@pytest.mark.parametrize("reader, args, events, want", [
+    # two workers' rows: (0.25 + 0.5) of (1.0 + 2.0); the family alone counts
+    ("stage_cpu_share", {"families": ["parse"]},
+     [stage_row("parse.w0", 1.0, cpu_s=0.25), stage_row("parse.w1", 2.0, cpu_s=0.5),
+      stage_row("render_stage.w0", 4.0, cpu_s=4.0)], 25.0),
+    # a row no span fed (no cpu_s) is on neither side
+    ("stage_cpu_share", {"families": ["ingest", "parse"]},
+     [stage_row("parse.w0", 1.0, cpu_s=0.5), stage_row("ingest", 9.0)], 50.0),
+    ("stage_cpu_share", {"families": ["parse"]}, PARENT_EVENTS, None),
+    ("stage_cpu_share", {"families": ["inflate"]},
+     [stage_row("parse.w0", 1.0, cpu_s=0.5)], None),
+    # (0.25 + 0.5 + 0.125 + 0.125) of (0.25 + 3.75)
+    ("span_sum_share", {"parts": ["run_open", "stream_open", "stream_close", "commit"],
+                        "whole": ["run_open", "stream"]},
+     [{"kind": "span", "name": n, "dur": d} for n, d in
+      [("run_open", 0.25), ("stream", 3.75), ("stream_open", 0.5),
+       ("stream_close", 0.125), ("commit", 0.125), ("parse", 9.0)]], 25.0),
+    # a request: no run_open, its stream is the whole
+    ("span_sum_share", {"parts": ["run_open", "stream_open"], "whole": ["run_open", "stream"]},
+     [{"kind": "span", "name": "stream", "dur": 2.0},
+      {"kind": "span", "name": "stream_open", "dur": 0.5}], 25.0),
+    ("span_sum_share", {"parts": ["run_open", "stream_open"], "whole": ["run_open", "stream"]},
+     PARENT_EVENTS, None),
+], ids=["cpu-two-workers", "cpu-unfed-row", "cpu-parent", "cpu-no-family",
+        "fixed-work-a-file", "fixed-work-a-request", "fixed-work-parent"])
+def test_on_cpu_and_span_sum_readers_on_hand_made_events(bench, reader, args, events, want):
+    got = bench.load("readers", reader).read(context(obs_events=events), **args)
+    assert got == (pytest.approx(want) if want is not None else None)
+
+
+def test_the_parents_program_gives_idle_by_layer_nothing_to_read(traced_threads):
+    assert traced_threads.shares(context(obs_events=PARENT_EVENTS)) is None
+    assert traced_threads.read(context(obs_events=PARENT_EVENTS), None) is None
+
+
+LAYER_METRICS_38 = {
+    "parse_on_cpu_share": ("ingest and parse", "stage_cpu_share", "all"),
+    "render_on_cpu_share": ("render and commit", "stage_cpu_share", "all"),
+    "inflate_on_cpu_share": ("ingest and parse", "stage_cpu_share", [VCFGZ_CELL]),
+    "idle_entry_share": ("CLI entry", "idle_by_layer", "all"),
+    "idle_ingest_share": ("ingest and parse", "idle_by_layer", "all"),
+    "idle_render_share": ("render and commit", "idle_by_layer", "all"),
+    "idle_commit_share": ("render and commit", "idle_by_layer", "all"),
+    "idle_daemon_share": ("daemon front", "idle_by_layer",
+                          ["forest-t40d6-hg38x2-exome.serve-c4"]),
+    "idle_tracing_share": ("CLI entry", "idle_by_layer", "batch"),
+    "idle_unnamed_share": ("device", "idle_by_layer", "all"),
+    "fixed_work_share": ("CLI entry", "span_sum_share", "all"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(LAYER_METRICS_38))
+def test_issue_38s_metrics_come_last_and_name_the_programs_layers(bench, name):
+    """Each of the eleven comes after everything the benchmark had, lists its
+    cells, names a reader this issue brought, and (the idle shares) a layer
+    the program's own table has."""
+    from variantcalling_tpu.utils import trace
+
+    layer, reader, where = LAYER_METRICS_38[name]
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    cells = [w["name"] for w in bm["workloads"]]
+    serve = "forest-t40d6-hg38x2-exome.serve-c4"
+    want = where if isinstance(where, list) else {
+        "all": cells, "batch": [c for c in cells if c != serve]}[where]
+    assert len(bm["per_layer"]) == 53
+    (m,) = [m for m in bm["per_layer"][42:] if m["name"] == name]
+    assert (m["workloads"], m["moves"], m["layer"], m["unit"]) == (
+        want, "variants_per_s", layer, "%")
+    with open(os.path.join(BENCH, "layer_metrics", name + ".json"), encoding="utf-8") as fh:
+        how = json.load(fh)
+    assert how["reader"] == reader and callable(bench.load("readers", reader).read)
+    if reader == "idle_by_layer" and how["args"]["layer"] is not None:
+        assert how["args"]["layer"] in set(trace.LAYER_OF.values()) - {"wait"}
+    elif reader == "span_sum_share":
+        assert set(how["args"]["parts"]) | set(how["args"]["whole"]) <= set(trace.LAYER_OF)
+    elif reader == "stage_cpu_share":
+        assert set(how["args"]["families"]) <= set(trace.LAYER_OF)

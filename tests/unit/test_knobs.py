@@ -59,13 +59,13 @@ def test_bool_spellings(monkeypatch):
     for raw, want in [("1", True), ("true", True), ("YES", True),
                       ("on", True), ("0", False), ("false", False),
                       ("No", False), ("off", False)]:
-        monkeypatch.setenv("VCTPU_TRACE", raw)
-        assert knobs.get_bool("VCTPU_TRACE") is want
+        monkeypatch.setenv("VCTPU_OBS_TRACE", raw)
+        assert knobs.get_bool("VCTPU_OBS_TRACE") is want
 
 
 def test_typed_accessors_enforce_kind():
     with pytest.raises(TypeError, match="bool knob"):
-        knobs.get_int("VCTPU_TRACE")
+        knobs.get_int("VCTPU_OBS_TRACE")
     with pytest.raises(KeyError):
         knobs.get("VCTPU_NOT_A_KNOB")
     with pytest.raises(KeyError):
@@ -87,7 +87,7 @@ def test_typed_accessors_enforce_kind():
     ("VCTPU_STAGE_TIMEOUT_S", "-5", "must be >= 0"),
     ("VCTPU_ENGINE", "cuda", "not a valid engine"),
     ("VCTPU_FOREST_STRATEGY", "narrow", "not a valid forest strategy"),
-    ("VCTPU_TRACE", "maybe", "not a valid boolean"),
+    ("VCTPU_OBS_TRACE", "maybe", "not a valid boolean"),
 ])
 def test_malformed_values_raise_engine_error(monkeypatch, name, bad, match):
     monkeypatch.setenv(name, bad)

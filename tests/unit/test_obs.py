@@ -182,8 +182,9 @@ def test_stream_is_ordered_and_schema_valid_from_threads(tmp_path):
     assert schema_mod.validate_lines(lines) == []  # seq/ts order included
     events = _events(path)
     assert [e["seq"] for e in events] == list(range(len(events)))
-    # manifest + spam + sampler watermark (obs v2) + metrics/run_end
-    assert len(events) == 1 + 4 * 200 + 3
+    # manifest + spam + sampler watermark (obs v2) + metrics/run_end + the
+    # stream's own two spans (obs_open, obs_close)
+    assert len(events) == 1 + 4 * 200 + 3 + 2
 
 
 def test_end_run_snapshots_metrics(tmp_path):

@@ -276,6 +276,37 @@ def test_bottleneck_cli_and_span_fallback(tmp_path, capsys):
     assert b["limiting_stage"] == "ingest"
 
 
+def test_bottleneck_prints_a_familys_on_cpu_share_beside_its_work(tmp_path,
+                                                                 capsys):
+    """The operator's reading of the spans' ``cpu_s``: of a family's work,
+    the share its threads spent on a CPU. A family no span fed has none."""
+    run, path = _open_run(tmp_path, name="oncpu.jsonl")
+    prof = profile_mod.StageProfiler()
+    prof.stage("parse.w0", layer="ingest").add_work(2.0, cpu=0.5, records=50)
+    prof.stage("parse.w1", layer="ingest").add_work(2.0, cpu=0.7, records=50)
+    prof.stage("score_stage.w0", layer="wait").add_work(3.0, cpu=0.3, records=100)
+    prof.stage("dispatch_wait.w0", parent="score_stage",
+               layer="wait").add_work(2.0, cpu=0.01)
+    prof.stage("generic").add_work(1.0)  # the executor's: a wall only
+    prof.emit(wall_s=4.0, records=100)
+    obs.end_run(run, "ok")
+    b = export_mod.bottleneck(export_mod.read_run(path))
+    parse, score = b["stages"]["parse"], b["stages"]["score_stage"]
+    assert (parse["work_pct"], parse["on_cpu_pct"], parse["cpu_s"]) == (50.0, 30.0, 1.2)
+    assert score["on_cpu_pct"] == 10.0
+    assert score["children"]["dispatch_wait"]["on_cpu_pct"] == 0.5
+    assert "on_cpu_pct" not in b["stages"]["generic"]
+    assert obs_cli.run(["bottleneck", str(path)]) == 0
+    text = capsys.readouterr().out
+    header, = [ln for ln in text.splitlines() if "work%" in ln]
+    assert header.split()[:3] == ["stage", "work%", "on-cpu%"]
+    row, = [ln for ln in text.splitlines() if ln.strip().startswith("parse x2")]
+    assert row.split()[2:4] == ["50.0", "30.0"]
+    generic, = [ln for ln in text.splitlines() if ln.strip().startswith("generic")]
+    assert generic.split()[2] == "-"
+    assert "(66.7% of score_stage's work, 0.5% of it on CPU)" in text
+
+
 # ---------------------------------------------------------------------------
 # the real streaming executor feeds the profiler
 # ---------------------------------------------------------------------------

@@ -1,23 +1,28 @@
 """The ONE span primitive (``utils.trace.stage``) and what hangs on it:
 off it is a bool check; on it is one ``span`` event with ``start`` /
-``parent`` / ``trace_id``, one attribution row with its parent, a
-``vctpu:<name>`` annotation on the profiler trace's clock; the score
-stage's parts are its children and add up to no more than it; the
-predictor cache and JAX's compiles are counted at their boundaries."""
+``parent`` / ``trace_id`` / ``cpu`` / ``layer``, one attribution row with
+its parent, its layer and its on-CPU seconds, a ``vctpu:<name>`` annotation
+on the profiler trace's clock; the score stage's parts are its children and
+add up to no more than it; every span name has a layer in ONE table; a
+run's head and tail are under spans; the predictor cache and JAX's compiles
+are counted at their boundaries."""
 
 from __future__ import annotations
 
 import argparse
+import ast
 import glob
 import json
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
 
 from variantcalling_tpu import obs
 from variantcalling_tpu.obs import export as export_mod
+from variantcalling_tpu.obs import layers as layers_mod
 from variantcalling_tpu.obs import profile as profile_mod
 from variantcalling_tpu.utils import trace
 
@@ -38,6 +43,13 @@ def _open_run(tmp_path, name="run.jsonl"):
 def _events(path):
     with open(path, encoding="utf-8") as fh:
         return [json.loads(ln) for ln in fh if ln.strip()]
+
+
+def _spans(events):
+    """The program's span events: all but the stream's own open and close
+    (``obs_open`` / ``obs_close``, which every stream carries)."""
+    return [e for e in events if e["kind"] == "span"
+            and e.get("layer") != "tracing"]
 
 
 class _CountingAnnotation:
@@ -92,7 +104,7 @@ def test_stage_on_emits_one_span_with_start_parent_trace(tmp_path):
     prof.emit(wall_s=1.0, records=100)
     obs.end_run(run, "ok")
     ev = _events(path)
-    spans = [e for e in ev if e["kind"] == "span"]
+    spans = _spans(ev)
     assert [e["name"] for e in spans] == ["dispatch_wait", "score_stage"]
     inner, outer = spans
     for e in spans:
@@ -123,7 +135,7 @@ def test_stage_causal_feeds_the_trace_span_from_the_same_measurement(tmp_path):
         pass
     obs.end_run(run, "ok")
     ev = _events(path)
-    span = next(e for e in ev if e["kind"] == "span")
+    span, = _spans(ev)
     causal = next(e for e in ev if e["kind"] == "trace")
     assert causal["name"] == "writeback" and causal["trace_id"] == tid
     assert causal["dur"] == span["dur"] == round(sp.seconds, 6)
@@ -139,8 +151,100 @@ def test_failed_body_records_nothing_and_unwinds(tmp_path):
     with trace.stage("score_stage"):
         pass
     obs.end_run(run, "ok")
-    spans = [e for e in _events(path) if e["kind"] == "span"]
+    spans = _spans(_events(path))
     assert len(spans) == 1 and "parent" not in spans[0]
+
+
+def _sleep():
+    time.sleep(0.15)
+
+
+def _spin():
+    """A tenth of a second of this thread's OWN CPU time (however long the
+    machine takes to grant it: the suite runs beside five other workers)."""
+    t_end = time.thread_time() + 0.1
+    while time.thread_time() < t_end:
+        pass
+
+
+def test_a_sleeping_span_has_its_cpu_far_under_its_wall(tmp_path):
+    run, path = _open_run(tmp_path)
+    with trace.stage("parse"):
+        _sleep()
+    obs.end_run(run, "ok")
+    span, = _spans(_events(path))
+    assert span["dur"] >= 0.15 and 0 <= span["cpu"] <= 0.2 * span["dur"]
+
+
+def test_a_spinning_span_has_its_cpu_within_a_fifth_of_its_wall(tmp_path):
+    """``cpu`` is the thread's own CPU clock around the body. On a machine
+    that grants the thread a core, a body that spins reads within a fifth of
+    its wall; one of five tries finds such a moment."""
+    run, path = _open_run(tmp_path)
+    for _ in range(5):
+        with trace.stage("parse") as sp:
+            _spin()
+        if sp.seconds <= 0.12:
+            break  # the machine gave the thread its core
+    obs.end_run(run, "ok")
+    spans = _spans(_events(path))
+    for e in spans:  # what the body burnt, and not a hundredth more
+        assert 0.1 <= e["cpu"] <= 0.11 and e["cpu"] <= e["dur"] + 1e-3
+    assert max(e["cpu"] / e["dur"] for e in spans) >= 0.8
+
+
+def test_a_rows_cpu_s_is_the_sum_of_its_spans(tmp_path):
+    run, path = _open_run(tmp_path)
+    prof = profile_mod.StageProfiler()
+    with obs.bind_profiler(prof):
+        for body in (_sleep, _spin, _spin):
+            with trace.stage("render_stage"):
+                body()
+        with pytest.raises(ValueError):  # a failed body records neither
+            with trace.stage("render_stage"):
+                _spin()
+                raise ValueError("poison")
+        with pytest.raises(ValueError):
+            with trace.stage("compress_stage"):
+                raise ValueError("poison")
+    prof.emit(wall_s=1.0)
+    obs.end_run(run, "ok")
+    ev = _events(path)
+    spans = _spans(ev)
+    assert [e["name"] for e in spans] == ["render_stage"] * 3
+    rows = {e["stage"]: e for e in ev
+            if e["kind"] == "profile" and e["name"] == "stage"}
+    assert set(rows) == {"render_stage"}  # the failed compress left no row
+    row = rows["render_stage"]
+    assert row["items"] == 3 and row["layer"] == "render"
+    assert row["work_s"] == pytest.approx(sum(e["dur"] for e in spans), abs=1e-5)
+    assert row["cpu_s"] == pytest.approx(sum(e["cpu"] for e in spans), abs=1e-5)
+    # two spun a tenth of a second of CPU each, one slept 0.15 s of wall
+    assert 0.2 <= row["cpu_s"] <= row["work_s"] - 0.14
+
+
+def test_a_row_no_span_fed_says_nothing_of_cpu(tmp_path):
+    """The executor's generic stages measure a wall only: their rows carry
+    no ``cpu_s`` (0 would read as "all of it waiting")."""
+    run, path = _open_run(tmp_path)
+    prof = profile_mod.StageProfiler()
+    prof.stage("generic").add_work(0.5)
+    # rows the executor made, fed by a span later: one that reads the CPU
+    # clock and one that does not (``obs.layers.CPU_SPANS``)
+    prof.stage("compress_stage")
+    prof.stage("writeback")
+    with obs.bind_profiler(prof):
+        for name in ("compress_stage", "writeback"):
+            with trace.stage(name):
+                pass
+    prof.emit(wall_s=1.0)
+    obs.end_run(run, "ok")
+    rows = {e["stage"]: e for e in _events(path)
+            if e["kind"] == "profile" and e["name"] == "stage"}
+    assert "cpu_s" not in rows["generic"] and "layer" not in rows["generic"]
+    assert rows["writeback"]["layer"] == "commit" and "cpu_s" not in rows["writeback"]
+    assert rows["compress_stage"]["layer"] == "commit" \
+        and rows["compress_stage"]["cpu_s"] >= 0
 
 
 def test_span_table_is_the_runs_and_bounded(tmp_path, monkeypatch):
@@ -181,6 +285,51 @@ def test_bottleneck_lists_children_under_parents_and_ranks_neither_twice():
     text = export_mod.render_bottleneck(b)
     assert "- dispatch_wait: 6.500s (81.2% of score_stage's work)" in text
     assert "cost_analysis" not in text and "cost_analysis" not in b
+
+
+# ---------------------------------------------------------------------------
+# one table of layers, in the program
+# ---------------------------------------------------------------------------
+
+
+def _span_names_in_the_package():
+    """Every literal name handed to ``stage(...)`` / ``timed(name=...)`` (and
+    to obs's own ``_SelfSpan``) under ``variantcalling_tpu/``: {name: where}."""
+    import variantcalling_tpu
+
+    root = os.path.dirname(variantcalling_tpu.__file__)
+    found: dict = {}
+    for path in glob.glob(os.path.join(root, "**", "*.py"), recursive=True):
+        with open(path, encoding="utf-8") as fh:
+            tree = ast.parse(fh.read(), path)
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
+            fn = node.func
+            called = fn.attr if isinstance(fn, ast.Attribute) else \
+                fn.id if isinstance(fn, ast.Name) else None
+            arg = None
+            if called in ("stage", "_SelfSpan") and node.args:
+                arg = node.args[0]
+            elif called == "timed":
+                arg = next((k.value for k in node.keywords if k.arg == "name"),
+                           None)
+            if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
+                found.setdefault(arg.value, f"{os.path.relpath(path, root)}:"
+                                            f"{node.lineno}")
+    return found
+
+
+def test_every_span_name_in_the_package_has_a_layer():
+    found = _span_names_in_the_package()
+    # the walk sees what it should: spans of every kind of call site
+    assert {"parse", "host_featurize", "stream_open", "commit", "obs_open",
+            "serve_request", "genome_upload"} <= set(found)
+    missing = {n: at for n, at in found.items() if n not in trace.LAYER_OF}
+    assert not missing, f"span names with no layer in trace.LAYER_OF: {missing}"
+    # and the table names nothing that is gone
+    assert set(trace.LAYER_OF) <= set(found), set(trace.LAYER_OF) - set(found)
+    assert trace.LAYER_OF["dispatch_wait"] == trace.LAYER_OF["stream"] == "wait"
 
 
 # ---------------------------------------------------------------------------
@@ -266,8 +415,9 @@ def test_streaming_run_emits_every_span_once_a_chunk(streamed):
     stages = {e["trace_id"]: e for e in by_name["score_stage"]}
     assert len(stages) == chunks
     inside = {tid: 0.0 for tid in stages}
-    for name in SCORE_PARTS:
-        for e in by_name[name]:
+    # (a process's first wave also probes the backend, once a platform)
+    for name in SCORE_PARTS + ("backend_probe",):
+        for e in by_name.get(name, ()):
             st = stages[e["trace_id"]]
             assert e["parent"] == "score_stage" and e["thread"] == st["thread"]
             assert e["start"] >= st["start"] - 1e-6
@@ -307,6 +457,32 @@ def test_streaming_run_emits_every_span_once_a_chunk(streamed):
     assert built.count(True) == 1  # single flight: the workers share one
 
 
+def test_every_span_event_and_row_of_a_streamed_run_carries_its_layer(streamed):
+    ev = streamed["events"]
+    spans = [e for e in ev if e["kind"] == "span"]
+    assert len(spans) > 20
+    for e in spans:
+        assert e.get("layer") == trace.LAYER_OF[e["name"]], e
+    # a span named in CPU_SPANS (the families a metric or the bottleneck
+    # column reads on-CPU shares of) carries its thread's on-CPU seconds,
+    # within its wall (the clocks differ: a hundredth of slack); no other
+    # span reads that clock
+    assert layers_mod.CPU_SPANS <= set(trace.LAYER_OF)
+    for e in spans:
+        assert ("cpu" in e) == (e["name"] in layers_mod.CPU_SPANS), e
+        if "cpu" in e:
+            assert 0 <= e["cpu"] <= e["dur"] + 0.01, e
+    assert {e["name"] for e in spans if "cpu" in e} == {"parse", "render_stage"}
+    for r in (e for e in ev if e["kind"] == "profile" and e["name"] == "stage"):
+        base = r["stage"].split(".")[0]
+        if base in trace.LAYER_OF and "cpu_s" in r:
+            assert r["layer"] == trace.LAYER_OF[base], r
+            assert 0 <= r["cpu_s"] <= r["work_s"] + 0.01 * max(1, r["items"]), r
+    families = {r["stage"].split(".")[0] for r in ev
+                if r["kind"] == "profile" and r["name"] == "stage" and "cpu_s" in r}
+    assert families == {"parse", "render_stage"}
+
+
 def test_profiler_trace_holds_a_vctpu_event_for_each_span(streamed):
     from jax.profiler import ProfileData
 
@@ -344,6 +520,134 @@ def test_output_bytes_equal_with_obs_on_and_off(streamed, world, tmp_path):
     with open(off, "rb") as a, open(streamed["out"], "rb") as b:
         assert a.read() == b.read()
     assert not os.path.exists(off + ".obs.jsonl")
+
+
+# ---------------------------------------------------------------------------
+# a run's head and tail are under spans (the CLI entry, plain and .vcf.gz)
+# ---------------------------------------------------------------------------
+
+HEAD_AND_TAIL = ("obs_open", "run_open", "stream_open", "stream_close",
+                 "commit", "obs_close")
+
+
+@pytest.fixture(scope="module")
+def cli_world(tmp_path_factory):
+    import pickle
+
+    from variantcalling_tpu.io import bgzf as bgzf_mod
+    from variantcalling_tpu.synthetic import make_fixtures_fast, synthetic_forest
+
+    d = str(tmp_path_factory.mktemp("obs_head_tail"))
+    make_fixtures_fast(d, n=6000, genome_len=400_000, n_contigs=4)
+    with open(f"{d}/calls.vcf", "rb") as fh, \
+            bgzf_mod.BgzfWriter(f"{d}/calls.vcf.gz") as w:
+        w.write(fh.read())
+    with open(f"{d}/model.pkl", "wb") as fh:
+        pickle.dump({"m": synthetic_forest(np.random.default_rng(0),
+                                           n_trees=8, depth=4)}, fh)
+    return d
+
+
+def _cli(cli_world, monkeypatch, suffix, out, obs_on):
+    from variantcalling_tpu import engine as engine_mod
+    from variantcalling_tpu.io import vcf as vcf_mod
+    from variantcalling_tpu.pipelines.filter_variants import run as fvp_run
+
+    if not pytest.importorskip("variantcalling_tpu.native").available():
+        pytest.skip("streaming (chunked ingest) needs the native library")
+    monkeypatch.setattr(vcf_mod, "STREAM_CHUNK_BYTES", 1 << 16)
+    monkeypatch.setenv("VCTPU_IO_THREADS", "2")
+    monkeypatch.setenv("VCTPU_IO_SHARD_BYTES", str(1 << 17))
+    monkeypatch.setenv("VCTPU_OBS", "1" if obs_on else "0")
+    try:
+        rc = fvp_run(["--input_file", f"{cli_world}/calls{suffix}",
+                      "--model_file", f"{cli_world}/model.pkl",
+                      "--model_name", "m",
+                      "--reference_file", f"{cli_world}/ref.fa",
+                      "--output_file", out])
+    finally:
+        engine_mod.reset_for_tests()
+    assert rc == 0
+    return _events(out + ".obs.jsonl") if obs_on else None
+
+
+@pytest.mark.parametrize("suffix", [".vcf", ".vcf.gz"], ids=["plain", "vcfgz"])
+def test_a_cli_run_puts_its_head_and_tail_under_spans(cli_world, monkeypatch,
+                                                      tmp_path, suffix):
+    out = str(tmp_path / ("on" + suffix))
+    ev = _cli(cli_world, monkeypatch, suffix, out, obs_on=True)
+    spans = [e for e in ev if e["kind"] == "span"]
+    mine = [e for e in spans if e["name"] in HEAD_AND_TAIL]
+    # once each, in this order (file order is close order), on one thread
+    assert [e["name"] for e in mine] == list(HEAD_AND_TAIL)
+    assert {e["thread"] for e in mine} == {"MainThread"}
+    eps = 1e-4
+
+    def end(e):
+        return e["start"] + e["dur"]
+
+    for a, b in zip(mine, mine[1:]):
+        assert end(a) <= b["start"] + eps, (a, b)  # none overlaps the next
+    by = {e["name"]: e for e in mine}
+    writes = [e for e in spans if e["name"] == "writeback"]
+    assert writes
+    for w in writes:  # nor a writeback: they lie between the open and the close
+        assert end(by["stream_open"]) <= w["start"] + eps
+        assert end(w) <= by["stream_close"]["start"] + eps
+    # the committer's own work between two writebacks has a name, once a
+    # journaled chunk (a .gz output keeps no journal)
+    appends = [e for e in spans if e["name"] == "journal_append"]
+    assert len(appends) == (len(writes) if suffix == ".vcf" else 0)
+    for w, j in zip(writes, appends):
+        assert end(w) <= j["start"] + eps and j["layer"] == "commit"
+    # and so has what obs itself adds to the file
+    assert [e["name"] for e in spans if e["layer"] == "tracing"] == [
+        "obs_open", "profile_emit", "obs_close"]
+    stream, = [e for e in spans if e["name"] == "stream"]
+    assert by["stream_open"]["parent"] == by["commit"]["parent"] == "stream"
+    assert 0 <= by["stream_open"]["start"] - stream["start"] < 0.05
+    assert end(by["commit"]) <= end(stream) + eps
+    assert end(by["run_open"]) <= stream["start"] + eps
+    assert by["commit"]["records"] == 6000 and by["commit"]["chunks"] >= 2
+    # the stream's own close is its last event before the end
+    assert [e["name"] for e in ev[-3:]] == ["final", "obs_close", ev[-1]["name"]]
+    assert ev[-1]["kind"] == "run_end"
+    if suffix == ".vcf.gz":  # the commit ends before the index is written
+        index, = [e for e in spans if e["name"] == "tabix_index" and "bytes" in e]
+        assert end(by["commit"]) <= index["start"] + eps
+        assert os.path.exists(out + ".tbi")
+    # the pipeline's wall holds the tail: the rows are on the profile
+    rows = {e["stage"] for e in ev if e["kind"] == "profile" and e["name"] == "stage"}
+    assert {"stream_open", "stream_close", "commit"} <= rows
+    # and the bytes are the bytes of a run with obs off
+    off = str(tmp_path / ("off" + suffix))
+    assert _cli(cli_world, monkeypatch, suffix, off, obs_on=False) is None
+    assert not os.path.exists(off + ".obs.jsonl")
+    with open(out, "rb") as a, open(off, "rb") as b:
+        assert a.read() == b.read()
+
+
+def test_a_request_through_run_loaded_emits_the_middle_three_only(
+        world, jit_engine, tmp_path):
+    """A daemon's request enters at ``run_loaded`` with its model and genome
+    resident, inside the daemon's one obs run: no ``run_open``, no stream
+    opened or closed for it."""
+    from variantcalling_tpu.pipelines.filter_variants import run_loaded
+
+    run, path = _open_run(tmp_path)
+    rc = run_loaded(_args(world, str(tmp_path / "req.vcf")), world["model"],
+                    world["fasta"], {}, None)
+    assert rc == 0
+    obs.end_run(run, "ok")
+    spans = _spans(_events(path))  # all but the test's own stream's two
+    mine = [e["name"] for e in spans if e["name"] in HEAD_AND_TAIL]
+    assert mine == ["stream_open", "stream_close", "commit"]
+    stream, = [e for e in spans if e["name"] == "stream"]
+    for e in spans:
+        if e["name"] in mine:
+            assert e["parent"] == "stream"
+            assert stream["start"] - 1e-6 <= e["start"]
+            assert e["start"] + e["dur"] <= stream["start"] + stream["dur"] + 1e-6
 
 
 # ---------------------------------------------------------------------------

@@ -33,7 +33,8 @@ GENOME_LEN, N_VARIANTS, SEED = 240_000, 480, 11
 STAGE_FAMILIES = {"ingest", "parse", "score_stage", "host_featurize",
                   "prepare_inputs", "fused_program", "dispatch_feed",
                   "dispatch_enqueue", "dispatch_wait", "score_finalize",
-                  "render_stage", "writeback"}
+                  "render_stage", "writeback",
+                  "stream_open", "stream_close", "commit", "journal_append"}
 _WATCHED: list[str] = []
 
 

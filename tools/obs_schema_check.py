@@ -171,6 +171,8 @@ def main() -> int:
             with trace.stage("score_stage", records=128):
                 with trace.stage("dispatch_wait"):
                     pass
+            with trace.stage("render_stage", records=128):
+                pass
         prof.emit(wall_s=0.01, records=128)
         # one backend compile seen by the jax.monitoring listener
         obs._on_jax_duration(obs.JAX_BACKEND_COMPILE_EVENT, 0.25,
@@ -247,11 +249,29 @@ def main() -> int:
                 or "start" not in inner[0]:
             errors.append("the nested trace.stage span lacks start / "
                           f"parent / trace_id: {inner}")
+        # ... with its layer (obs.layers.LAYER_OF) and, on a span named in
+        # CPU_SPANS, its thread's on-CPU seconds
+        render = [e for e in parsed if e["kind"] == "span"
+                  and e["name"] == "render_stage"]
+        if not inner or inner[0].get("layer") != "wait" or "cpu" in inner[0] \
+                or not render or render[0].get("layer") != "render" \
+                or not 0 <= render[0].get("cpu", -1) <= render[0]["dur"] + 0.01:
+            errors.append("a trace.stage span lacks its layer, or a render "
+                          "span an on-CPU time within its wall, or a wait "
+                          f"span carries one: {inner} {render}")
         rows = {e.get("stage"): e for e in parsed if e["kind"] == "profile"
                 and e["name"] == "stage"}
         if rows.get("dispatch_wait", {}).get("parent") != "score_stage":
             errors.append("the child span's profile/stage row names no "
                           "parent")
+        if rows.get("dispatch_wait", {}).get("layer") != "wait" \
+                or "cpu_s" not in rows.get("render_stage", {}):
+            errors.append("the span's profile/stage row lacks layer / cpu_s")
+        own = [e["name"] for e in parsed if e["kind"] == "span"
+               and e.get("layer") == "tracing"]
+        if own != ["obs_open", "obs_close"]:
+            errors.append("the stream's own open and close are not the "
+                          f"spans obs_open, obs_close: {own}")
         compiles = [e for e in parsed if e["kind"] == "profile"
                     and e["name"] == "backend_compile"]
         if not compiles or not {"span", "thread", "dur"} <= set(compiles[0]):

@@ -333,8 +333,6 @@ REGISTRY: dict[str, Knob] = {k.name: k for k in (
        "Prometheus textfile-collector path: every periodic snapshot "
        "atomically rewrites this file with the text exposition "
        "(vctpu obs prom is the offline sibling)"),
-    _k("VCTPU_TRACE", "bool", False,
-       "print every closed trace span at INFO level"),
     _k("VCTPU_FAULTS", "str", "",
        "fault-injection spec, e.g. io.chunk_read:2,pipeline.stage_hang@30 "
        "(utils/faults.py)"),

@@ -82,6 +82,7 @@ import threading
 import time
 
 from variantcalling_tpu import knobs, logger
+from variantcalling_tpu.obs.layers import LAYER_OF
 from variantcalling_tpu.obs.metrics import NOOP, MetricsRegistry
 from variantcalling_tpu.obs.schema import SCHEMA_VERSION
 
@@ -141,7 +142,7 @@ def active() -> bool:
 class ObsRun:
     """One open run stream: file handle, ordered event writer, metrics."""
 
-    def __init__(self, path: str, tool: str):
+    def __init__(self, path: str, tool: str, opened_at: float | None = None):
         self.path = path
         self.tool = tool
         self.metrics = MetricsRegistry(window_s=knobs.get_float(WINDOW_ENV))
@@ -186,9 +187,12 @@ class ObsRun:
         self._since_flush = 0
         # ts is derived from ONE wall anchor plus the monotonic clock so
         # the stream's timestamps can never move backwards (NTP steps the
-        # wall clock; perf_counter does not step)
-        self._t0_wall = time.time()
-        self._t0_mono = time.perf_counter()
+        # wall clock; perf_counter does not step). The clock's zero is
+        # ``opened_at`` where the opener took one (a perf_counter reading
+        # at the start of ``obs_open``, so that span starts at 0)
+        now = time.perf_counter()
+        self._t0_mono = now if opened_at is None else opened_at
+        self._t0_wall = time.time() - (now - self._t0_mono)
 
     def now(self) -> float:
         """The stream's ``t`` clock: seconds since the run opened (a span's
@@ -292,16 +296,43 @@ class ObsRun:
             degrade.record("obs.prom_write", e,
                            fallback="Prometheus textfile skipped")
 
-    def close(self, status: str) -> None:
+    def close(self, status: str, closing: "_SelfSpan | None" = None) -> None:
         self._closing = True  # run_end must be the stream's last event
         with self._lock:
             dur = self.now()
         snap = self.metrics.snapshot()
         self._emit("metrics", "final", snap)
+        if closing is not None:
+            closing.emit(self)  # obs_close: what closing cost up to here
         self._emit("run_end", self.tool, {"status": status,
                                           "dur": round(dur, 6)}, flush=True)
         self._fh.close()
         self._write_prom(snap, in_flight=False)
+
+
+class _SelfSpan:
+    """What opening and closing a run stream costs, as a span like any
+    other (``obs_open`` / ``obs_close``, layer ``tracing``): a ``vctpu:``
+    profiler annotation around all of it and one ``span`` event, so that
+    what tracing adds to a traced file is a number. ``utils.trace.stage``
+    cannot do it: it needs the open run these two make and unmake."""
+
+    __slots__ = ("name", "thread", "t0", "_ann")
+
+    def __init__(self, name: str):
+        self.name = name
+        self.thread = threading.current_thread().name
+        self._ann = annotate(name, trace="", thread=self.thread)
+        self.t0 = time.perf_counter()
+
+    def emit(self, run: ObsRun) -> None:
+        run._emit("span", self.name, span_body(
+            self.name, self.t0 - run._t0_mono, time.perf_counter() - self.t0,
+            self.thread, 0, {}))
+
+    def end(self) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
 
 
 def _rank_suffixed(path: str) -> str:
@@ -328,7 +359,6 @@ def start_run(tool: str, default_path: str | None = None,
     ``force_path`` bypasses the ``VCTPU_OBS`` gate — for the tier-0
     schema check and tests that must record regardless of environment.
     """
-    global _ACTIVE, _RUN, _TRACING
     if force_path is None and not enabled():
         return None
     with _LOCK:
@@ -337,41 +367,53 @@ def start_run(tool: str, default_path: str | None = None,
         path = force_path or knobs.get_str(OBS_PATH_ENV) or default_path
         if not path:
             return None  # nowhere to write (no output file context)
-        path = _rank_suffixed(path)
-        from variantcalling_tpu.obs.manifest import build_manifest
-
+        opening = _SelfSpan("obs_open")
         try:
-            run = ObsRun(path, tool)
-        except OSError as e:
-            logger.warning("obs: cannot open run log %s: %s — recording "
-                           "disabled for this run", path, e)
-            return None
-        run._emit("manifest", tool, build_manifest(tool, argv=argv,
-                                                   inputs=inputs), flush=True)
-        _RUN = run
-        _ACTIVE = True
-        _TRACING = run.tracing
-        _register_flush_handlers()
-        if _register_jax_listener():
-            # declared up front: a snapshot that reads 0 says "JAX compiled
-            # nothing in this run", an absent counter "nobody was counting"
-            for name in JAX_COUNTERS:
-                run.metrics.counter(name)
-        if knobs.get_bool(profile_mod().PROFILE_ENV):
-            # RSS/CPU watermark sampler (obs v2): daemon thread, stopped
-            # (and its watermark event emitted) by end_run
-            run.sampler = profile_mod().ResourceSampler(run)
-            run.sampler.start()
-        if knobs.get_bool(sampler_mod().CPUPROF_ENV):
-            # continuous CPU sampling profiler (obs v3): daemon thread
-            # folding whole-process stack samples into the stream;
-            # stopped (final flush + cpuprof summary event) by end_run
-            run.cpu_sampler = sampler_mod().CpuSampler(run)
-            run.cpu_sampler.start()
-        if knobs.get_bool(JAXPROF_ENV):
-            _start_jaxprof(run)
-        logger.info("obs: recording run telemetry to %s", path)
-        return run
+            return _open_run(tool, _rank_suffixed(path), argv, inputs, opening)
+        finally:
+            opening.end()
+
+
+def _open_run(tool: str, path: str, argv, inputs,
+              opening: _SelfSpan) -> ObsRun | None:
+    """:func:`start_run`'s body, under its ``obs_open`` span and the
+    module lock."""
+    global _ACTIVE, _RUN, _TRACING
+    from variantcalling_tpu.obs.manifest import build_manifest
+
+    try:
+        run = ObsRun(path, tool, opened_at=opening.t0)
+    except OSError as e:
+        logger.warning("obs: cannot open run log %s: %s — recording "
+                       "disabled for this run", path, e)
+        return None
+    run._emit("manifest", tool, build_manifest(tool, argv=argv,
+                                               inputs=inputs), flush=True)
+    _RUN = run
+    _ACTIVE = True
+    _TRACING = run.tracing
+    _register_flush_handlers()
+    if _register_jax_listener():
+        # declared up front: a snapshot that reads 0 says "JAX compiled
+        # nothing in this run", an absent counter "nobody was counting"
+        for name in JAX_COUNTERS:
+            run.metrics.counter(name)
+    if knobs.get_bool(profile_mod().PROFILE_ENV):
+        # RSS/CPU watermark sampler (obs v2): daemon thread, stopped
+        # (and its watermark event emitted) by end_run
+        run.sampler = profile_mod().ResourceSampler(run)
+        run.sampler.start()
+    if knobs.get_bool(sampler_mod().CPUPROF_ENV):
+        # continuous CPU sampling profiler (obs v3): daemon thread
+        # folding whole-process stack samples into the stream;
+        # stopped (final flush + cpuprof summary event) by end_run
+        run.cpu_sampler = sampler_mod().CpuSampler(run)
+        run.cpu_sampler.start()
+    if knobs.get_bool(JAXPROF_ENV):
+        _start_jaxprof(run)
+    logger.info("obs: recording run telemetry to %s", path)
+    opening.emit(run)
+    return run
 
 
 def end_run(run: ObsRun | None, status: str = "ok") -> None:
@@ -383,6 +425,7 @@ def end_run(run: ObsRun | None, status: str = "ok") -> None:
     with _LOCK:
         if _RUN is not run:
             return
+        closing = _SelfSpan("obs_close")
         # attachments stop while the stream still accepts events (the
         # samplers' summary events must precede the metrics snapshot)
         if run.cpu_sampler is not None:
@@ -403,9 +446,11 @@ def end_run(run: ObsRun | None, status: str = "ok") -> None:
         _TRACING = False
         _RUN = None
     try:
-        run.close(status)
+        run.close(status, closing)
     except OSError as e:  # a full disk must not mask the run's own error
         logger.warning("obs: failed to finalize run log %s: %s", run.path, e)
+    finally:
+        closing.end()
 
 
 def profile_mod():
@@ -605,6 +650,39 @@ def event(kind: str, name: str, **fields) -> None:
         run._emit(kind, name, fields)
 
 
+#: the profiler-trace name prefix of every program span (the benchmark's
+#: ``program_spans.py`` finds them by it)
+ANNOTATION_PREFIX = "vctpu:"
+
+
+def annotate(name: str, **stats):
+    """An ENTERED ``jax.profiler.TraceAnnotation("vctpu:<name>", **stats)``
+    for the caller to ``__exit__``: the span on the device trace's clock.
+    None where jax is not loaded (never the reason it gets imported)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **stats)
+    ann.__enter__()
+    return ann
+
+
+def span_body(name: str, start: float, dur: float, thread: str, depth: int,
+              fields: dict, cpu: float | None = None) -> dict:
+    """The body of a ``span`` event, in its one spelling (``trace.stage``'s
+    spans, the executor's generic stages and the stream's own ``obs_open`` /
+    ``obs_close``): ``start`` on the stream's ``t`` clock, the name's layer
+    (``obs.layers.LAYER_OF``) where it has one, ``cpu`` where it was read."""
+    body = dict(fields, start=round(start, 6), dur=round(dur, 6),
+                thread=thread, depth=depth)
+    if cpu is not None:
+        body["cpu"] = round(cpu, 6)
+    layer = LAYER_OF.get(name)
+    if layer is not None:
+        body["layer"] = layer
+    return body
+
+
 def span(name: str, dur: float, thread: str, depth: int = 0, **fields) -> None:
     """Record one closed wall-clock span measured by the caller (the
     stage executor's generic stages and queue waits; everything else goes
@@ -617,9 +695,8 @@ def span(name: str, dur: float, thread: str, depth: int = 0, **fields) -> None:
         request = _REQUEST.get()
         if request is not None and request.root is not None and not depth:
             fields.setdefault("parent", request.root)
-        run._emit("span", name, dict(fields, start=round(max(0.0, run.now() - dur), 6),
-                                     dur=round(dur, 6), thread=thread,
-                                     depth=depth))
+        run._emit("span", name, span_body(name, max(0.0, run.now() - dur), dur,
+                                          thread, depth, fields))
 
 
 # -- causal chunk tracing (docs/observability.md "Causal chunk tracing") ---

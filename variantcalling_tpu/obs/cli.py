@@ -62,8 +62,10 @@ def get_parser() -> argparse.ArgumentParser:
                       help="emit the summary as JSON")
 
     bott = sub.add_parser("bottleneck",
-                          help="per-stage work/wait attribution: name the "
-                               "limiting stage (obs v2 profile events)")
+                          help="per-stage work/wait attribution, with the "
+                               "share of each stage's work spent on a CPU: "
+                               "name the limiting stage (obs v2 profile "
+                               "events)")
     bott.add_argument("log", help="obs run log (JSONL)")
     bott.add_argument("--json", action="store_true",
                       help="emit the attribution as JSON")

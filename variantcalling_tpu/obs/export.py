@@ -379,7 +379,11 @@ def bottleneck(events: list[dict]) -> dict:
     depth-0 trace spans — work attribution only, waits unknown. Every
     stage's ``work/wait_in/wait_out/other`` percentages sum to ~100% of
     the pipeline wall clock (``other`` = the stage thread's untracked
-    time: startup, teardown, span bookkeeping). The limiting stage is
+    time: startup, teardown, span bookkeeping). ``on_cpu_pct`` is the
+    share of a family's work its threads spent on a CPU (the rows'
+    ``cpu_s``; absent where no ``trace.stage`` span fed the family): a
+    large work share with a small on-CPU share is a stage that waits — for
+    the interpreter, a lock, the device, the disk. The limiting stage is
     the one with the largest work share — in a pipelined executor its
     work IS the wall clock floor, so it is the stage ROADMAP item 1 must
     shrink.
@@ -422,6 +426,12 @@ def bottleneck(events: list[dict]) -> dict:
             if e.get("parent"):
                 s["parent"] = e["parent"]
             s["work_s"] += float(e.get("work_s", 0.0))
+            if "cpu_s" in e:
+                # rows a trace.stage span fed: the on-CPU part of their
+                # work, and the work it is a part of
+                s["cpu_s"] = s.get("cpu_s", 0.0) + float(e["cpu_s"])
+                s["_timed_s"] = s.get("_timed_s", 0.0) \
+                    + float(e.get("work_s", 0.0))
             s["wait_in_s"] += float(e.get("wait_in_s", 0.0))
             s["wait_out_s"] += float(e.get("wait_out_s", 0.0))
             s["items"] += int(e.get("items", 0))
@@ -458,6 +468,12 @@ def bottleneck(events: list[dict]) -> dict:
             s[f"{key}_pct"] = round(100.0 * s[f"{key}_s"] / capacity, 1) \
                 if capacity > 0 else 0.0
             s[f"{key}_s"] = round(s[f"{key}_s"], 6)
+        timed = s.pop("_timed_s", 0.0)
+        if timed > 0:
+            # the share of the family's work its threads spent ON a CPU;
+            # the rest of it they waited (interpreter, lock, device, disk)
+            s["cpu_s"] = round(s["cpu_s"], 6)
+            s["on_cpu_pct"] = round(100.0 * s["cpu_s"] / timed, 1)
         n_rec = s.pop("stage_records", 0) or records
         if n_rec and s["work_s"] > 0:
             # standalone throughput: what the stage (all its workers
@@ -522,7 +538,8 @@ def render_bottleneck(b: dict) -> str:
 
         labels = {n: label(n, s) for n, s in b["stages"].items()}
         width = max(len(v) for v in labels.values())
-        lines.append(f"  {'stage':<{width}}  {'work%':>6} {'wait-in%':>8} "
+        lines.append(f"  {'stage':<{width}}  {'work%':>6} {'on-cpu%':>7} "
+                     f"{'wait-in%':>8} "
                      f"{'wait-out%':>9} {'other%':>6} {'work_s':>9} "
                      f"{'v/s-alone':>10}  bytes")
         for name, s in b["stages"].items():
@@ -533,6 +550,7 @@ def render_bottleneck(b: dict) -> str:
                 byt.append(f"{s['bytes_out'] / (1 << 20):.1f}MB out")
             lines.append(
                 f"  {labels[name]:<{width}}  {s['work_pct']:>6.1f} "
+                f"{s.get('on_cpu_pct', '-'):>7} "
                 f"{s['wait_in_pct']:>8.1f} {s['wait_out_pct']:>9.1f} "
                 f"{s['other_pct']:>6.1f} {s['work_s']:>9.3f} "
                 f"{s.get('vps', '-'):>10}  {' '.join(byt)}")
@@ -540,8 +558,10 @@ def render_bottleneck(b: dict) -> str:
                 # the parts of this stage's work, by the spans opened
                 # inside it; what they leave is the stage's own time
                 of = 100.0 * c["work_s"] / s["work_s"] if s["work_s"] else 0.0
+                on_cpu = f", {c['on_cpu_pct']}% of it on CPU" \
+                    if "on_cpu_pct" in c else ""
                 lines.append(f"    - {part}: {c['work_s']:.3f}s "
-                             f"({of:.1f}% of {name}'s work)")
+                             f"({of:.1f}% of {name}'s work{on_cpu})")
     if b["source"] == "spans":
         lines.append("(span fallback: work attribution only — rerun with "
                      "VCTPU_OBS=1 + profiling for wait attribution)")

@@ -68,16 +68,26 @@ class StageStats:
     so the fractions still sum to ~100% of wall.
     """
 
-    __slots__ = ("name", "parent", "work_s", "wait_in_s", "wait_out_s",
-                 "items", "records", "bytes_in", "bytes_out")
+    __slots__ = ("name", "parent", "layer", "work_s", "cpu_s", "wait_in_s",
+                 "wait_out_s", "items", "records", "bytes_in", "bytes_out")
 
-    def __init__(self, name: str, parent: str | None = None):
+    def __init__(self, name: str, parent: str | None = None,
+                 layer: str | None = None):
         self.name = name
         #: the enclosing ``trace.stage`` span's name, for a row that is a
         #: PART of another row's work (``host_featurize.w3`` inside
         #: ``score_stage``): a reader that adds rows up skips it
         self.parent = parent
+        #: the span's layer (``utils.trace.LAYER_OF``); None on a row no
+        #: ``trace.stage`` span has fed
+        self.layer = layer
         self.work_s = 0.0
+        #: the on-CPU part of ``work_s`` (the spans' ``time.thread_time()``
+        #: readings): ``work_s - cpu_s`` is time this row's thread WAITED
+        #: inside its work — for the interpreter, a lock, the device, the
+        #: disk. None until a span has fed the row: the executor's generic
+        #: stages measure a wall only
+        self.cpu_s: float | None = None
         self.wait_in_s = 0.0
         self.wait_out_s = 0.0
         self.items = 0
@@ -87,8 +97,10 @@ class StageStats:
 
     def add_work(self, dt: float, items: int = 1,
                  bytes_in: int = 0, bytes_out: int = 0,
-                 records: int = 0) -> None:
+                 records: int = 0, cpu: float | None = None) -> None:
         self.work_s += dt
+        if cpu is not None:
+            self.cpu_s = (self.cpu_s or 0.0) + cpu
         self.items += items
         self.bytes_in += bytes_in
         self.bytes_out += bytes_out
@@ -109,8 +121,12 @@ class StageStats:
             "wait_out_s": round(self.wait_out_s, 6),
             "items": self.items,
         }
+        if self.cpu_s is not None:
+            out["cpu_s"] = round(self.cpu_s, 6)
         if self.parent is not None:
             out["parent"] = self.parent
+        if self.layer is not None:
+            out["layer"] = self.layer
         if self.records:
             out["records"] = self.records
             if self.work_s > 0:
@@ -133,11 +149,15 @@ class StageProfiler:
         self._stages: dict[str, StageStats] = {}
         self._lock = threading.Lock()
 
-    def stage(self, name: str, parent: str | None = None) -> StageStats:
+    def stage(self, name: str, parent: str | None = None,
+              layer: str | None = None) -> StageStats:
         s = self._stages.get(name)
         if s is None:
             with self._lock:
-                s = self._stages.setdefault(name, StageStats(name, parent))
+                s = self._stages.setdefault(name,
+                                            StageStats(name, parent, layer))
+        elif s.layer is None and layer is not None:
+            s.layer = layer  # a row the executor made before any span fed it
         return s
 
     def set_records(self, n: int) -> None:

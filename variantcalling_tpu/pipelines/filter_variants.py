@@ -13,6 +13,7 @@ host memory with one compile per chunk shape.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import logging
 import os
 import pickle
@@ -1531,14 +1532,19 @@ def _run_streaming_impl(args, model, fasta: FastaReader, annotate, blacklist,
     from variantcalling_tpu.obs import profile as profile_mod
 
     prof = profile_mod.StageProfiler() if profile_mod.enabled() else None
-    with obs.bind_profiler(prof):
+    # `stream_open`: a stream's head, from here to the pipeline's first pull
+    # (reader and header scan, FilterContext, output header, identity, resume
+    # decision, partial and journal open). _stream_chunks closes it there; a
+    # failure before that unwinds it here
+    with obs.bind_profiler(prof), contextlib.ExitStack() as opening:
+        opening.enter_context(stage("stream_open"))
         return _stream_chunks(args, model, fasta, annotate, blacklist, prof,
-                              engine=engine, mesh_plan=mesh_plan,
+                              opening, engine=engine, mesh_plan=mesh_plan,
                               rank_plan=rank_plan)
 
 
 def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
-                   engine: engine_mod.EngineDecision | None = None,
+                   opening, engine: engine_mod.EngineDecision | None = None,
                    mesh_plan=None, rank_plan=None) -> dict:
     import contextvars
     import threading
@@ -2088,6 +2094,7 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                     _sink_write(sink, head)
                 else:
                     _sink_write(sink, header_bytes)
+            opening.close()  # `stream_open` ends: the first pull is next
             for body, k, p, qbody, trace_id, _facts in gen:
                 # cooperative per-request cancellation (vctpu serve
                 # deadlines/drain, docs/serving.md): chunk-granular by
@@ -2144,21 +2151,24 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
                             if 0 < session_done and done < input_bytes else 0.0
                     obs.event("heartbeat", "stream", **hb)
                 if journal is not None:
-                    # the journal must never claim bytes still sitting in
-                    # the Python write buffer — a SIGKILL would then leave
-                    # the partial file behind the watermark and resume
-                    # would (safely but wastefully) start fresh
-                    sink.flush()
-                    if journal_mod.fsync_enabled():
-                        # durability knob (VCTPU_JOURNAL_FSYNC): the
-                        # chunk's bytes reach the platter before the
-                        # journal claims them (journal.append fsyncs its
-                        # own line next) — a power cut can then cost at
-                        # most the in-flight chunk
-                        os.fsync(sink.fileno())
-                    journal.append(n_chunks - 1, k, p, len(data),
-                                   zlib.crc32(data),
-                                   in_end=reader.chunk_end(n_chunks - 1))
+                    # the chunk's checksum, the flush and the journal line:
+                    # the committer's own work between two writebacks
+                    with stage("journal_append", chunk=n_chunks - 1):
+                        # the journal must never claim bytes still sitting
+                        # in the Python write buffer — a SIGKILL would then
+                        # leave the partial file behind the watermark and
+                        # resume would (safely but wastefully) start fresh
+                        sink.flush()
+                        if journal_mod.fsync_enabled():
+                            # durability knob (VCTPU_JOURNAL_FSYNC): the
+                            # chunk's bytes reach the platter before the
+                            # journal claims them (journal.append fsyncs
+                            # its own line next) — a power cut can then
+                            # cost at most the in-flight chunk
+                            os.fsync(sink.fileno())
+                        journal.append(n_chunks - 1, k, p, len(data),
+                                       zlib.crc32(data),
+                                       in_end=reader.chunk_end(n_chunks - 1))
                 if cache_session is not None:
                     # committed-prefix publication: entries become
                     # visible (disk store / serve warm index) only once
@@ -2181,20 +2191,21 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
         # worker pool shut down, prefetch cancelled and joined (a dying
         # process must not kill a .venc persist mid-file), journal handle
         # closed.
-        try:
-            gen.close()
-        finally:
-            reader.close()
-            prefetch_cancel.set()
-            prefetch.join()
-        if qsink is not None:
-            qsink.close()
-        if journal is not None:
-            journal.close()
-        if cache_session is not None and not ok:
-            # failure/cancellation: drop everything unpublished — the
-            # stores hold only committed chunks' entries
-            cache_session.discard()
+        with stage("stream_close"):
+            try:
+                gen.close()
+            finally:
+                reader.close()
+                prefetch_cancel.set()
+                prefetch.join()
+            if qsink is not None:
+                qsink.close()
+            if journal is not None:
+                journal.close()
+            if cache_session is not None and not ok:
+                # failure/cancellation: drop everything unpublished — the
+                # stores hold only committed chunks' entries
+                cache_session.discard()
         if not ok:
             # failure exit: the partial (if kept) now awaits a RESUME —
             # release the claim so the resumer (or a superseding fresh
@@ -2222,25 +2233,26 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     # ENOSPC on the rename itself must leave journal + partial behind so
     # the NEXT run resumes (skipping every chunk) instead of recomputing
     # — journal.finish() therefore runs only after the rename landed
-    try:
-        retry_transient(_commit, "output commit")
-    except BaseException:
-        journal_mod.release_token(part_token)
-        if journal is None:
-            # non-resumable run: never leave droppings at the destination
-            journal_mod.remove_partial(out_path, part_token)
-        else:
-            logger.info("output commit failed after %d chunks; partial "
-                        "output + journal kept for resume at %s",
-                        n_chunks, part_path)
-            if obs.active():
-                obs.event("journal", "kept_for_resume", chunks=n_chunks)
-        raise
-    journal_mod.release_token(part_token)  # committed: the partial is gone
-    if journal is not None:
-        journal.finish()
-    if cache_session is not None:
-        cache_session.finish()
+    with stage("commit", records=n_total, chunks=n_chunks):
+        try:
+            retry_transient(_commit, "output commit")
+        except BaseException:
+            journal_mod.release_token(part_token)
+            if journal is None:
+                # non-resumable run: never leave droppings at the destination
+                journal_mod.remove_partial(out_path, part_token)
+            else:
+                logger.info("output commit failed after %d chunks; partial "
+                            "output + journal kept for resume at %s",
+                            n_chunks, part_path)
+                if obs.active():
+                    obs.event("journal", "kept_for_resume", chunks=n_chunks)
+            raise
+        journal_mod.release_token(part_token)  # committed: the partial is gone
+        if journal is not None:
+            journal.finish()
+        if cache_session is not None:
+            cache_session.finish()
     if obs.active():
         obs.event("journal", "committed", chunks=n_chunks, records=n_total)
     if n_quar_chunks:
@@ -2272,8 +2284,11 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
         approx = n_chunks * reader.chunk_bytes
         prof.stage("ingest").bytes_in = \
             min(approx, input_bytes) if bytes_comparable else approx
-        prof.emit(wall_s=_time.perf_counter() - t_start,  # vctpu-lint: disable=VCT006 — obs profile wall clock
-                  records=n_total - resumed_records)
+        # writing the rows out is tracing's own work, under a span of its
+        # own (which closes after the rows are written: it leaves none)
+        with stage("profile_emit"):
+            prof.emit(wall_s=_time.perf_counter() - t_start,  # vctpu-lint: disable=VCT006 — obs profile wall clock
+                      records=n_total - resumed_records)
     return {"n": n_total, "n_pass": n_pass, "chunks": n_chunks,
             "engine": ctx.engine.name,
             "resumed_chunks": resume.chunks if resume is not None else 0,
@@ -2322,22 +2337,25 @@ def run(argv: list[str]) -> int:
 
 
 def _run_impl(args) -> int:
-    # resolve the scoring engine ONCE, up front (engine contract,
-    # docs/robustness.md): an explicitly required native engine that
-    # cannot build/load fails the run HERE with a clear message — never a
-    # silent jit fallback half-way through scoring. Multi-host runs also
-    # agree on one engine across ranks so the allgathered score slices
-    # cannot mix engines within one output file.
-    try:
-        eng = engine_mod.resolve_for_run()
-    except EngineError as e:
-        logger.error("%s", e)
-        return 2
+    # the head of a cold run under one span (a daemon's requests enter at
+    # run_loaded with all of this resident, and emit none)
+    with stage("run_open"):
+        # resolve the scoring engine ONCE, up front (engine contract,
+        # docs/robustness.md): an explicitly required native engine that
+        # cannot build/load fails the run HERE with a clear message — never
+        # a silent jit fallback half-way through scoring. Multi-host runs
+        # also agree on one engine across ranks so the allgathered score
+        # slices cannot mix engines within one output file.
+        try:
+            eng = engine_mod.resolve_for_run()
+        except EngineError as e:
+            logger.error("%s", e)
+            return 2
 
-    model = load_model(args.model_file, args.model_name)
-    fasta = FastaReader(args.reference_file)
-    annotate = {_interval_name(p): bedio.read_intervals(p) for p in args.annotate_intervals}
-    blacklist = read_blacklist(args.blacklist) if args.blacklist else None
+        model = load_model(args.model_file, args.model_name)
+        fasta = FastaReader(args.reference_file)
+        annotate = {_interval_name(p): bedio.read_intervals(p) for p in args.annotate_intervals}
+        blacklist = read_blacklist(args.blacklist) if args.blacklist else None
     return run_loaded(args, model, fasta, annotate, blacklist, engine=eng)
 
 

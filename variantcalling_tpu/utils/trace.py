@@ -19,15 +19,21 @@ makes tracing first-class here:
     event stats; all Python threads' lines are named ``python`` there, so
     the thread name has to ride as a stat);
   * one obs ``span`` event with an explicit ``start`` (the stream's ``t``
-    clock, taken at entry, outside the stream's lock), ``dur``,
-    ``thread``, ``depth``, ``parent`` (the enclosing ``stage`` on this
-    thread; for a thread's outermost span under a request, the request's
-    root span), ``trace_id`` (the chunk's causal trace) and, under a
-    request, ``req``;
+    clock, taken at entry, outside the stream's lock), ``dur``, ``layer``
+    (:data:`LAYER_OF`), on a span named in ``obs.layers.CPU_SPANS`` ``cpu``
+    (the calling thread's on-CPU seconds from entry to exit,
+    ``time.thread_time()``, read just outside the wall clock's readings and
+    the profiler annotation: native code that released the interpreter
+    counts, a thread blocked on the interpreter, a lock, a queue, the
+    device or the disk does not, so ``dur - cpu`` is waiting), ``thread``,
+    ``depth``, ``parent`` (the enclosing ``stage`` on this thread; for a
+    thread's outermost span under a request, the request's root span),
+    ``trace_id`` (the chunk's causal trace) and, under a request, ``req``;
   * the :class:`~variantcalling_tpu.obs.profile.StageProfiler` row of the
     pipeline run this context belongs to (``obs.current_profiler()``),
     ``<name>.w<idx>`` on a pooled worker (``<name>`` elsewhere), with the
-    parent's name on the row, and the histogram ``stage.<name>.s``;
+    parent's name, the layer and the summed ``cpu_s`` on the row, and the
+    histogram ``stage.<name>.s``;
   * with ``causal=True`` the chunk's causal ``trace`` span, fed from the
     same measurement;
   * the run's span table (``ObsRun.spans``, bounded), which ``report()``
@@ -37,26 +43,25 @@ makes tracing first-class here:
 - ``device_trace(logdir)``: context manager around ``jax.profiler`` —
   captures an XLA trace (HLO timelines, fusion views) viewable in
   TensorBoard/Perfetto; no-op if profiling is unavailable.
-- ``VCTPU_TRACE=1`` makes every live ``stage`` span log at INFO as it
-  closes (DEBUG otherwise).
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import logging
 import re
-import sys
 import threading
+import time
 from dataclasses import dataclass
 
 from variantcalling_tpu import logger, obs
+from variantcalling_tpu.obs.layers import CPU_SPANS
+from variantcalling_tpu.obs.layers import LAYER_OF as LAYER_OF  # re-exported
 from variantcalling_tpu.utils import degrade
-from variantcalling_tpu import knobs
 
-#: the profiler-trace name prefix of every program span (the benchmark's
-#: ``program_spans.py`` finds them by it)
-ANNOTATION_PREFIX = "vctpu:"
+#: the profiler-trace name prefix of every program span
+ANNOTATION_PREFIX = obs.ANNOTATION_PREFIX
 
 #: a pooled worker's thread name ends in its index (``vctpu-io-w3``): its
 #: attribution row is ``<name>.w3``, the family spelling
@@ -106,7 +111,7 @@ _NOOP = _NoSpan()
 
 class _LiveSpan:
     __slots__ = ("name", "fields", "causal", "trace_id", "thread", "parent",
-                 "request", "run", "start", "seconds", "_ann")
+                 "request", "run", "start", "seconds", "_ann", "_cpu0")
 
     def __init__(self, run, name: str, trace: str | None, causal: bool,
                  fields: dict):
@@ -123,6 +128,10 @@ class _LiveSpan:
         self.fields.update(fields)
 
     def __enter__(self):
+        # the CPU clock is read OUTSIDE the wall clock's two readings: where
+        # the kernel makes it a slow call (tens of microseconds on the chip's
+        # host) a span's wall must not grow by it
+        self._cpu0 = time.thread_time() if self.name in CPU_SPANS else None
         stack = _LOCAL.stack
         self.parent = stack[-1].name if stack else None
         stack.append(self)
@@ -130,15 +139,10 @@ class _LiveSpan:
         if self.trace_id is None:
             self.trace_id = obs.current_trace()
         request = self.request = obs.current_request()
-        self._ann = None
-        jax = sys.modules.get("jax")  # never the reason jax gets imported
-        if jax is not None:
-            stats = {"trace": self.trace_id or "", "thread": self.thread}
-            if request is not None:
-                stats["req"] = request.req
-            self._ann = jax.profiler.TraceAnnotation(
-                ANNOTATION_PREFIX + self.name, **stats)
-            self._ann.__enter__()
+        stats = {"trace": self.trace_id or "", "thread": self.thread}
+        if request is not None:
+            stats["req"] = request.req
+        self._ann = obs.annotate(self.name, **stats)
         self.start = self.run.now()
         return self
 
@@ -147,6 +151,8 @@ class _LiveSpan:
         dur = self.seconds = run.now() - self.start
         if self._ann is not None:
             self._ann.__exit__(*exc)
+        cpu = None if self._cpu0 is None \
+            else time.thread_time() - self._cpu0
         stack = _LOCAL.stack
         stack.pop()
         if exc[0] is not None:
@@ -155,8 +161,8 @@ class _LiveSpan:
             return False
         depth = len(stack)
         fields = self.fields
-        body = dict(fields, start=round(self.start, 6), dur=round(dur, 6),
-                    thread=self.thread, depth=depth)
+        body = obs.span_body(name, self.start, dur, self.thread, depth,
+                             fields, cpu)
         request = self.request
         if self.parent is not None:
             body["parent"] = self.parent
@@ -173,14 +179,14 @@ class _LiveSpan:
         if prof is not None:
             worker = _WORKER_RE.search(self.thread)
             row = f"{name}.{worker.group(1)}" if worker else name
-            prof.stage(row, parent=self.parent).add_work(
-                dur, **{k: fields[k] for k in _ROW_FIELDS if k in fields})
+            prof.stage(row, parent=self.parent,
+                       layer=body.get("layer")).add_work(
+                dur, cpu=cpu,
+                **{k: fields[k] for k in _ROW_FIELDS if k in fields})
         if self.causal and self.trace_id is not None:
             obs.trace_span(self.trace_id, name, dur, **fields)
         run.spans.append(Span(name, dur, depth, self.thread, self.parent))
-        if knobs.get_bool("VCTPU_TRACE"):
-            logger.info("stage %s: %.3fs", name, dur)
-        else:
+        if logger.isEnabledFor(logging.DEBUG):
             logger.debug("stage %s: %.3fs", name, dur)
         return False
 
@@ -194,7 +200,8 @@ def stage(name: str, *, trace: str | None = None, causal: bool = False,
     bound (the committer); by default it is ``obs.current_trace()``.
     ``fields`` must be JSON-serializable; ``records`` / ``bytes_in`` /
     ``bytes_out`` also feed the attribution row. Names carry no dot: the
-    row's family is everything before the first one."""
+    row's family is everything before the first one. A new name goes into
+    :data:`LAYER_OF` (a test fails on one that is not there)."""
     if not obs.active():
         return _NOOP
     run = obs.current()

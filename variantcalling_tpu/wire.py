@@ -43,6 +43,7 @@ import threading
 import numpy as np
 
 from variantcalling_tpu.featurize import WINDOW_RADIUS
+from variantcalling_tpu.utils.trace import stage
 
 assert sys.byteorder == "little", "the wire packs little-endian words"
 
@@ -244,16 +245,21 @@ def put_reads_host_memory(platform: str | None = None) -> bool:
     import jax
 
     platform = platform or jax.default_backend()
-    with _ALIASES_LOCK:
-        if platform not in _ALIASES:
-            raw = np.zeros(4096 + 64, dtype=np.uint8)
-            start = (-raw.ctypes.data) % 64
-            host = raw[start:start + 4096].view(np.uint32)
-            dev = jax.device_put(host, jax.local_devices(backend=platform)[0])
-            dev.block_until_ready()
-            host[:] = 1
-            _ALIASES[platform] = bool(np.asarray(dev[0]) == 1)
-        return _ALIASES[platform]
+    if platform not in _ALIASES:
+        # once a process and platform, inside a first wave's score_stage
+        # (the read of ``dev[0]`` compiles a small program): the span holds
+        # the wave's other threads too, which wait here for the one probing
+        with stage("backend_probe", platform=platform), _ALIASES_LOCK:
+            if platform not in _ALIASES:
+                raw = np.zeros(4096 + 64, dtype=np.uint8)
+                start = (-raw.ctypes.data) % 64
+                host = raw[start:start + 4096].view(np.uint32)
+                dev = jax.device_put(host,
+                                     jax.local_devices(backend=platform)[0])
+                dev.block_until_ready()
+                host[:] = 1
+                _ALIASES[platform] = bool(np.asarray(dev[0]) == 1)
+    return _ALIASES[platform]
 
 
 def native_fillable(table) -> bool:
