@@ -25,7 +25,8 @@ SPANS = ("inflate", "compress_stage", "tabix_index")
 COUNTERS = ("bgzf.in_bytes", "bgzf.in_blocks", "bgzf.inflate_shards",
             "bgzf.text_bytes_in", "bgzf.text_bytes_out", "bgzf.out_bytes",
             "bgzf.out_blocks", "tabix.records", "tabix.index_skipped",
-            "tabix.index_streamed", "tabix.index_second_pass")
+            "tabix.index_streamed", "tabix.index_second_pass",
+            "bgzf.deflate_members", "bgzf.libdeflate_members")
 
 
 @pytest.fixture(autouse=True)

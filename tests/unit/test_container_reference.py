@@ -66,7 +66,10 @@ def test_it_imports_nothing_of_the_program():
     assert names == {"__future__", "gzip", "os", "random", "struct", "zlib"}
 
 
-def test_the_writer_is_bgzf_by_the_specification(ref, world, tmp_path):
+def test_the_writer_is_bgzf_by_the_specification(ref, world, tmp_path, monkeypatch):
+    from variantcalling_tpu import native
+    from variantcalling_tpu.io.bgzf import BgzfWriter, scan_block_spans
+
     out = str(tmp_path / "ref.vcf.gz")
     n = ref.compress_file(world["plain"], out)
     assert n == os.path.getsize(out) and ref.member(b"") == ref.EOF
@@ -76,8 +79,20 @@ def test_the_writer_is_bgzf_by_the_specification(ref, world, tmp_path):
     got = ref.validate_container(out)
     assert got["text_bytes"] == len(text) and got["payload_max"] == ref.PAYLOAD
     assert got["blocks"] == -(-len(text) // ref.PAYLOAD) + 1
-    # the program frames the same text the same way, at the same level
-    assert open(world["gz"], "rb").read() == open(out, "rb").read()
+    # the program frames the same text the same way, at the same level: the
+    # same members; under its zlib engine the same bytes (ISSUE 39: its
+    # default is libdeflate where the host has it, which deflates them smaller)
+    program = open(world["gz"], "rb").read()
+    theirs = open(out, "rb").read()
+    assert [s[2] for s in scan_block_spans(program)] == [s[2] for s in scan_block_spans(theirs)]
+    assert len(program) <= len(theirs)
+    if native.available():
+        compress = native.bgzf_compress
+        monkeypatch.setattr(native, "bgzf_compress",
+                            lambda data, level=6: compress(data, level, engine=native.BGZF_ZLIB))
+    with BgzfWriter(str(tmp_path / "zlib.vcf.gz")) as w:
+        w.write(text)
+    assert open(tmp_path / "zlib.vcf.gz", "rb").read() == theirs
 
 
 def test_the_programs_container_passes_every_check(ref, world, tmp_path):
@@ -85,7 +100,8 @@ def test_the_programs_container_passes_every_check(ref, world, tmp_path):
     plain = str(tmp_path / "inflated.vcf")
     assert ref.inflate_file(world["gz"], plain) == got["text_bytes"]
     size = ref.check_size(got["bytes"], plain, tolerance())
-    assert size["size_ratio"] == pytest.approx(1.0, abs=0.01)
+    # at or under the plain writer's zlib at the same level (ISSUE 39)
+    assert 0.9 < size["size_ratio"] <= 1.0
     index = ref.check_index(world["gz"], plain, world["contigs"], world["lengths"],
                             64, "5:11")
     assert index == {"regions": 64, "regions_checked": 64}

@@ -3,7 +3,9 @@ on the workers and ``StreamedIndex`` where chunks pass in order, held byte for
 byte (inflated) to ``build_tabix_index``'s second pass over the same committed
 file: wherever record ends fall against the 65,280-byte members and the chunk
 borders, for one and four contigs, REFs over two windows and bin levels, both
-renderers; and the files the facts do not cover, which fall back to that pass."""
+renderers; and the files the facts do not cover, which fall back to that pass.
+Since ISSUE 39 a run's members are libdeflate's where it loaded, and the
+streamed index follows their offsets."""
 
 from __future__ import annotations
 
@@ -260,7 +262,7 @@ def run(world, monkeypatch, inp, out, io_threads=2, **env) -> dict:
         events = [json.loads(ln) for ln in fh if ln.strip()]
     final = [e for e in events if e["kind"] == "metrics" and e["name"] == "final"]
     c = final[-1]["counters"]
-    return {"rc": rc, "records": c["tabix.records"],
+    return {"rc": rc, "records": c["tabix.records"], "counters": c,
             "chunks": sum(e["kind"] == "heartbeat" for e in events),
             "how": (c["tabix.index_streamed"], c["tabix.index_second_pass"],
                     c["tabix.index_skipped"])}
@@ -274,6 +276,29 @@ def test_a_run_writes_the_index_it_gathered(world, monkeypatch, tmp_path, render
     got = run(world, monkeypatch, "calls.vcf.gz", out)
     assert (got["rc"], got["records"], got["how"]) == (0, N, (1, 0, 0)) and got["chunks"] > 3
     same_as_the_second_pass(out, out + ".tbi")
+
+
+def test_the_streamed_index_follows_the_engines_member_offsets(world, monkeypatch, tmp_path):
+    """ISSUE 39: under libdeflate the members' sizes, and so the index's
+    virtual offsets, are not zlib's; the index gathered in the run follows
+    the members the run wrote, under either engine, to the same text."""
+    if native.bgzf_engine() != native.BGZF_LIBDEFLATE:
+        pytest.skip("libdeflate.so.0 did not load on this host")
+    ld_out, zl_out = str(tmp_path / "ld.vcf.gz"), str(tmp_path / "zl.vcf.gz")
+    got = run(world, monkeypatch, "calls.vcf.gz", ld_out)
+    assert (got["rc"], got["records"], got["how"]) == (0, N, (1, 0, 0))
+    members = got["counters"]["bgzf.deflate_members"]
+    assert members > 0 and got["counters"]["bgzf.libdeflate_members"] == members
+    same_as_the_second_pass(ld_out, ld_out + ".tbi")
+    compress = native.bgzf_compress
+    monkeypatch.setattr(native, "bgzf_compress",
+                        lambda data, level=6: compress(data, level, engine=native.BGZF_ZLIB))
+    got = run(world, monkeypatch, "calls.vcf.gz", zl_out)
+    assert (got["rc"], got["how"], got["counters"]["bgzf.libdeflate_members"]) == (0, (1, 0, 0), 0)
+    same_as_the_second_pass(zl_out, zl_out + ".tbi")
+    assert inflated(ld_out) == inflated(zl_out)
+    assert open(ld_out, "rb").read() != open(zl_out, "rb").read()
+    assert inflated(ld_out + ".tbi.streamed") != inflated(zl_out + ".tbi.streamed")
 
 
 def test_an_unsorted_input_falls_back_to_the_second_pass(world, monkeypatch, tmp_path):

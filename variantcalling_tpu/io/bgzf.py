@@ -14,6 +14,14 @@ ingest splits compressed input at member boundaries (:func:`scan_block_spans`)
 and inflates shards on a worker pool; the streaming writeback compresses
 chunk bodies block-parallel through :class:`BgzfChunkCompressor`, whose
 framing is byte-identical to a serial :class:`BgzfWriter` by construction.
+
+Full members deflate in the native compressor, with libdeflate at the same
+level where the host's ``libdeflate.so.0`` loads and with zlib otherwise
+(``native.bgzf_engine``); a file's last partial member, and every member
+without the native library, take zlib through :func:`compress_block`. So
+the bytes are identical across thread counts, chunk cuts and the two
+writers for one engine, and not zlib's where libdeflate loaded: smaller,
+and the same text inflated.
 """
 
 from __future__ import annotations
@@ -213,11 +221,11 @@ class BgzfChunkCompressor:
     The byte stream is split into consecutive ``MAX_BLOCK_DATA`` payloads
     exactly as a serial :class:`BgzfWriter` would (the carry is always
     ``stream_length mod MAX_BLOCK_DATA``, independent of write sizes), so
-    the compressed output is byte-identical to the serial writer
-    regardless of chunk boundaries or worker count. :meth:`add` runs on
-    ONE pipeline stage thread in chunk order — the carry is therefore
-    deterministic — while the deflate work itself fans out (native
-    block-sharded compressor, or per-block on ``pool``).
+    the compressed output is byte-identical to the serial writer's on the
+    same deflate engine regardless of chunk boundaries or worker count.
+    :meth:`add` runs on ONE pipeline stage thread in chunk order — the
+    carry is therefore deterministic — while the deflate work itself fans
+    out (native block-sharded compressor, or per-block on ``pool``).
     """
 
     def __init__(self, level: int = 6, pool=None):

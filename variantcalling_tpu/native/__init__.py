@@ -20,6 +20,7 @@ import threading
 
 import numpy as np
 
+from variantcalling_tpu import obs
 from variantcalling_tpu.obs.sampler import native_span
 
 _DIR = os.path.dirname(__file__)
@@ -129,7 +130,10 @@ def get_lib() -> ctypes.CDLL | None:
         lib.vctpu_bgzf_inflate.restype = _i64
         lib.vctpu_bgzf_inflate.argtypes = [_u8p, _i64, _u8p, _i64]
         lib.vctpu_bgzf_compress.restype = _i64
-        lib.vctpu_bgzf_compress.argtypes = [_u8p, _i64, _u8p, _i64, ctypes.c_int]
+        lib.vctpu_bgzf_compress.argtypes = [_u8p, _i64, _u8p, _i64, ctypes.c_int,
+                                            ctypes.c_int, _i64p]
+        lib.vctpu_bgzf_engine.restype = ctypes.c_int
+        lib.vctpu_bgzf_engine.argtypes = []
         lib.vctpu_bam_depth.restype = _i64
         lib.vctpu_bam_depth.argtypes = [
             _u8p, _i64, _i64p, _i64p, ctypes.c_int32, _i32p,
@@ -321,16 +325,33 @@ def bgzf_decompress(data: bytes) -> bytes | None:
     return None if out is None else out.tobytes()
 
 
-def bgzf_compress(data, level: int = 6) -> bytes | None:
+#: the deflate engines of ``vctpu_bgzf_compress``
+BGZF_ZLIB, BGZF_LIBDEFLATE = 0, 1
+
+
+def bgzf_engine() -> int | None:
+    """The engine :func:`bgzf_compress` deflates with: ``BGZF_LIBDEFLATE``
+    where the host's ``libdeflate.so.0`` loaded, else ``BGZF_ZLIB``; None
+    without the native library."""
+    lib = get_lib()
+    return None if lib is None else lib.vctpu_bgzf_engine()
+
+
+def bgzf_compress(data, level: int = 6, *, engine: int | None = None) -> bytes | None:
     """Deflate a bytes-like buffer into BGZF blocks (+EOF sentinel);
     None → Python fallback. Zero-copy on the way in: the engine deflates
     straight from the caller's buffer (bytes, memoryview, uint8 array) —
     the streaming writeback hands multi-MB chunk bodies through here and
     an extra materialization would double the write path's memory
-    traffic."""
+    traffic. ``engine`` is the loaded one (:func:`bgzf_engine`); tests
+    pass ``BGZF_ZLIB`` to hold the other path. Counts the members it
+    deflated (``bgzf.deflate_members``) and those libdeflate deflated
+    (``bgzf.libdeflate_members``; live only under obs)."""
     lib = get_lib()
     if lib is None:
         return None
+    if engine is None:
+        engine = lib.vctpu_bgzf_engine()
     src_arr = np.ascontiguousarray(_u8view(data))
     n_in = len(src_arr)
     src = src_arr.ctypes.data_as(_u8p) if n_in else \
@@ -338,11 +359,17 @@ def bgzf_compress(data, level: int = 6) -> bytes | None:
     n_blocks = n_in // 65280 + 1
     cap = n_in + n_blocks * 128 + 64
     dst = np.empty(cap, dtype=np.uint8)
+    fallbacks = _i64(0)
     with native_span("bgzf_deflate"):
         n = lib.vctpu_bgzf_compress(src, n_in, dst.ctypes.data_as(_u8p),
-                                    cap, level)
+                                    cap, level, engine, ctypes.byref(fallbacks))
     if n < 0:
         return None
+    if obs.active():
+        members = -(-n_in // 65280)
+        obs.counter("bgzf.deflate_members").add(members)
+        if engine == BGZF_LIBDEFLATE:
+            obs.counter("bgzf.libdeflate_members").add(members - fallbacks.value)
     return dst[:n].tobytes()
 
 

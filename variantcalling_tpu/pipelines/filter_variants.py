@@ -1578,7 +1578,8 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     for name in ("dispatches", "chunks", "rows", "padded_rows"):
         obs.counter(f"mesh.{name}").add(0)
     for name in ("in_bytes", "in_blocks", "inflate_shards", "text_bytes_in",
-                 "text_bytes_out", "out_bytes", "out_blocks"):
+                 "text_bytes_out", "out_bytes", "out_blocks", "deflate_members",
+                 "libdeflate_members"):
         obs.counter(f"bgzf.{name}").add(0)
     for name in ("records", "index_streamed", "index_second_pass", "index_skipped"):
         obs.counter(f"tabix.{name}").add(0)
