@@ -437,9 +437,11 @@ def test_the_vcfgz_configuration_is_the_forest_one_in_another_container():
     assert {k for k in a if a[k] != b[k]} == {"about", "driver"} and set(a) == set(b)
     with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
         bm = json.load(fh)
-    assert bm["configs"][-1]["name"] == gz["name"] == bm["workloads"][-1]["config"]
-    assert bm["configs"][-1]["source"] == gz["source"]
-    assert bm["configs"][-1]["reduced"] == gz["reduced"] == ["variants_per_file"]
+    # the fifth configuration and cell (later ones come after them)
+    entry, cell = bm["configs"][4], bm["workloads"][4]
+    assert entry["name"] == gz["name"] == cell["config"]
+    assert entry["source"] == gz["source"]
+    assert entry["reduced"] == gz["reduced"] == ["variants_per_file"]
 
 
 # -- idle time by the program's own layers, on-CPU shares, fixed work (ISSUE 38) --
@@ -633,3 +635,94 @@ def test_issue_38s_metrics_come_last_and_name_the_programs_layers(bench, name):
         assert set(how["args"]["parts"]) | set(how["args"]["whole"]) <= set(trace.LAYER_OF)
     elif reader == "stage_cpu_share":
         assert set(how["args"]["families"]) <= set(trace.LAYER_OF)
+
+
+# -- the xgboost cell ----------------------------------------------------------
+
+XGB_CELL = "xgb-t100d6-hg38x2.wgs-batch"
+XGB_METRICS = {"feed_missing_share": "streaming executor score stage",
+               "wide_dispatch_share": "device featurize and score",
+               "forest_wide_roofline": "kernel forest wide margin"}
+
+
+@pytest.mark.parametrize("name", sorted(XGB_METRICS))
+def test_the_xgb_cells_metrics_come_last_and_list_only_that_cell(bench, name):
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    assert [m["name"] for m in bm["per_layer"][54:57]] == list(XGB_METRICS)
+    (m,) = [m for m in bm["per_layer"] if m["name"] == name]
+    assert (m["workloads"], m["moves"], m["layer"]) == \
+        ([XGB_CELL], "variants_per_s", XGB_METRICS[name])
+    with open(os.path.join(BENCH, "layer_metrics", name + ".json"), encoding="utf-8") as fh:
+        how = json.load(fh)
+    assert callable(bench.load("readers", how["reader"]).read)
+
+
+#: a traced pair's device events: two 16,384-row steps of the wide margin
+#: (the pick, the mask select, a routing, a leaf pick), a gather and a
+#: featurize fusion of the 163,840-row dispatch that no pattern may take
+WIDE_OPS = [("%convolution_compare_fusion.3 = pred[16384,6300]{0,1} fusion(...)", 0, 2e6),
+            ("%convert_select_fusion.2 = bf16[16384,6300]{0,1} fusion(...)", 0, 1e6),
+            ("%fusion.78 = pred[16384,128]{0,1} fusion(...)", 0, 0.5e6),
+            ("%select_reduce_fusion.4 = f32[16384,2]{0,1} fusion(...)", 0, 0.5e6),
+            ("%convolution_compare_fusion.3 = pred[16384,6300]{0,1} fusion(...)", 0, 2e6),
+            ("%fusion = u32[163840,128]{1,0} fusion(...)", 0, 7e6),
+            # the block loop itself: its carried tuple holds a routed shape
+            ("%while.30 = (s32[]{:T(128)}, f32[50,16384,2]{1,2,0}) while(...)", 0, 50e6),
+            ("%convert_reduce_fusion.8 = (f32[163840]{0}) fusion(...)", 0, 9e6)]
+
+
+def test_ops_roofline_sums_the_parts_ops_and_counts_tables_once_a_call(bench):
+    with open(os.path.join(BENCH, "configs", "xgb-t100d6-hg38x2.json"), encoding="utf-8") as fh:
+        config = json.load(fh)
+    with open(os.path.join(BENCH, "layer_metrics", "forest_wide_roofline.json"),
+              encoding="utf-8") as fh:
+        how = json.load(fh)
+    family = bench.load("families", "xgb")
+    peaks = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+    rows = 4_000_000
+    ctx = context(device_events=[WIDE_OPS], traced_rows=rows, config=config,
+                  family=family, peaks=peaks)
+    got = bench.load("readers", how["reader"]).read(ctx, **how["args"])
+    # by hand: 6 ms of the part's ops; 2 calls open with the feature pick
+    flops = 1_058_600 * rows
+    nbytes = 80 * rows + 2 * 4 * 100 * (2 * 63 + 64)
+    assert got == pytest.approx(100 * max(flops / 197e12, nbytes / 819e9) / 6e-3)
+    # a program without the part's ops gives nothing to read
+    ctx["device_events"] = [WIDE_OPS[5:]]
+    assert bench.load("readers", how["reader"]).read(ctx, **how["args"]) is None
+
+
+def test_xgb_required_work_is_the_forests_count_at_its_shape(bench):
+    """1,058,600 FLOPs a variant: 2 T (F I + I L + L) at T=100, F=19, I=63,
+    L=64, the shape the program's GEMM packing of the drawn booster has."""
+    from variantcalling_tpu.models import forest as fmod
+
+    with open(os.path.join(BENCH, "configs", "xgb-t100d6-hg38x2.json"), encoding="utf-8") as fh:
+        config = json.load(fh)
+    family = bench.load("families", "xgb")
+    forest = family.to_program(config, family.arrays(config["weights_seed"], config))
+    gf = fmod.to_gemm(forest, config["n_features"])
+    assert (gf.a.shape, gf.m2.shape[2]) == \
+        ((config["n_trees"], config["n_features"], config["n_internal"]), config["n_leaves"])
+    assert family.flops_per_variant(config) == 1_058_600
+    assert family.flops_per_variant(config) / \
+        bench.load("families", "forest").flops_per_variant(
+            {"n_trees": 40, "n_features": 12, "n_internal": 31, "n_leaves": 32}) \
+        == pytest.approx(9.48, abs=0.01)
+
+
+def test_the_xgb_configuration_is_the_forest_cells_but_for_the_model():
+    def load(name):
+        with open(os.path.join(BENCH, "configs", name + ".json"), encoding="utf-8") as fh:
+            return json.load(fh)
+
+    forest, xgb = load("forest-t40d6-hg38x2"), load("xgb-t100d6-hg38x2")
+    for key in ("references", "variants_per_file", "published", "reduced", "env",
+                "weights_seed", "control", "limits"):
+        assert xgb[key] == forest[key], key
+    assert (xgb["family"], xgb["n_trees"], xgb["max_depth"]) == ("xgb", 100, 6)
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        bm = json.load(fh)
+    (cell,) = [w for w in bm["workloads"] if w["name"] == XGB_CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("xgb-t100d6-hg38x2", "wgs-batch", 1)

@@ -277,3 +277,44 @@ def test_pin_backend_none_takes_what_jax_initialized():
     engine.pin_backend("cpu")
     with pytest.raises(engine.EngineError, match="--backend tpu"):
         engine.pin_backend("tpu")
+
+
+def test_the_xgb_cells_booster_compiles_for_v5e_and_names_its_roofline_ops(v5e):
+    """The benchmark's xgboost booster (100 trees of depth 6 with
+    ``default_left`` bits, all 19 columns) as the cell dispatches it: the
+    fused program over the wire at the 163,840-row rung, ``auto``'s ``wide``
+    on a TPU, one chip. It compiles, adds under 1 GiB to the resident
+    genome, and every operand shape ``forest_wide_roofline`` reads the
+    device trace by is the result of some operation of the compiled text,
+    so the metric cannot fall silent unnoticed."""
+    import json
+
+    from variantcalling_tpu.featurize import BASE_FEATURES, GENOME_ROW_WORDS
+    from variantcalling_tpu.pipelines import filter_variants as fv
+
+    bench = os.path.join(_REPO, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        import lookup
+        from readers.ops_roofline import result_type
+
+        with open(os.path.join(bench, "configs", "xgb-t100d6-hg38x2.json")) as fh:
+            config = json.load(fh)
+        with open(os.path.join(bench, "layer_metrics", "forest_wide_roofline.json")) as fh:
+            patterns = json.load(fh)["args"]["patterns"]
+        family = lookup.load("families", "xgb")
+        model = family.to_program(config, family.arrays(config["weights_seed"], config))
+    finally:
+        sys.path.remove(bench)
+    assert fmod.resolve_strategy(model, backend="tpu") == "wide"
+    single, _, _ = v5e
+    fn, layout, _fin = fv._build_fused_program(
+        model, list(BASE_FEATURES), "TGCA", True, "wide", None)
+    compiled = fn.lower(
+        jax.ShapeDtypeStruct((6_055_937, GENOME_ROW_WORDS), jnp.uint32, sharding=single),
+        jax.ShapeDtypeStruct((163_840, layout.words), jnp.uint32, sharding=single)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+    results = [result_type(ln.strip()) for ln in compiled.as_text().splitlines()
+               if ln.strip().startswith("%") and " = " in ln]
+    for p in patterns:
+        assert any(p in r for r in results), p

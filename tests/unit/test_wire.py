@@ -439,3 +439,29 @@ def test_put_reads_host_memory_is_found_by_writing_after_the_copy():
     assert wire.put_reads_host_memory() in (True, False)
     if jax.default_backend() == "cpu":
         assert wire.put_reads_host_memory("cpu") is True
+
+
+@pytest.mark.parametrize("keep_nan", [True, False], ids=["keep_nan", "nan_to_zero"])
+@pytest.mark.parametrize("case", ["plain", "missing_qual", "missing_gq", "missing_dp_sor",
+                                  "af_from_info_when_no_ad", "keep_nan", "extra_info_tlod"])
+def test_both_fills_count_the_nan_cells_they_write(world, case, keep_nan):
+    """Each fill returns the float32 cells it wrote as NaN, counted only
+    where NaN is kept: what ``feed.nan_cells`` adds up."""
+    records, kw = CASES[case]
+    table = _table(world, f"count_{case}", records)
+    kw = dict(_featurize_args(kw), keep_nan=keep_nan)
+    fasta, genome, n = world["fasta"], world["genome"], len(table)
+    full = host_featurize(table, fasta, compute_windows=False, **kw)
+    part = host_featurize(table, fasta, compute_windows=False, base_columns=False, **kw)
+    layout = wire.layout_for(tuple(fv._host_names(full.names)), True)
+    assert {"qual", "dp", "sor", "af", "gq"} <= set(layout.floats)
+    a, b = wire.Staging(1024, layout), wire.Staging(1024, layout)
+    got_native = wire.fill_native(a, 0, table, 0, n, part.cols, genome, keep_nan)
+    got_numpy = wire.fill_numpy(b, 0, wire.numpy_columns(
+        layout, full, globalize_positions(table, genome)), 0, n, keep_nan)
+    written = sum(int(np.isnan(a.rec[name][:n]).sum()) for name in layout.floats)
+    assert got_native == got_numpy == (written if keep_nan else 0)
+    if not keep_nan:
+        assert written == 0
+    elif case == "keep_nan":  # by hand: QUAL; DP and SOR (AF comes from AD); GQ
+        assert written == 4

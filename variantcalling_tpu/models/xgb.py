@@ -1,9 +1,13 @@
-"""xgboost model ingestion -> FlatForest (TPU inference for the reference's
-production classifiers).
+"""xgboost model ingestion -> FlatForest (TPU inference for boosters of the
+kind the reference's environment trains).
 
-The reference's filtering models are xgboost 2.1.2 artifacts
-(setup/environment.yml:451, docs/howto-callset-filter.md:114); SURVEY §2.5
-names faithful forest-pickle loading a core replacement target. This module
+What the reference's snapshot shows: its environment pins xgboost 2.1.2
+(setup/environment.yml:451); the model its howto names is a random forest,
+``rf_model_ignore_gt_incl_hpol_runs`` (docs/howto-callset-filter.md:63,
+:114; SURVEY §2.3); the trainer that would say which classifier
+``train_models_pipeline`` fits lives in the ``ugbio_utils`` submodule, which
+the snapshot does not carry. SURVEY §2.5 names faithful forest-pickle
+loading a core replacement target. This module
 ingests them WITHOUT requiring the xgboost library: the ≥1.6 JSON model
 format (``Booster.save_model("*.json")``) is parsed directly, and live
 ``Booster``/``XGBClassifier`` objects round-trip through that same dump

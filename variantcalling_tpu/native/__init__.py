@@ -268,7 +268,7 @@ def get_lib() -> ctypes.CDLL | None:
             _vp, _vp, _vp, _vp,
             _vp, _i64, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
             _vp, _vp, _vp, _vp, _vp, _vp,
-            _vp, _i64, ctypes.c_int32,
+            _vp, _i64, ctypes.c_int32, _vp,
         ]
         lib.vctpu_tabix_chunk_facts.restype = _i64
         lib.vctpu_tabix_chunk_facts.argtypes = [
@@ -1071,16 +1071,18 @@ def wire_fill(dst: np.ndarray, dst_row0: int, lo: int, hi: int,
               radius: int, pos_fill: int, qual, gt, gq, ad, info_vals,
               info_cols: tuple[int, int, int], aclass, indel_length, indel_nuc,
               ref_code, alt_code, n_alts, extras: list[np.ndarray],
-              keep_nan: bool) -> bool:
+              keep_nan: bool) -> int | None:
     """Rows ``[lo, hi)`` of the native scan's arrays into rows
     ``[dst_row0, dst_row0 + hi - lo)`` of ``dst`` (``uint32[rows, W/4]``, a
     staging buffer of :mod:`variantcalling_tpu.wire`), in ONE call with the
     interpreter released. ``fields`` is ``int32[k, 3]``: kind, byte offset,
     argument (``src/vctpu_wire.cc``); ``info_cols`` the DP, SOR and AF
-    columns of ``info_vals``. False when the library is missing."""
+    columns of ``info_vals``. Returns the float32 cells written as NaN,
+    counted in the same pass where ``keep_nan`` (0 otherwise); None when the
+    library is missing."""
     lib = get_lib()
     if lib is None:
-        return False
+        return None
 
     keep: list[np.ndarray] = []  # alive until the call returns
 
@@ -1095,6 +1097,7 @@ def wire_fill(dst: np.ndarray, dst_row0: int, lo: int, hi: int,
         raise ValueError("wire_fill: rows outside the staging buffer or the table")
     ex = (ctypes.c_void_p * max(len(extras), 1))(
         *[c(e, np.float32) for e in extras])
+    nans = ctypes.c_int64(0)
     rc = lib.vctpu_wire_fill(
         dst.ctypes.data, dst_row0, 4 * dst.shape[1], lo, hi,
         c(fields, np.int32), len(fields),
@@ -1105,10 +1108,10 @@ def wire_fill(dst: np.ndarray, dst_row0: int, lo: int, hi: int,
         c(info_vals, np.float64), info_vals.shape[1], *info_cols,
         c(aclass, np.uint8), c(indel_length, np.int32), c(indel_nuc, np.int32),
         c(ref_code, np.int32), c(alt_code, np.int32), c(n_alts, np.int32),
-        ctypes.addressof(ex), len(extras), int(keep_nan))
+        ctypes.addressof(ex), len(extras), int(keep_nan), ctypes.addressof(nans))
     if rc != hi - lo:
         raise ValueError(f"wire_fill: the native fill refused its arguments ({rc})")
-    return True
+    return nans.value
 
 
 def tabix_chunk_facts(body, chrom_codes, pos, ref_len):

@@ -24,6 +24,11 @@
 // - EXTRA: float32 column `argument` of `extras`, made by Python (interval
 //   membership, extra INFO keys), copied.
 //
+// With `keep_nan` and a `nan_cells` out-count, the float32 cells written as
+// NaN (QUAL, DP, SOR, AF, GQ, EXTRA) are counted in the same pass: the
+// missing values a default_left model routes. Without either nothing is
+// counted.
+//
 // Single-threaded on purpose: the streaming executor's workers are the
 // parallelism, and ctypes releases the interpreter for the whole call.
 
@@ -79,10 +84,13 @@ int64_t vctpu_wire_fill(
     const int32_t* indel_length, const int32_t* indel_nuc,
     const int32_t* ref_code, const int32_t* alt_code, const int32_t* n_alts,
     const float* const* extras,  // (n_extras) columns of (n,) float32
-    int64_t n_extras, int32_t keep_nan)
+    int64_t n_extras, int32_t keep_nan,
+    int64_t* nan_cells)          // out: NaN float cells written; null: not counted
 {
     if (lo < 0 || hi < lo || row_bytes <= 0 || n_fields < 0) return -1;
     const bool keep = keep_nan != 0;
+    const bool count = keep && nan_cells != nullptr;
+    int64_t nans = 0;
     for (int64_t f = 0; f < n_fields; ++f) {
         const int32_t kind = fields[3 * f], off = fields[3 * f + 1], arg = fields[3 * f + 2];
         const int32_t size = (kind >= IS_HET && kind <= IS_INS) || kind == REF_CODE
@@ -110,18 +118,27 @@ int64_t vctpu_wire_fill(
                 }
                 break;
             case QUAL:
-                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes)
-                    put<float>(out, from_f64(qual[i], keep));
+                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes) {
+                    const float v = from_f64(qual[i], keep);
+                    if (count) nans += std::isnan(v);
+                    put<float>(out, v);
+                }
                 break;
             case DP: case SOR: {
                 const int32_t col = kind == DP ? dp_col : sor_col;
-                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes)
-                    put<float>(out, from_f64(info_vals[i * n_info + col], keep));
+                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes) {
+                    const float v = from_f64(info_vals[i * n_info + col], keep);
+                    if (count) nans += std::isnan(v);
+                    put<float>(out, v);
+                }
                 break;
             }
             case GQ:
-                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes)
-                    put<float>(out, from_f64(static_cast<double>(gq[i]), keep));
+                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes) {
+                    const float v = from_f64(static_cast<double>(gq[i]), keep);
+                    if (count) nans += std::isnan(v);
+                    put<float>(out, v);
+                }
                 break;
             case AF:
                 for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes) {
@@ -134,6 +151,7 @@ int64_t vctpu_wire_fill(
                         if (std::isnan(af)) af = 0.0f;
                         else if (std::isinf(af)) af = std::copysign(FLT_MAX, af);
                     }
+                    if (count) nans += std::isnan(af);
                     put<float>(out, af);
                 }
                 break;
@@ -162,13 +180,16 @@ int64_t vctpu_wire_fill(
             }
             case EXTRA: {
                 const float* src = extras[arg];
-                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes)
+                for (int64_t i = b_lo; i < b_hi; ++i, out += row_bytes) {
+                    if (count) nans += std::isnan(src[i]);
                     put<float>(out, src[i]);
+                }
                 break;
             }
             }
         }
     }
+    if (count) *nan_cells = nans;
     return hi - lo;
 }
 

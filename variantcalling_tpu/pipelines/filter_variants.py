@@ -528,12 +528,14 @@ class _FusedInputs:
     one staging buffer, chunks that resolved a different layout dispatch
     alone. ``table`` feeds the wire's native fill (``hf.alle`` is None:
     ``hf.cols`` holds only the Python-made columns); a complete ``hf``
-    feeds the numpy fill."""
+    feeds the numpy fill. ``strategy`` names the program a dispatch runs:
+    the resolved forest strategy, ``jit`` for the other families."""
 
     __slots__ = ("n", "program", "genome", "gpos_fill", "windows", "table",
-                 "hf", "_columns")
+                 "hf", "strategy", "_columns")
 
-    def __init__(self, n, program, genome, gpos_fill, windows, table, hf):
+    def __init__(self, n, program, genome, gpos_fill, windows, table, hf,
+                 strategy: str = "jit"):
         self.n = n
         self.program = program
         self.genome = genome
@@ -541,25 +543,34 @@ class _FusedInputs:
         self.windows = windows
         self.table = table
         self.hf = hf
+        self.strategy = strategy
         self._columns = None
 
     def fill(self, buf, row0: int, lo: int, hi: int) -> bool:
         """Rows ``[lo, hi)`` of this chunk into ``buf`` from row ``row0``;
-        True when the native fill wrote them."""
+        True when the native fill wrote them. Where missing values are kept
+        as NaN (a ``default_left`` model), counts ``feed.float_cells`` (the
+        float32 cells of the rows written) and ``feed.nan_cells`` (those
+        written as NaN)."""
+        keep_nan = self.hf.keep_nan
         if buf.windows is not None:
             buf.windows[row0:row0 + (hi - lo)] = self.windows[lo:hi]
-        if self.hf.alle is None:
-            wire.fill_native(buf, row0, self.table, lo, hi, self.hf.cols,
-                             self.genome, self.hf.keep_nan)
-            return True
-        if self._columns is None:  # once a chunk, however many buckets it spans
-            from variantcalling_tpu.featurize import globalize_positions
+        native = self.hf.alle is None
+        if native:
+            nans = wire.fill_native(buf, row0, self.table, lo, hi, self.hf.cols,
+                                    self.genome, keep_nan)
+        else:
+            if self._columns is None:  # once a chunk, however many buckets it spans
+                from variantcalling_tpu.featurize import globalize_positions
 
-            gpos = globalize_positions(self.table, self.genome) \
-                if self.genome is not None else None
-            self._columns = wire.numpy_columns(buf.layout, self.hf, gpos)
-        wire.fill_numpy(buf, row0, self._columns, lo, hi)
-        return False
+                gpos = globalize_positions(self.table, self.genome) \
+                    if self.genome is not None else None
+                self._columns = wire.numpy_columns(buf.layout, self.hf, gpos)
+            nans = wire.fill_numpy(buf, row0, self._columns, lo, hi, keep_nan)
+        if keep_nan:
+            obs.counter("feed.float_cells").add((hi - lo) * len(buf.layout.floats))
+            obs.counter("feed.nan_cells").add(nans)
+        return native
 
 
 def _prepare_fused_inputs(model, hf, flow_order: str,
@@ -617,7 +628,11 @@ def _prepare_fused_inputs(model, hf, flow_order: str,
                                  genome_resident=genome_resident,
                                  strategy=strategy, mesh=mesh)
     n = len(table) if table is not None else len(windows)
-    return _FusedInputs(n, program, genome, gpos_fill, windows, table, hf)
+    # the program's name for score.dispatches.<strategy>: a run pins its
+    # forest strategy; an unpinned build resolved it as it was made
+    label = (strategy or forest_mod.last_strategy) \
+        if isinstance(model, FlatForest) else "jit"
+    return _FusedInputs(n, program, genome, gpos_fill, windows, table, hf, label)
 
 
 #: argument signatures each live jit object has been called at. A jit
@@ -676,7 +691,10 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     ``feed.padded_rows`` (rows of the buckets sent), ``feed.h2d_arrays``
     (arrays handed to the device, the genome excluded),
     ``feed.native_fills`` / ``feed.numpy_fills`` (dispatches whose rows
-    every chunk's native fill wrote / the rest); under a mesh plan also
+    every chunk's native fill wrote / the rest), ``score.dispatches.<s>``
+    (dispatches of the program of strategy ``s``: ``wide``, ``pallas``, ...,
+    or ``jit`` for the other families) and, where NaN is kept, the fill's
+    ``feed.float_cells`` / ``feed.nan_cells``; under a mesh plan also
     ``mesh.dispatches``, ``mesh.chunks`` (chunks packed into them),
     ``mesh.rows`` and ``mesh.padded_rows`` (as the feed's two).
     """
@@ -740,6 +758,7 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
         obs.counter("feed.padded_rows").add(target)
         obs.counter("feed.h2d_arrays").add(len(sent))
         obs.counter("feed.native_fills" if native else "feed.numpy_fills").add(1)
+        obs.counter(f"score.dispatches.{first.strategy}").add(1)
         if mesh is not None:
             obs.counter("mesh.dispatches").add(1)
             obs.counter("mesh.rows").add(hi - lo)
@@ -1573,8 +1592,11 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     obs.counter("predictor.reuses").add(0)
     obs.counter("predictor.waits").add(0)
     for name in ("dispatches", "rows", "padded_rows", "h2d_arrays",
-                 "native_fills", "numpy_fills"):
+                 "native_fills", "numpy_fills", "float_cells", "nan_cells"):
         obs.counter(f"feed.{name}").add(0)
+    # a cell that silently changed programs reads 0, not nothing
+    for name in forest_mod.FOREST_STRATEGIES[1:] + ("jit",):
+        obs.counter(f"score.dispatches.{name}").add(0)
     for name in ("dispatches", "chunks", "rows", "padded_rows"):
         obs.counter(f"mesh.{name}").add(0)
     for name in ("in_bytes", "in_blocks", "inflate_shards", "text_bytes_in",

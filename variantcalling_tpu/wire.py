@@ -80,7 +80,8 @@ class WireLayout:
     ``fields`` the same as ``int32[k, 3]`` (kind, offset, extra's number) for
     the native fill, ``extras`` the host columns only Python makes."""
 
-    __slots__ = ("host_names", "resident", "dtype", "width", "fields", "extras")
+    __slots__ = ("host_names", "resident", "dtype", "width", "fields", "extras",
+                 "floats")
 
     def __init__(self, host_names: tuple[str, ...], resident: bool):
         self.host_names = host_names
@@ -102,6 +103,8 @@ class WireLayout:
         self.fields = np.asarray(
             [(kinds[n][0], o, self.extras.index(n) if n in self.extras else 0)
              for n, o in zip(order, offsets)], dtype=np.int32).reshape(-1, 3)
+        #: the float32 columns: where a missing value is NaN under keep_nan
+        self.floats = tuple(n for n in order if kinds[n][1] == np.float32)
 
     @property
     def words(self) -> int:
@@ -275,16 +278,18 @@ def native_fillable(table) -> bool:
 
 
 def fill_native(buf: Staging, row0: int, table, lo: int, hi: int,
-                extras: dict, genome, keep_nan: bool) -> None:
+                extras: dict, genome, keep_nan: bool) -> int:
     """Rows ``[lo, hi)`` of ``table`` into rows ``[row0, ...)`` of ``buf``:
     what ``host_featurize``, ``classify_alleles``, ``_compute_af``,
     ``globalize_positions`` and the NaN -> 0 rule make of the scan's arrays,
-    in one native pass. ``extras``: the layout's Python-made columns."""
+    in one native pass. ``extras``: the layout's Python-made columns.
+    Returns the float32 cells written as NaN where ``keep_nan`` (counted in
+    the same pass), 0 otherwise."""
     from variantcalling_tpu import native
     from variantcalling_tpu.featurize import packed_position_fill
 
     if hi <= lo:
-        return
+        return 0
     layout, aux = buf.layout, table.aux
     if layout.resident:
         names = table.chrom_names
@@ -294,7 +299,7 @@ def fill_native(buf: Staging, row0: int, table, lo: int, hi: int,
     else:
         off, length, pos_fill = [-1], [0], 0
     a = aux.alle
-    native.wire_fill(
+    return native.wire_fill(
         buf.words, row0, lo, hi, layout.fields,
         pos=table.pos, chrom_codes=table.chrom_codes,
         contig_off=off, contig_len=length, radius=WINDOW_RADIUS, pos_fill=pos_fill,
@@ -316,9 +321,14 @@ def numpy_columns(layout: WireLayout, hf, gpos=None) -> dict:
     return cols
 
 
-def fill_numpy(buf: Staging, row0: int, cols: dict, lo: int, hi: int) -> None:
+def fill_numpy(buf: Staging, row0: int, cols: dict, lo: int, hi: int,
+               keep_nan: bool = False) -> int:
     """The same bytes as :func:`fill_native`, column by column: a cast into
-    the column's place in the rows."""
+    the column's place in the rows. Returns what :func:`fill_native` does:
+    the float32 cells written as NaN where ``keep_nan``, 0 otherwise."""
     rec = buf.rec[row0:row0 + (hi - lo)]
     for name in buf.layout.dtype.names:
         rec[name] = cols[name][lo:hi]
+    if not keep_nan:
+        return 0
+    return sum(int(np.isnan(rec[name]).sum()) for name in buf.layout.floats)
