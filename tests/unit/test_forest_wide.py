@@ -98,17 +98,16 @@ def test_wide_matches_native_engine_bits(rng):
 
 
 def test_wide_nan_missing_routing_bits(rng):
-    """NaN features route through default_left in the wide path exactly as
-    in the gather walk and the scan GEMM (xgboost semantics)."""
+    """NaN features route through default_left in the wide path and the
+    pallas kernel exactly as in the gather walk and the scan GEMM (xgboost
+    semantics)."""
     from tests.unit.test_xgb_ingest import _probe_matrix, _two_tree_model
     from variantcalling_tpu.models.xgb import from_xgboost_json
 
     forest = from_xgboost_json(_two_tree_model())
     assert forest.default_left is not None
     x = _probe_matrix(rng)  # exact-threshold hits + NaN rows
-    # pallas excluded: the kernel does not implement default_left (and an
-    # explicit request fails loudly — test below)
-    _assert_all_bits_equal(_margins(forest, x, 3, ("gather", "gemm", "wide")))
+    _assert_all_bits_equal(_margins(forest, x, 3))
 
 
 def test_wide_tree_block_invariance(rng):
@@ -233,25 +232,32 @@ def test_malformed_wide_knobs_fail_loudly(rng, monkeypatch):
     FilterContext(model, fasta=None, engine=jit_eng)  # clean env: fine
 
 
-def test_explicit_pallas_on_missing_routing_fails_loudly(monkeypatch):
-    """The PR-2 contract applied to make_predictor's old bare-except: an
+def test_pallas_serves_missing_routing_and_fails_loudly_where_it_cannot_compile(
+        rng, monkeypatch):
+    """The engine contract applied to make_predictor's old bare-except: an
     EXPLICITLY requested strategy that cannot build raises (exit-2 style)
-    instead of silently degrading to another program."""
-    from tests.unit.test_xgb_ingest import _two_tree_model
+    instead of silently degrading to another program. A default_left
+    forest is the kernel's to serve (auto picks it on a TPU, the
+    interpreter matches the gather walk); on this CPU backend Mosaic
+    cannot compile it, and that is what fails loudly."""
+    from tests.unit.test_xgb_ingest import _probe_matrix, _two_tree_model
     from variantcalling_tpu.models.xgb import from_xgboost_json
 
-    forest = from_xgboost_json(_two_tree_model())  # default_left: pallas gap
+    forest = from_xgboost_json(_two_tree_model())
     with pytest.raises(EngineError, match="explicitly requested"):
         fmod.make_margin_predictor(forest, 3, strategy="pallas")
     monkeypatch.setenv(fmod.FOREST_STRATEGY_ENV, "pallas")
     with pytest.raises(EngineError, match="explicitly requested"):
         fmod.make_margin_predictor(forest, 3)
-    # auto never resolves to pallas for a default_left forest
     monkeypatch.setenv(fmod.FOREST_STRATEGY_ENV, "auto")
-    assert fmod.resolve_strategy(forest, 3, backend="tpu") == "wide"
+    assert fmod.resolve_strategy(forest, 3, backend="tpu") == "pallas"
     fn = fmod.make_margin_predictor(forest, 3)
     assert fmod.last_strategy == "gather"  # cpu auto
     assert fn is not None
+    x = jnp.asarray(_probe_matrix(rng))
+    kernel = fmod.make_margin_predictor(forest, 3, strategy="pallas", interpret=True)
+    assert np.asarray(kernel(x)).tobytes() == \
+        np.asarray(fmod.predict_margin(forest, x)).tobytes()
 
 
 def test_auto_resolved_strategy_that_cannot_build_raises(rng, monkeypatch):
@@ -306,13 +312,15 @@ def test_auto_resolution_matrix(rng):
     assert fmod.resolve_strategy(f, 12, backend="cpu") == "gather"
     assert fmod.resolve_strategy(f, 12, backend="tpu") == "pallas"
     assert fmod.resolve_strategy(f, 12, backend="gpu") == "wide"
-    # pallas' known gap (default_left) routes auto-TPU to the jnp wide path
+    # missing-value routing (default_left) takes the kernel too
     dl = from_xgboost_json(_two_tree_model())
-    assert fmod.resolve_strategy(dl, 3, backend="tpu") == "wide"
+    assert fmod.resolve_strategy(dl, 3, backend="tpu") == "pallas"
+    assert fmod.resolve_strategy(dl, 3, backend="gpu") == "wide"
     # VCTPU_PALLAS=0 opt-out
     os.environ["VCTPU_PALLAS"] = "0"
     try:
         assert fmod.resolve_strategy(f, 12, backend="tpu") == "wide"
+        assert fmod.resolve_strategy(dl, 3, backend="tpu") == "wide"
     finally:
         del os.environ["VCTPU_PALLAS"]
 

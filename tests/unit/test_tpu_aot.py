@@ -74,10 +74,11 @@ def _tpu_strategy_program(strategy, forest, n_features):
     return fmod._build_margin_program(strategy, forest, n_features)
 
 
-def test_every_strategy_auto_can_pick_on_a_tpu_compiles_for_v5e(v5e, rng):
+def test_every_strategy_auto_can_pick_on_a_tpu_compiles_for_v5e(v5e, rng, monkeypatch):
     """The test that would have caught both bring-up blockers without a
     chip: the Mosaic block-shape refusal (pallas) and the unvarying loop
-    carries inside shard_map (every strategy at dp > 1)."""
+    carries inside shard_map (every strategy at dp > 1). ``wide`` is what
+    auto picks on a TPU under ``VCTPU_PALLAS=0``."""
     from tests.unit.test_xgb_ingest import _two_tree_model
     from variantcalling_tpu.models.xgb import from_xgboost_json
     from variantcalling_tpu.synthetic import synthetic_forest
@@ -87,10 +88,11 @@ def test_every_strategy_auto_can_pick_on_a_tpu_compiles_for_v5e(v5e, rng):
     too_wide = synthetic_forest(rng, n_trees=2, depth=11, n_features=12)
     missing_routing = from_xgboost_json(_two_tree_model())
     assert fmod.max_tree_leaves(widest_gemm) == fmod.GEMM_MAX_LEAVES
-    cases = [(bench_shape, 12), (widest_gemm, 12), (too_wide, 12),
-             (missing_routing, 3)]
+    cases = [(bench_shape, 12, "1"), (widest_gemm, 12, "1"), (too_wide, 12, "1"),
+             (missing_routing, 3, "1"), (bench_shape, 12, "0")]
     picked = set()
-    for forest, n_features in cases:
+    for forest, n_features, pallas_on in cases:
+        monkeypatch.setenv("VCTPU_PALLAS", pallas_on)
         strategy = fmod.resolve_strategy(forest, n_features, backend="tpu")
         picked.add(strategy)
         _compile_dp1_and_dp4(
@@ -279,17 +281,22 @@ def test_pin_backend_none_takes_what_jax_initialized():
         engine.pin_backend("tpu")
 
 
-def test_the_xgb_cells_booster_compiles_for_v5e_and_names_its_roofline_ops(v5e):
+def test_the_xgb_cells_booster_compiles_for_v5e_and_names_its_roofline_ops(v5e, monkeypatch):
     """The benchmark's xgboost booster (100 trees of depth 6 with
     ``default_left`` bits, all 19 columns) as the cell dispatches it: the
-    fused program over the wire at the 163,840-row rung, ``auto``'s ``wide``
-    on a TPU, one chip. It compiles, adds under 1 GiB to the resident
-    genome, and every operand shape ``forest_wide_roofline`` reads the
-    device trace by is the result of some operation of the compiled text,
-    so the metric cannot fall silent unnoticed."""
+    fused program over the wire at the 163,840-row rung, one chip, under
+    ``auto``'s ``pallas`` on a TPU and under ``wide`` (``VCTPU_PALLAS=0``,
+    other accelerators). Each compiles and adds under 1 GiB to the resident
+    genome; the kernel's program holds ``forest_wide_block_missing``, the
+    name ``forest_wide_block_missing_roofline`` reads the device trace by,
+    and every operand shape ``forest_wide_roofline`` reads is the result of
+    some operation of the wide program's compiled text, so neither metric
+    can fall silent unnoticed."""
     import json
 
     from variantcalling_tpu.featurize import BASE_FEATURES, GENOME_ROW_WORDS
+    from variantcalling_tpu.models.forest_pallas import \
+        make_wide_pallas_margin_predictor
     from variantcalling_tpu.pipelines import filter_variants as fv
 
     bench = os.path.join(_REPO, "benchmarks")
@@ -300,21 +307,33 @@ def test_the_xgb_cells_booster_compiles_for_v5e_and_names_its_roofline_ops(v5e):
 
         with open(os.path.join(bench, "configs", "xgb-t100d6-hg38x2.json")) as fh:
             config = json.load(fh)
-        with open(os.path.join(bench, "layer_metrics", "forest_wide_roofline.json")) as fh:
-            patterns = json.load(fh)["args"]["patterns"]
+        metrics = {}
+        for name in ("forest_wide_roofline", "forest_wide_block_missing_roofline"):
+            with open(os.path.join(bench, "layer_metrics", name + ".json")) as fh:
+                metrics[name] = json.load(fh)["args"]
         family = lookup.load("families", "xgb")
         model = family.to_program(config, family.arrays(config["weights_seed"], config))
     finally:
         sys.path.remove(bench)
-    assert fmod.resolve_strategy(model, backend="tpu") == "wide"
+    assert fmod.resolve_strategy(model, backend="tpu") == "pallas"
+    # the registry would warm the kernel up on THIS (CPU) backend; build it
+    # Mosaic-bound, as _tpu_strategy_program does
+    build = fmod._build_margin_program
+    monkeypatch.setattr(fmod, "_build_margin_program", lambda s, f, n, interpret=False: (
+        make_wide_pallas_margin_predictor(fmod.to_gemm(f, n)) if s == "pallas"
+        else build(s, f, n, interpret)))
     single, _, _ = v5e
-    fn, layout, _fin = fv._build_fused_program(
-        model, list(BASE_FEATURES), "TGCA", True, "wide", None)
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct((6_055_937, GENOME_ROW_WORDS), jnp.uint32, sharding=single),
-        jax.ShapeDtypeStruct((163_840, layout.words), jnp.uint32, sharding=single)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
-    results = [result_type(ln.strip()) for ln in compiled.as_text().splitlines()
+    texts = {}
+    for strategy in ("pallas", "wide"):
+        fn, layout, _fin = fv._build_fused_program(
+            model, list(BASE_FEATURES), "TGCA", True, strategy, None)
+        compiled = fn.lower(
+            jax.ShapeDtypeStruct((6_055_937, GENOME_ROW_WORDS), jnp.uint32, sharding=single),
+            jax.ShapeDtypeStruct((163_840, layout.words), jnp.uint32, sharding=single)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
+        texts[strategy] = compiled.as_text()
+    assert metrics["forest_wide_block_missing_roofline"]["pattern"] in texts["pallas"]
+    results = [result_type(ln.strip()) for ln in texts["wide"].splitlines()
                if ln.strip().startswith("%") and " = " in ln]
-    for p in patterns:
+    for p in metrics["forest_wide_roofline"]["patterns"]:
         assert any(p in r for r in results), p

@@ -8,7 +8,8 @@ that default routing fires on some records and not on others.
 - every record's TREE_SCORE and FILTER agree with the family's plain scorer
   (xgboost's documented prediction) under the jit engine's ``gather`` and
   ``wide`` programs and under the native engine;
-- ``auto`` sends a ``default_left`` forest to ``wide`` on a TPU;
+- ``auto`` sends a ``default_left`` forest to ``pallas`` on a TPU, whose
+  kernel routes the draw's missing values as every other program does;
 - the counters the cell's metrics read: ``feed.nan_cells`` equals the absent
   values drawn, ``feed.float_cells`` the float cells written, and one
   ``score.dispatches.<strategy>`` a dispatch.
@@ -191,15 +192,49 @@ def test_a_missing_value_routed_as_zero_is_caught(world):
     assert np.abs(as_zero - _want(world)).max() > 100 * SCORE_TOL
 
 
-def test_auto_sends_a_default_left_forest_to_wide_on_a_tpu(world):
+def test_auto_sends_a_default_left_forest_to_pallas_on_a_tpu(world, monkeypatch):
     import dataclasses
 
     from variantcalling_tpu.models import forest as fmod
 
     forest = world["family"].to_program(SMALL, world["weights"])
-    assert fmod.resolve_strategy(forest, backend="tpu") == "wide"
+    assert fmod.resolve_strategy(forest, backend="tpu") == "pallas"
     plain = dataclasses.replace(forest, default_left=None)
     assert fmod.resolve_strategy(plain, backend="tpu") == "pallas"
+    monkeypatch.setenv("VCTPU_PALLAS", "0")
+    assert fmod.resolve_strategy(forest, backend="tpu") == "wide"
+
+
+@pytest.mark.parametrize("n", [0, 1, 1000])
+def test_the_kernel_routes_the_draws_missing_values_as_every_strategy(world, n):
+    """The family's draw through every device program, the Pallas kernel in
+    the interpreter: NaN in every column somewhere, a row of NaN only, the
+    roots' thresholds met exactly, n not a multiple of the kernel's tile."""
+    import jax
+    import jax.numpy as jnp
+
+    import fixtures
+
+    from variantcalling_tpu.models import forest as fmod
+
+    forest = world["family"].to_program(SMALL, world["weights"])
+    rng = np.random.default_rng(n)
+    names = fixtures.RUN_FEATURES
+    lo, hi = (np.array([fixtures.FEATURE_RANGE[f][k] for f in names], np.float32)
+              for k in (0, 1))
+    x = (lo + rng.random((n, len(names))) * (hi - lo)).astype(np.float32)
+    if n > 1:
+        x[rng.random(x.shape) < 0.1] = np.nan
+        for t in range(forest.n_trees):
+            x[1 + t, forest.feature[t, 0]] = forest.threshold[t, 0]
+        assert np.isnan(x).any(axis=0).all()
+    x[:1] = np.nan
+    xj = jnp.asarray(x)
+    margins = {s: np.asarray(jax.jit(fmod.make_margin_predictor(
+        forest, len(names), strategy=s, interpret=True))(xj))
+        for s in ("gather", "gemm", "wide", "pallas")}
+    for s, m in margins.items():
+        assert m.shape == (n,) and m.tobytes() == margins["gather"].tobytes(), s
 
 
 @pytest.mark.parametrize("strategy", ["gather", "wide"])

@@ -582,9 +582,8 @@ def resolve_strategy(forest: FlatForest, n_features: int | None = None,
     single-device scoring through the native C++ engine before reaching
     here; this is the jit engine's CPU program). Accelerators take the
     wide-contraction GEMM; TPUs take the pallas wide-block kernel when
-    enabled (VCTPU_PALLAS=0 opts out) and the forest has no missing-value
-    routing (the kernel's known gap). Trees beyond GEMM_MAX_LEAVES fall
-    back to the gather walk everywhere.
+    enabled (VCTPU_PALLAS=0 opts out), missing-value routing included.
+    Trees beyond GEMM_MAX_LEAVES fall back to the gather walk everywhere.
     """
     from variantcalling_tpu import knobs, obs
 
@@ -597,8 +596,7 @@ def resolve_strategy(forest: FlatForest, n_features: int | None = None,
             resolved, why = "gather", "auto: cpu backend keeps the gather walk"
         elif max_tree_leaves(forest) > GEMM_MAX_LEAVES:
             resolved, why = "gather", "auto: tree leaves exceed GEMM_MAX_LEAVES"
-        elif backend == "tpu" and knobs.get_bool("VCTPU_PALLAS") \
-                and forest.default_left is None:
+        elif backend == "tpu" and knobs.get_bool("VCTPU_PALLAS"):
             resolved, why = "pallas", "auto: tpu backend, pallas enabled"
         else:
             resolved, why = "wide", f"auto: {backend} backend wide-contraction"

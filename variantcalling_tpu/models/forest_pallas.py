@@ -31,14 +31,25 @@ leading squeezed dimension, and G*I, G*L, G and F are zero-padded to
 tile multiples on the host (padded decision rows meet zero routing
 columns, padded leaves carry plen - c = -1 and never match).
 
+Missing-value routing (xgboost's ``default_left``): the wrapper feeds x
+with NaN replaced by 0 plus its 0/1 NaN mask, and the step adds
+
+    mf    = a[b]^T  @ mask^T     (G*I, TILE_N)   MXU, exact 0/1 in bf16
+    d     = mf > 0.5 ? dleft[b] : xf <= thr[b]
+
+(a column of ``a`` selects one feature, so ``mf`` is exactly that
+feature's mask bit). The ``pallas_call`` is then named
+``forest_wide_block_missing``; a forest without ``default_left`` traces
+to the plain program above, name and operands unchanged.
+
 Integration: the ``pallas`` entry of the models/forest strategy registry
-(``VCTPU_FOREST_STRATEGY``). Forests with missing-value routing
-(default_left) use the jnp paths — NaN-bearing inputs need the extra mask
-matmul. ``interpret=True`` runs the same kernel through the Pallas
-interpreter; only tests ask for it.
+(``VCTPU_FOREST_STRATEGY``). ``interpret=True`` runs the same kernel
+through the Pallas interpreter; only tests ask for it.
 """
 
 from __future__ import annotations
+
+import functools
 
 import numpy as np
 
@@ -52,14 +63,28 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _wide_block_kernel(xt_ref, at_ref, thr_ref, m2t_ref, q_ref, vsel_ref,
-                       out_ref):
-    """One (variant tile, tree block) step; see the module docstring."""
+def _wide_block_kernel(*refs, missing: bool):
+    """One (variant tile, tree block) step; see the module docstring.
+    ``refs`` are (xt, at, thr, m2t, q, vsel, out), with (mt, dleft)
+    after xt and thr where the forest routes missing values."""
+    if missing:
+        xt_ref, mt_ref, at_ref, thr_ref, dleft_ref, *rest = refs
+    else:
+        xt_ref, at_ref, thr_ref, *rest = refs
+    m2t_ref, q_ref, vsel_ref, out_ref = rest
     # feature pick must keep f32 values exact (thresholds compare tightly)
     xf = jnp.dot(at_ref[...], xt_ref[...],
                  precision=jax.lax.Precision.HIGHEST,
                  preferred_element_type=jnp.float32)
-    d = (xf <= thr_ref[...]).astype(jnp.bfloat16)
+    if missing:
+        # 0/1 selector against a 0/1 mask: exact at default precision
+        mf = jnp.dot(at_ref[...].astype(jnp.bfloat16), mt_ref[...],
+                     preferred_element_type=jnp.float32)
+        d = jnp.where(mf > 0.5, dleft_ref[...],
+                      (xf <= thr_ref[...]).astype(jnp.float32))
+        d = d.astype(jnp.bfloat16)
+    else:
+        d = (xf <= thr_ref[...]).astype(jnp.bfloat16)
     # block-diagonal routing: 0/1 decisions against -1/0/+1 path entries,
     # exact in bf16 with f32 accumulation
     match = jnp.dot(m2t_ref[...], d, preferred_element_type=jnp.float32)
@@ -89,31 +114,28 @@ def _kernel_tables(wf):
     vsel = np.zeros((b, gp, glp), np.float32)
     for k in range(g):
         vsel[:, k, k * l:(k + 1) * l] = wf.value[:, k]
-    return at, thr, m2t.astype(jnp.bfloat16), q, vsel
+    if wf.dleft is None:
+        return at, thr, m2t.astype(jnp.bfloat16), q, vsel
+    dleft = np.zeros((b, gip, 1), np.float32)
+    dleft[:, :gi, 0] = wf.dleft
+    return at, thr, dleft, m2t.astype(jnp.bfloat16), q, vsel
 
 
 def make_wide_pallas_margin_predictor(gf, tree_block: int | None = None,
                                       interpret: bool = False):
     """fn(x) -> canonical-order margin for a GemmForest, running the
     wide-block kernel (grid over (variant tile, tree block); all of a
-    block's operands VMEM-resident).
-
-    Raises ValueError for forests the kernel does not cover (missing-value
-    routing); ``forest.resolve_strategy`` never auto-selects it for them
-    and an explicit ``pallas`` request fails loudly (models/forest
-    registry).
-    """
+    block's operands VMEM-resident). A forest with ``dleft`` routes NaN
+    features by it (``forest_wide_block_missing``)."""
     from jax.experimental import pallas as pl
 
     from variantcalling_tpu.models import forest as forest_mod
 
-    if gf.dleft is not None:
-        raise ValueError("pallas forest kernel does not implement default_left routing")
+    missing = gf.dleft is not None
     wf = forest_mod.to_wide(gf, tree_block)
     tables = _kernel_tables(wf)
     b, gip, fp = tables[0].shape
-    glp = tables[2].shape[1]
-    gp = tables[4].shape[1]
+    gp, glp = tables[-1].shape[1:]
     f = wf.a.shape[1]
     g = wf.tree_block
     n_trees = wf.n_trees
@@ -126,37 +148,44 @@ def make_wide_pallas_margin_predictor(gf, tree_block: int | None = None,
         if n == 0:  # a zero-size grid cannot dispatch
             return jnp.zeros((0,), jnp.float32)
         n_pad = _round_up(n, TILE_N)
-        xt = jnp.pad(x.astype(jnp.float32).T, ((0, fp - f), (0, n_pad - n)))
+        x = x.astype(jnp.float32)
+        pad = ((0, fp - f), (0, n_pad - n))
+        if missing:
+            # NaN would poison the feature pick: pick from zeros, route by
+            # the mask
+            xs = (jnp.pad(jnp.nan_to_num(x, nan=0.0).T, pad),
+                  jnp.pad(jnp.isnan(x).T.astype(jnp.bfloat16), pad))
+        else:
+            xs = (jnp.pad(x.T, pad),)
+        # the mask matmul, and the mask tile and dleft column a step
+        mask_flops = gip * fp if missing else 0
+        mask_bytes = 2 * fp * TILE_N + 4 * gip if missing else 0
         per_tree = pl.pallas_call(
-            _wide_block_kernel,
+            functools.partial(_wide_block_kernel, missing=missing),
             grid=(n_pad // TILE_N, b),
-            in_specs=[
-                pl.BlockSpec((fp, TILE_N), lambda ni, bi: (0, ni)),
-                table_spec(gip, fp),
-                table_spec(gip, 1),
-                table_spec(glp, gip),
-                table_spec(glp, 1),
-                table_spec(gp, glp),
-            ],
+            in_specs=[pl.BlockSpec((fp, TILE_N), lambda ni, bi: (0, ni))
+                      for _ in xs]
+            + [table_spec(*t.shape[1:]) for t in tables],
             out_specs=pl.BlockSpec((None, gp, TILE_N),
                                    lambda ni, bi: (bi, 0, ni)),
             # inside shard_map the output varies over the same mesh axes
             # as the input shard
             out_shape=jax.ShapeDtypeStruct((b, gp, n_pad), jnp.float32,
-                                           vma=jax.typeof(xt).vma),
+                                           vma=jax.typeof(xs[0]).vma),
             # XLA's cost analysis cannot see inside the custom call: count
-            # the three matmuls per grid step from the block shapes, and
-            # the x tile + one block's tables in, one margin block out
+            # the matmuls per grid step from the block shapes, and the x
+            # tile(s) + one block's tables in, one margin block out
             cost_estimate=pl.CostEstimate(
-                flops=2 * n_pad * b * (gip * fp + glp * gip + gp * glp),
+                flops=2 * n_pad * b * (gip * fp + mask_flops + glp * gip
+                                       + gp * glp),
                 transcendentals=0,
                 bytes_accessed=(n_pad // TILE_N) * b * (
-                    4 * fp * TILE_N + 4 * gip * (fp + 1)
+                    4 * fp * TILE_N + 4 * gip * (fp + 1) + mask_bytes
                     + 2 * glp * gip + 4 * glp + 4 * gp * glp
                     + 4 * gp * TILE_N)),
             interpret=interpret,
-            name="forest_wide_block",
-        )(xt, *(jnp.asarray(t) for t in tables))
+            name="forest_wide_block_missing" if missing else "forest_wide_block",
+        )(*xs, *(jnp.asarray(t) for t in tables))
         # (B, Gp, Np) -> (N, T): drop sublane padding, padded trees and
         # padded variants before the shared canonical-order reduction
         per_tree = per_tree[:, :g, :n].reshape(b * g, n)[:n_trees].T
