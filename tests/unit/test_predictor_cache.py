@@ -255,10 +255,10 @@ def test_forest_finalize_resolves_finalize_margin_at_call_time(
     and relies on a cache HIT still seeing the patch."""
     from variantcalling_tpu.models import forest as forest_mod
 
-    _fn, _hosts, finalize = _lookup("fused", _forest())
+    finalize = _lookup("fused", _forest()).finalize
     monkeypatch.setattr(forest_mod, "finalize_margin",
                         lambda m, _forest: np.full_like(m, 7.0))
-    _fn, _hosts, again = _lookup("fused", _forest())
+    again = _lookup("fused", _forest()).finalize
     assert again is finalize
     assert (again(np.zeros(3, np.float32)) == 7.0).all()
 
@@ -478,8 +478,10 @@ def test_dispatch_gates_each_bucket_on_size_and_window_shape(
     assert scores.shape == (n,)
     (sig, _span, call_args), = seen
     assert sig == (featurize_bucket(n), (41,))
-    # one buffer beside the windows: the whole dispatch is two arrays
-    assert [tuple(a.shape) for a in call_args] \
+    # the model's weights (the one device copy the program was built
+    # with), then one buffer beside the windows: the rows are two arrays
+    assert call_args[0] is program.weights
+    assert [tuple(a.shape) for a in call_args[1:]] \
         == [(featurize_bucket(n), 41), (featurize_bucket(n), layout.words)]
 
 

@@ -49,16 +49,28 @@ def v5e():
             NamedSharding(mesh, P(DATA_AXIS)))
 
 
-def _compile_dp1_and_dp4(fn, n_features, v5e):
+def _weight_specs(weights, sharding):
+    """A program's weights operand as shapes placed by ``sharding``."""
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+                        weights)
+
+
+def _compile_dp1_and_dp4(fn, weights, n_features, v5e):
+    """``fn(weights, x)`` compiled as a dispatch calls it: the weights as an
+    argument on the program's device (replicated over the dp=4 mesh)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     from variantcalling_tpu.parallel import shard_score
 
     single, mesh, dp_sharded = v5e
-    for program, sharding in (
-            (fn, single),
-            (shard_score.shard_program(fn, mesh, n_data_args=1), dp_sharded)):
+    for program, sharding, w_sharding in (
+            (fn, single, single),
+            (shard_score.shard_program(fn, mesh, n_data_args=1, replicated_leading=1),
+             dp_sharded, NamedSharding(mesh, P()))):
         spec = jax.ShapeDtypeStruct((ROWS, n_features), jnp.float32,
                                     sharding=sharding)
-        compiled = jax.jit(program).lower(spec).compile()
+        compiled = jax.jit(program).lower(_weight_specs(weights, w_sharding),
+                                          spec).compile()
         assert compiled is not None
 
 
@@ -69,8 +81,9 @@ def _tpu_strategy_program(strategy, forest, n_features):
         from variantcalling_tpu.models.forest_pallas import \
             make_wide_pallas_margin_predictor
 
-        return make_wide_pallas_margin_predictor(
+        kernel = make_wide_pallas_margin_predictor(
             fmod.to_gemm(forest, n_features), interpret=False)
+        return (lambda _w, x: kernel(x)), ()
     return fmod._build_margin_program(strategy, forest, n_features)
 
 
@@ -96,7 +109,7 @@ def test_every_strategy_auto_can_pick_on_a_tpu_compiles_for_v5e(v5e, rng, monkey
         strategy = fmod.resolve_strategy(forest, n_features, backend="tpu")
         picked.add(strategy)
         _compile_dp1_and_dp4(
-            _tpu_strategy_program(strategy, forest, n_features), n_features, v5e)
+            *_tpu_strategy_program(strategy, forest, n_features), n_features, v5e)
     # the cases above cover everything auto can return on a TPU
     assert picked == {"pallas", "gather", "wide"}
 
@@ -109,8 +122,8 @@ def test_dan_program_compiles_for_v5e(v5e):
     names = list(BASE_FEATURES)
     model = synthetic_dan(np.random.default_rng(0), names, embed_dim=16,
                           hidden=256, n_layers=2)  # the served width
-    _compile_dp1_and_dp4(dan_mod.make_score_predictor(model, names),
-                         len(names), v5e)
+    score = dan_mod.make_score_predictor(model, names)
+    _compile_dp1_and_dp4(lambda _w, x: score(x), (), len(names), v5e)
 
 
 def test_window_gather_compiles_for_v5e_at_hg38_size(v5e):
@@ -171,10 +184,11 @@ def test_fused_program_over_the_wire_compiles_for_v5e_at_the_cells_size(v5e, fam
     assert _bucket(bucket) == bucket
     for use_mesh, genome_sharding, wire_sharding in (
             (None, single, single), (mesh, NamedSharding(mesh, P()), dp_sharded)):
-        fn, layout, _fin = fv._build_fused_program(
+        fn, layout, _fin, weights, _levels = fv._build_fused_program(
             model, names, "TGCA", True, strategy, use_mesh)
         assert layout.words == 10
         compiled = fn.lower(
+            _weight_specs(weights, genome_sharding),
             jax.ShapeDtypeStruct((n_rows, GENOME_ROW_WORDS), jnp.uint32,
                                  sharding=genome_sharding),
             jax.ShapeDtypeStruct((bucket, layout.words), jnp.uint32,
@@ -319,15 +333,20 @@ def test_the_xgb_cells_booster_compiles_for_v5e_and_names_its_roofline_ops(v5e, 
     # the registry would warm the kernel up on THIS (CPU) backend; build it
     # Mosaic-bound, as _tpu_strategy_program does
     build = fmod._build_margin_program
-    monkeypatch.setattr(fmod, "_build_margin_program", lambda s, f, n, interpret=False: (
-        make_wide_pallas_margin_predictor(fmod.to_gemm(f, n)) if s == "pallas"
-        else build(s, f, n, interpret)))
+    def mosaic_bound(s, f, n, interpret=False, sharding=None):
+        if s != "pallas":
+            return build(s, f, n, interpret, sharding)
+        kernel = make_wide_pallas_margin_predictor(fmod.to_gemm(f, n))
+        return (lambda _w, x: kernel(x)), ()
+
+    monkeypatch.setattr(fmod, "_build_margin_program", mosaic_bound)
     single, _, _ = v5e
     texts = {}
     for strategy in ("pallas", "wide"):
-        fn, layout, _fin = fv._build_fused_program(
+        fn, layout, _fin, weights, _levels = fv._build_fused_program(
             model, list(BASE_FEATURES), "TGCA", True, strategy, None)
         compiled = fn.lower(
+            _weight_specs(weights, single),
             jax.ShapeDtypeStruct((6_055_937, GENOME_ROW_WORDS), jnp.uint32, sharding=single),
             jax.ShapeDtypeStruct((163_840, layout.words), jnp.uint32, sharding=single)).compile()
         assert compiled.memory_analysis().temp_size_in_bytes < (1 << 30)
@@ -337,3 +356,57 @@ def test_the_xgb_cells_booster_compiles_for_v5e_and_names_its_roofline_ops(v5e, 
                if ln.strip().startswith("%") and " = " in ln]
     for p in metrics["forest_wide_roofline"]["patterns"]:
         assert any(p in r for r in results), p
+
+
+def test_the_gather_walk_compiles_for_v5e_with_its_table_as_an_argument(v5e, rng):
+    """An unpruned forest's walk as the skrf cell dispatches it: the fused
+    program over the wire at the 163,840-row rung, one chip, with the
+    configuration's tree count and its node table an ARGUMENT. It compiles,
+    adds under 1 GiB of scratch to the resident genome (a row gather of a
+    packed ``(T*M, 5)`` table asked for 8.4 GB there), holds the table once
+    among its arguments, and the whole feature column, by which
+    ``forest_gather_roofline`` finds the walk's tree loop in the device
+    trace, is the type of one ``while`` of its compiled text and of no
+    operation that runs inside it, so the metric cannot fall silent or count
+    twice unnoticed."""
+    import json
+
+    from variantcalling_tpu.featurize import BASE_FEATURES, GENOME_ROW_WORDS
+    from variantcalling_tpu.pipelines import filter_variants as fv
+    from variantcalling_tpu.synthetic import synthetic_forest
+
+    bench = os.path.join(_REPO, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        import lookup
+
+        with open(os.path.join(bench, "configs", "skrf-t100-full-hg38x2.json")) as fh:
+            config = json.load(fh)
+        with open(os.path.join(bench, "layer_metrics", "forest_gather_roofline.json")) as fh:
+            assert json.load(fh)["reader"] == "loop_roofline"
+        family = lookup.load("families", "skrf")
+    finally:
+        sys.path.remove(bench)
+    forest = synthetic_forest(rng, n_trees=config["n_trees"], depth=8,
+                              n_features=config["n_features"])
+    single, _, _ = v5e
+    fn, layout, _fin, weights, levels = fv._build_fused_program(
+        forest, list(BASE_FEATURES), "TGCA", True, "gather", None)
+    assert levels == forest.max_depth
+    n_rows = 6_055_937
+    compiled = fn.lower(
+        _weight_specs(weights, single),
+        jax.ShapeDtypeStruct((n_rows, GENOME_ROW_WORDS), jnp.uint32, sharding=single),
+        jax.ShapeDtypeStruct((163_840, layout.words), jnp.uint32, sharding=single)).compile()
+    mem = compiled.memory_analysis()
+    table_bytes = sum(a.nbytes for a in jax.tree.leaves(weights))
+    genome_bytes = -(-n_rows // 8) * 8 * GENOME_ROW_WORDS * 4
+    assert mem.temp_size_in_bytes < (1 << 30)
+    assert table_bytes <= mem.argument_size_in_bytes - genome_bytes < 2 * table_bytes + (16 << 20)
+    mark = family.walk_loop_operand(*forest.feature.shape)
+    ops = [ln.strip() for ln in compiled.as_text().splitlines()
+           if ln.strip().startswith("%") and " = " in ln]
+    # a parameter, a tuple and its elements name the array but run nothing
+    inert = (" parameter(", " get-tuple-element(", " tuple(")
+    holding = [op for op in ops if mark in op and not any(k in op for k in inert)]
+    assert len(holding) == 1 and " while(" in holding[0], holding

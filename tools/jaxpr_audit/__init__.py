@@ -12,9 +12,10 @@ contract (``tools/jaxpr_audit/contract.json``, the ``event_schema.json``
 pattern: the invariants are an artifact reviewed in diffs, not constants
 buried in tool code):
 
-- **Programs:** every forest strategy's margin predictor
-  (``forest.make_margin_predictor``: gather walk, scan GEMM, wide
-  contraction, pallas wide-block) x ``shard_score.shard_program`` at
+- **Programs:** every forest strategy's margin program
+  (``forest.make_margin_program``: gather walk, scan GEMM, wide
+  contraction, pallas wide-block; traced over its weights operand and
+  the feature matrix) x ``shard_score.shard_program`` at
   dp in {1, 2} (the mesh wrap `_predictor_for` installs), plus the
   coverage reduce kernels (``ops.coverage.binned_mean`` /
   ``depth_histogram`` on both methods).
@@ -123,6 +124,14 @@ def audit_forest(contract: dict):
                       feature_names=[f"f{i}" for i in range(f)])
 
 
+def weight_avals(weights):
+    """The shapes of a program's weights operand (its device arrays stand
+    for no data here: the audit traces, it never runs)."""
+    import jax
+
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), weights)
+
+
 def build_programs(contract: dict) -> list[tuple[str, object, tuple, str]]:
     """-> [(label, fn, avals, kind)] for every program under contract.
 
@@ -146,16 +155,17 @@ def build_programs(contract: dict) -> list[tuple[str, object, tuple, str]]:
         # interpret=True: this is a CPU trace-only stage, and building the
         # pallas entry warms the kernel up once — Mosaic compilation for
         # the chip is checked by tests/unit/test_tpu_aot.py instead
-        program = forest_mod.make_margin_predictor(
+        program = forest_mod.make_margin_program(
             forest, f, strategy=strategy, interpret=True)
         for dp in contract["mesh_device_counts"]:
-            fn = program
+            fn = program.fn
             if dp > 1:
                 plan = shard_score.MeshPlan(dp, str(dp), "jaxpr audit")
                 mesh = shard_score.mesh_for(plan)
-                fn = shard_score.shard_program(fn, mesh, n_data_args=1)
-            programs.append((f"margin/{strategy}/dp={dp}", fn, (x_aval,),
-                             "margin"))
+                fn = shard_score.shard_program(fn, mesh, n_data_args=1,
+                                               replicated_leading=1)
+            programs.append((f"margin/{strategy}/dp={dp}", fn,
+                             (weight_avals(program.weights), x_aval), "margin"))
     programs.extend(build_fused_programs(contract))
     programs.extend(build_dan_programs(contract))
     depth_aval = jax.ShapeDtypeStruct((4096,), jnp.int32)
@@ -213,13 +223,14 @@ def build_fused_programs(contract: dict) -> list[tuple[str, object, tuple, str]]
             if dp > 1:
                 plan = shard_score.MeshPlan(dp, str(dp), "jaxpr audit")
                 mesh = shard_score.mesh_for(plan)
-            fn, layout, _fin = fv._fused_program(
+            program = fv._fused_program(
                 forest, names, "TGCA", genome_resident=(variant == "genome"),
                 strategy="gather", mesh=mesh)
             # the dispatch's one buffer, in the program's wire layout
-            wire_aval = jax.ShapeDtypeStruct((rows, layout.words), jnp.uint32)
-            avals = (genome_aval if variant == "genome" else win_aval, wire_aval)
-            programs.append((f"fused/{variant}/dp={dp}", fn, avals, "margin"))
+            wire_aval = jax.ShapeDtypeStruct((rows, program.layout.words), jnp.uint32)
+            avals = (weight_avals(program.weights),
+                     genome_aval if variant == "genome" else win_aval, wire_aval)
+            programs.append((f"fused/{variant}/dp={dp}", program.fn, avals, "margin"))
     return programs
 
 
@@ -265,11 +276,10 @@ def build_dan_programs(contract: dict) -> list[tuple[str, object, tuple, str]]:
         if mesh is not None:
             fn = shard_score.shard_program(fn, mesh, n_data_args=1)
         programs.append((f"dan/score/dp={dp}", fn, (x_aval,), "dan"))
-        fused, layout, _fin = fv._fused_program(model, names, "TGCA",
-                                                mesh=mesh)
-        wire_aval = jax.ShapeDtypeStruct((rows, layout.words), jnp.uint32)
-        programs.append((f"dan/fused/windows/dp={dp}", fused,
-                         (win_aval, wire_aval), "dan"))
+        fused = fv._fused_program(model, names, "TGCA", mesh=mesh)
+        wire_aval = jax.ShapeDtypeStruct((rows, fused.layout.words), jnp.uint32)
+        programs.append((f"dan/fused/windows/dp={dp}", fused.fn,
+                         (weight_avals(fused.weights), win_aval, wire_aval), "dan"))
     return programs
 
 

@@ -11,7 +11,9 @@ probabilities) and boosted margins (GBT: sum + sigmoid).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field, replace
+from typing import Any, Callable, NamedTuple
 
 import numpy as np
 
@@ -50,9 +52,10 @@ class FlatForest:
 def sequential_tree_sum(per_tree: jnp.ndarray) -> jnp.ndarray:
     """(N, T) per-tree leaf margins -> (N,) canonical-order sum.
 
-    THE one reduction every inference strategy (gather walk, scan GEMM,
-    wide GEMM, pallas) funnels through: a loop-carried fori_loop over
-    trees t=0,1,...,T-1. XLA cannot reassociate a loop-carried f32 sum,
+    THE one reduction every inference strategy (scan GEMM, wide GEMM,
+    pallas) funnels through, and the order the gather walk's tree loop
+    accumulates in itself (:func:`walk_margin`): a loop-carried fori_loop
+    over trees t=0,1,...,T-1. XLA cannot reassociate a loop-carried f32 sum,
     and the native C++ walk accumulates in the same order, so any path
     that produces bit-exact per-tree leaf values produces bit-identical
     margins (the round-5 multihost byte-parity fix, see predict_margin).
@@ -68,50 +71,69 @@ def sequential_tree_sum(per_tree: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.fori_loop(0, t, acc_body, jnp.zeros_like(per_tree[:, 0]))
 
 
-def _packed_node_table(forest: FlatForest) -> np.ndarray:
-    """(T*M, C) float32 packed node table for the gather walk: columns
-    [feature, threshold, left, right, value(, default_left)] with the
-    int32 columns BITCAST into the f32 lanes (a gather only moves bytes,
-    so the bitcast round-trip is exact). One table -> ONE gather per
-    traversal level instead of four or five — on XLA:CPU each rank-2
-    gather lowers to its own scalar loop nest, and collapsing them (plus
-    flattening the (T, M) indexing into 1-D takes) read ~2.5x on the
-    gather strategy there (docs/perf_notes.md "The packed node table").
-    Built at trace time from host arrays, so it lands in the
-    compiled program as one constant.
-    """
-    def i32_as_f32(a):
-        # np.asarray first: boosting-trained forests hold concrete jax
-        # arrays, whose .astype lacks numpy's .view
-        return np.asarray(a, dtype=np.int32).reshape(-1).view(np.float32)
+class NodeTable(NamedTuple):
+    """The gather walk's node table: one flat column per field over all
+    ``T*M`` node slots (tree ``t``'s node ``k`` at ``t*M + k``), children
+    interleaved (``children[2*g]`` left, ``children[2*g + 1]`` right, as
+    tree-local ids), ``default_left`` only for a forest that routes missing
+    values. A pytree of arrays: the program takes it as an operand."""
 
-    cols = [
-        i32_as_f32(forest.feature),
-        np.asarray(forest.threshold, dtype=np.float32).reshape(-1),
-        i32_as_f32(forest.left),
-        i32_as_f32(forest.right),
-        np.asarray(forest.value, dtype=np.float32).reshape(-1),
-    ]
-    if forest.default_left is not None:
-        cols.append(np.asarray(forest.default_left,
-                               dtype=np.float32).reshape(-1))
-    return np.stack(cols, axis=1)
+    feature: np.ndarray  # int32 (T*M,); LEAF for leaves
+    threshold: np.ndarray  # float32 (T*M,)
+    children: np.ndarray  # int32 (2*T*M,)
+    value: np.ndarray  # float32 (T*M,)
+    default_left: np.ndarray | None = None  # bool (T*M,) or None
+
+
+def node_table(forest: FlatForest) -> NodeTable:
+    """The forest's :class:`NodeTable` on the host (built once a predictor).
+
+    Flat columns, gathered with tree-local ids from one tree's slice at a
+    time (:func:`walk_margin`); on a TPU a row gather of a packed
+    ``(T*M, 5)`` table pads each 5-wide row to 128 lanes (an unpruned
+    forest's 163,840-row level then needed 8.4 GB of scratch), and a
+    level-major walk of all trees' columns at once reads them from HBM at
+    about 13 ns a value (docs/perf_notes.md "The node table")."""
+    # np.asarray first: boosting-trained forests hold concrete jax arrays
+    left = np.asarray(forest.left, dtype=np.int32).reshape(-1)
+    right = np.asarray(forest.right, dtype=np.int32).reshape(-1)
+    return NodeTable(
+        feature=np.asarray(forest.feature, dtype=np.int32).reshape(-1),
+        threshold=np.asarray(forest.threshold, dtype=np.float32).reshape(-1),
+        children=np.stack([left, right], axis=1).reshape(-1),
+        value=np.asarray(forest.value, dtype=np.float32).reshape(-1),
+        default_left=None if forest.default_left is None else
+        np.asarray(forest.default_left, dtype=bool).reshape(-1))
 
 
 def predict_margin(forest: FlatForest, x: jnp.ndarray) -> jnp.ndarray:
-    """Raw per-variant leaf-value SUM in canonical tree order (jit-safe).
+    """Raw per-variant leaf-value SUM in canonical tree order (jit-safe):
+    :func:`walk_margin` over the forest's :func:`node_table`, built here at
+    trace time (so a program made this way holds the table as constants;
+    the pipeline's gather program passes it as an operand instead)."""
+    return walk_margin(jax.tree.map(jnp.asarray, node_table(forest)), x,
+                       forest.n_trees, forest.max_depth)
 
-    Traversal: ``max_depth`` rounds of gathers; each round every (variant,
-    tree) pair advances one level (leaves self-loop), so control flow is
-    static — no per-variant Python, no host sync. Each round makes ONE
-    gather of the packed node table (:func:`_packed_node_table`) with
-    flat 1-D node ids, plus one flat take of the feature matrix — the
-    XLA:CPU-friendly lowering (the naive per-array ``take_along_axis``
-    formulation ran ~2.5x slower; docs/perf_notes.md). Flat int32
-    indexing bounds N*F and T*M to 2^31 — callers chunk the variants
-    axis (CHUNK = 2^18) far below that.
 
-    The accumulation is a SEQUENTIAL fori_loop over trees (t=0,1,...,T-1)
+def walk_margin(table: NodeTable, x: jnp.ndarray, n_trees: int,
+                max_depth: int) -> jnp.ndarray:
+    """The gather walk over ``table`` (:class:`NodeTable` of ``n_trees``
+    trees) as an operand.
+
+    Tree by tree: tree ``t``'s columns are sliced out (an unpruned tree's
+    are a few MB, which the TPU compiler keeps in VMEM), then ``max_depth``
+    rounds in which every variant advances one level (leaves self-loop),
+    so control flow is static — no per-variant Python, no host sync. A
+    round gathers the node's column and threshold and the chosen child
+    (and, where missing values are routed, the node's ``default_left``)
+    with tree-local ids; the tested feature is picked from the transposed
+    feature rows by a chain of selects on the column id, which on a TPU
+    costs a fraction of a gather. Flat int32 indexing bounds 2*T*M to
+    2^31. Without ``default_left`` a NaN goes right (``NaN <= t`` is
+    false); with it, a NaN takes the node's default branch.
+
+    The accumulation is SEQUENTIAL over trees (t=0,1,...,T-1, a
+    loop-carried f32 sum from zero, as :func:`sequential_tree_sum`)
     rather than ``jnp.sum``: XLA's reduce reassociates f32 sums into
     SIMD-lane partials whose grouping varies with backend and device
     count, which made jit scores differ from the native C++ walk (and
@@ -121,34 +143,35 @@ def predict_margin(forest: FlatForest, x: jnp.ndarray) -> jnp.ndarray:
     (``native/src/vctpu_forest_tile.h`` forest_walk_tile), so the two
     engines' sums are bit-identical (tests/unit/test_engine_contract.py).
     """
-    t, m = forest.feature.shape
-    has_dl = forest.default_left is not None
-    ptab = jnp.asarray(_packed_node_table(forest))
-    n = x.shape[0]
-    xflat = jnp.asarray(x).reshape(-1)
-    fbase = (jnp.arange(n, dtype=jnp.int32) * x.shape[1])[:, None]  # (N, 1)
-    toff = (jnp.arange(t, dtype=jnp.int32) * m)[None, :]  # (1, T)
+    m = table.feature.shape[0] // n_trees
+    n_features = x.shape[1]
+    rows = jnp.asarray(x).T  # (F, N)
 
-    def unpack_i32(col):
-        return jax.lax.bitcast_convert_type(col, jnp.int32)
+    def tree(t, acc):
+        def column(col, width=1):
+            return jax.lax.dynamic_slice(col, (t * m * width,), (m * width,))
 
-    def body(_, idx):
-        rows = ptab[toff + idx]  # (N, T, C): the ONE node gather per level
-        f = unpack_i32(rows[..., 0])
-        th = rows[..., 1]
-        xv = xflat[fbase + jnp.maximum(f, 0)]  # (N, T)
-        go_left = xv <= th
-        if has_dl:  # missing (NaN) takes the node's default branch
-            go_left = jnp.where(jnp.isnan(xv), rows[..., 5] != 0, go_left)
-        nxt = jnp.where(go_left, unpack_i32(rows[..., 2]),
-                        unpack_i32(rows[..., 3]))
-        return jnp.where(f == LEAF, idx, nxt)
+        feature, threshold = column(table.feature), column(table.threshold)
+        children = column(table.children, 2)
+        default_left = None if table.default_left is None else column(table.default_left)
 
-    # derived from x for the same reason as sequential_tree_sum's carry
-    idx0 = jnp.zeros_like(x, dtype=jnp.int32, shape=(n, t))
-    idx = jax.lax.fori_loop(0, forest.max_depth, body, idx0)
-    leaf_vals = ptab[toff + idx][..., 4]  # (N, T)
-    return sequential_tree_sum(leaf_vals)
+        def level(_, idx):
+            f = feature[idx]
+            xv = rows[0]  # a leaf's column is never read: it keeps its idx
+            for j in range(1, n_features):
+                xv = jnp.where(f == j, rows[j], xv)
+            go_right = ~(xv <= threshold[idx])
+            if default_left is not None:  # missing (NaN) takes the default branch
+                go_right = jnp.where(jnp.isnan(xv), ~default_left[idx], go_right)
+            nxt = children[2 * idx + go_right.astype(jnp.int32)]
+            return jnp.where(f == LEAF, idx, nxt)
+
+        # derived from x for the same reason as sequential_tree_sum's carry
+        idx = jax.lax.fori_loop(0, max_depth, level,
+                                jnp.zeros_like(rows[0], dtype=jnp.int32))
+        return acc + column(table.value)[idx]
+
+    return jax.lax.fori_loop(0, n_trees, tree, jnp.zeros_like(rows[0], dtype=jnp.float32))
 
 
 def finalize_margin(margin: np.ndarray, forest: FlatForest) -> np.ndarray:
@@ -606,22 +629,45 @@ def resolve_strategy(forest: FlatForest, n_features: int | None = None,
     return resolved
 
 
+class MarginProgram(NamedTuple):
+    """A scoring program and what it is handed on every call.
+
+    ``fn(weights, x)`` is jit-safe; ``weights`` is a pytree of device
+    arrays passed as the program's first ARGUMENT, so a compiled program's
+    key holds their shapes, not their bytes, and every batch size shares
+    one device copy. The gather walk's node table travels so (an
+    unpruned forest's table is hundreds of MB: as a constant it would be
+    compiled into, and kept on the device by, every program of every batch
+    size); the matmul strategies and the pallas kernel keep their
+    kilobyte tables compiled in and take ``()``. ``walk_levels`` is the
+    levels one call walks (the gather walk's ``max_depth``, else 0)."""
+
+    fn: Callable
+    weights: Any
+    walk_levels: int = 0
+
+
 def _build_margin_program(strategy: str, forest: FlatForest,
-                          n_features: int | None, interpret: bool = False):
-    """fn(x) -> canonical-order margin for one concrete strategy.
+                          n_features: int | None, interpret: bool = False,
+                          sharding=None) -> tuple[Callable, Any]:
+    """``(fn, weights)`` for one concrete strategy: ``fn(weights, x)`` ->
+    canonical-order margin (:class:`MarginProgram`). ``sharding`` places
+    the weights (None: the default device).
 
     Raises on anything the strategy cannot serve (pallas lowering gaps,
-    bad env values); :func:`make_margin_predictor` turns that into an
+    bad env values); :func:`make_margin_program` turns that into an
     EngineError.
     """
     if strategy == "gather":
-        return lambda x: predict_margin(forest, x)
+        t, depth = forest.n_trees, forest.max_depth
+        table = jax.device_put(node_table(forest), sharding)
+        return (lambda w, x: walk_margin(w, x, t, depth)), table
     gf = to_gemm(forest, n_features)
     if strategy == "gemm":
-        return lambda x: predict_margin_gemm(gf, x)
+        return (lambda _w, x: predict_margin_gemm(gf, x)), ()
     if strategy == "wide":
         wf = to_wide(gf)
-        return lambda x: predict_margin_wide(wf, x)
+        return (lambda _w, x: predict_margin_wide(wf, x)), ()
     if strategy == "pallas":
         from variantcalling_tpu.models.forest_pallas import \
             make_wide_pallas_margin_predictor
@@ -632,14 +678,14 @@ def _build_margin_program(strategy: str, forest: FlatForest,
         # exception from inside some chunk's dispatch
         n_feat = gf.a.shape[1]
         jax.block_until_ready(jax.jit(fn)(jnp.zeros((1, n_feat), jnp.float32)))
-        return fn
+        return (lambda _w, x: fn(x)), ()
     raise ValueError(f"unknown forest strategy {strategy!r}")
 
 
-def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
-                          strategy: str | None = None,
-                          interpret: bool = False):
-    """jittable fn(x) -> canonical-order margin, by strategy.
+def make_margin_program(forest: FlatForest, n_features: int | None = None,
+                        strategy: str | None = None, interpret: bool = False,
+                        sharding=None) -> MarginProgram:
+    """The canonical-order margin program, by strategy (:class:`MarginProgram`).
 
     ``strategy=None`` reads ``VCTPU_FOREST_STRATEGY`` (default ``auto``,
     resolved once through :func:`resolve_strategy`). The resolved
@@ -648,6 +694,8 @@ def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
     honored or the run dies, never silently scored by another program.
     ``interpret=True`` runs the pallas kernel through the Pallas
     interpreter (tests on a CPU backend ask for it; no product path does).
+    ``sharding`` places the program's weights (the scoring mesh's
+    replicated sharding under a mesh plan).
 
     Every strategy returns the SAME bits: bit-exact per-tree leaf margins
     reduced in canonical tree order (:func:`sequential_tree_sum` /
@@ -664,7 +712,8 @@ def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
             f"{'/'.join(FOREST_STRATEGIES[1:])}")
     resolved = resolve_strategy(forest, n_features) if req == "auto" else req
     try:
-        fn = _build_margin_program(resolved, forest, n_features, interpret)
+        fn, weights = _build_margin_program(resolved, forest, n_features, interpret,
+                                            sharding)
     except Exception as e:  # noqa: BLE001 — any build failure is a config error
         how = "auto-resolved" if req == "auto" else \
             f"explicitly requested ({FOREST_STRATEGY_ENV} or a pinned run " \
@@ -676,7 +725,18 @@ def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
             "(gather serves every forest on every backend). "
             "See docs/models.md.") from e
     last_strategy = resolved  # vctpu-lint: disable=VCT010 — run-scoped diagnostic; GIL-atomic store, the strategy is pinned per run so every writer agrees
-    return fn
+    return MarginProgram(fn, weights, forest.max_depth if resolved == "gather" else 0)
+
+
+def make_margin_predictor(forest: FlatForest, n_features: int | None = None,
+                          strategy: str | None = None,
+                          interpret: bool = False):
+    """jittable fn(x) -> canonical-order margin: :func:`make_margin_program`
+    with its weights bound (a program jitted from it holds them as
+    constants; the filter pipeline passes them as arguments instead)."""
+    program = make_margin_program(forest, n_features, strategy=strategy,
+                                  interpret=interpret)
+    return functools.partial(program.fn, program.weights)
 
 
 def make_predictor(forest: FlatForest, n_features: int | None = None,

@@ -15,7 +15,7 @@ children's results."""
 from __future__ import annotations
 
 _LAYERS = {
-    "entry": ("run_open", "stream_open", "stream_close"),
+    "entry": ("run_open", "model_load", "stream_open", "stream_close"),
     "daemon": ("serve_request", "serve_state", "serve_respond"),
     "ingest": ("ingest", "parse", "inflate"),
     "feed": ("host_featurize", "featurize_stage", "prepare_inputs",

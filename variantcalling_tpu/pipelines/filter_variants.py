@@ -14,12 +14,14 @@ from __future__ import annotations
 
 import argparse
 import contextlib
+import functools
 import logging
 import os
 import pickle
 import sys
 import threading
 import weakref
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -31,7 +33,7 @@ from variantcalling_tpu import knobs, logger, obs, wire
 from variantcalling_tpu.engine import EngineError
 from variantcalling_tpu.utils import degrade, keyed_cache
 from variantcalling_tpu.utils.trace import note, stage, timed
-from variantcalling_tpu.featurize import host_featurize
+from variantcalling_tpu.featurize import host_featurize, standard_genome_sharding
 from variantcalling_tpu.io import bed as bedio
 from variantcalling_tpu.io.fasta import FastaReader
 from variantcalling_tpu.io.vcf import FactorizedColumn, VariantTable, read_vcf, write_vcf
@@ -273,30 +275,37 @@ def _strategy_token(strategy: str | None) -> tuple:
             knobs.raw(forest_mod.WIDE_BLOCK_ENV) or "")
 
 
-def _raw_predictor(model, feature_names: list[str], strategy: str | None = None):
-    """-> (program, host_finalize|None).
+def _raw_predictor(model, feature_names: list[str], strategy: str | None = None,
+                   sharding=None):
+    """-> (:class:`forest_mod.MarginProgram`, host_finalize|None).
 
-    ``program`` is jit-safe; ``host_finalize`` (if set) turns its fetched
+    The program's ``fn(weights, x)`` is jit-safe and takes the model's
+    device operands as its first argument (``()`` where the tables are
+    compiled in); ``host_finalize`` (if set) turns its fetched
     output into TREE_SCOREs on the host. FlatForests return canonical-order
     MARGINS from the strategy-resolved device program
-    (:func:`forest_mod.make_margin_predictor` — gather walk, scan GEMM,
+    (:func:`forest_mod.make_margin_program` — gather walk, scan GEMM,
     wide-contraction GEMM or the pallas wide-block kernel, all bit-identical)
     and finalize through :func:`forest_mod.finalize_margin` — the same
     shared code the native engine uses, so every engine/strategy's score
     bits are identical by construction (sigmoid/exp is not bit-portable
     across XLA and libm). ``strategy`` pins the run-level resolution
-    (FilterContext); None reads ``VCTPU_FOREST_STRATEGY``.
+    (FilterContext); None reads ``VCTPU_FOREST_STRATEGY``. ``sharding``
+    places the weights (the mesh's replicated sharding, else None).
     """
     if isinstance(model, FlatForest):
         ordered = forest_mod.with_feature_order(model, feature_names)
-        program = forest_mod.make_margin_predictor(
-            ordered, len(feature_names), strategy=strategy)
+        program = forest_mod.make_margin_program(
+            ordered, len(feature_names), strategy=strategy, sharding=sharding)
         return program, (lambda m: forest_mod.finalize_margin(m, ordered))
     if isinstance(model, DanModel):
         # GEMM-native family: the fused forward pass IS the score (f32
         # end-to-end, docs/models.md) — no host finalize stage.
-        return dan_mod.make_score_predictor(model, feature_names), None
-    return (lambda xx: threshold_mod.predict_score(model, xx, feature_names)), None
+        fn = dan_mod.make_score_predictor(model, feature_names)
+    else:
+        def fn(xx):
+            return threshold_mod.predict_score(model, xx, feature_names)
+    return forest_mod.MarginProgram(lambda _w, xx: fn(xx), ()), None
 
 
 def _predictor_for(model, feature_names: list[str], strategy: str | None = None,
@@ -305,16 +314,19 @@ def _predictor_for(model, feature_names: list[str], strategy: str | None = None,
            _strategy_token(strategy), mesh)
 
     def build():
-        program, finalize = _raw_predictor(model, feature_names, strategy=strategy)
+        program, finalize = _raw_predictor(model, feature_names, strategy=strategy,
+                                           sharding=standard_genome_sharding(mesh))
+        fn = program.fn
         if mesh is not None:
             # data-parallel mesh plan (>1 device): the SAME program body runs
             # per device over its dp shard of the feature matrix — a pure
             # map, margins never cross devices (docs/streaming_executor.md
-            # "Mesh-sharded scoring")
+            # "Mesh-sharded scoring"); the weights are replicated
             from variantcalling_tpu.parallel import shard_score
 
-            program = shard_score.shard_program(program, mesh, n_data_args=1)
-        return jax.jit(program), finalize
+            fn = shard_score.shard_program(fn, mesh, n_data_args=1,
+                                           replicated_leading=1)
+        return functools.partial(jax.jit(fn), program.weights), finalize
 
     return _cached_program(key, build)
 
@@ -359,9 +371,24 @@ def _fused_program(model, feature_names: list[str], flow_order: str,
         model, feature_names, flow_order, genome_resident, strategy, mesh))
 
 
+class FusedProgram(NamedTuple):
+    """What :func:`_fused_program` caches: the jitted entry
+    ``fn(weights, genome_rows | windows, words)``, its wire ``layout``, the
+    host ``finalize`` (None: the program's output is the score), the
+    model's device operands ``weights`` that every dispatch hands it (one
+    copy whatever the bucket size: the gather walk's node table, else
+    ``()``), and ``walk_levels``, the levels one call walks (0: no walk)."""
+
+    fn: Any
+    layout: wire.WireLayout
+    finalize: Any
+    weights: Any
+    walk_levels: int
+
+
 def _build_fused_program(model, feature_names, flow_order, genome_resident,
-                         strategy, mesh):
-    """A miss of :func:`_fused_program`: ``(jitted, layout, finalize)``."""
+                         strategy, mesh) -> FusedProgram:
+    """A miss of :func:`_fused_program`."""
     from variantcalling_tpu.featurize import (CENTER, device_feature_dict,
                                               windows_from_packed)
 
@@ -371,13 +398,15 @@ def _build_fused_program(model, feature_names, flow_order, genome_resident,
     # reaches here, so no native split hides inside the "jit" engine).
     # FlatForest programs return margins and `finalize` (shared with the
     # native engine) produces the final score bits on the host.
-    predictor, finalize = _raw_predictor(model, feature_names, strategy=strategy)
+    program, finalize = _raw_predictor(model, feature_names, strategy=strategy,
+                                       sharding=standard_genome_sharding(mesh))
+    predictor = program.fn
     layout = wire.layout_for(tuple(_host_names(feature_names)), genome_resident)
 
     # The three parts carry names of their own into the compiled program
     # (jax.named_scope is metadata: no operation, byte or program moves),
     # so a device trace groups operations by part whatever their shapes.
-    def body(windows, col):
+    def body(weights, windows, col):
         with jax.named_scope(SCOPE_WINDOW_FEATURES):
             dev = device_feature_dict(windows, col["is_indel"].astype(bool),
                                       col["indel_nuc"].astype(jnp.int32),
@@ -391,31 +420,32 @@ def _build_fused_program(model, feature_names, flow_order, genome_resident,
             ]
             x = jnp.stack(cols, axis=1)
         with jax.named_scope(SCOPE_MODEL):
-            return predictor(x)
+            return predictor(weights, x)
 
     if genome_resident:
-        def fn(genome_rows, words):
+        def fn(weights, genome_rows, words):
             col = wire.unpack(layout, words)
             with jax.named_scope(SCOPE_WINDOW_GATHER):
                 windows = windows_from_packed(genome_rows, col["pos"])
-            return body(windows, col)
+            return body(weights, windows, col)
     else:
-        def fn(windows, words):
-            return body(windows, wire.unpack(layout, words))
+        def fn(weights, windows, words):
+            return body(weights, windows, wire.unpack(layout, words))
 
     if mesh is not None:
         # the mesh-sharded layout: the SAME fused body runs per device
-        # over its dp shard (genome replicated, the buffer's — and the
-        # windows' — leading axis sharded) — a pure map with no
+        # over its dp shard (weights and genome replicated, the buffer's —
+        # and the windows' — leading axis sharded) — a pure map with no
         # collectives, so per-row score bits cannot depend on the device
         # count
         from variantcalling_tpu.parallel import shard_score
 
         fn = shard_score.shard_program(
             fn, mesh, n_data_args=1 if genome_resident else 2,
-            replicated_leading=1 if genome_resident else 0)
+            replicated_leading=2 if genome_resident else 1)
 
-    return jax.jit(fn), layout, finalize
+    return FusedProgram(jax.jit(fn), layout, finalize, program.weights,
+                        program.walk_levels)
 
 
 def _fused_native_chunk_score(ordered, hf, fo: np.ndarray, table,
@@ -524,7 +554,7 @@ class _FusedInputs:
     """One chunk's prepared inputs for the fused featurize+score program —
     the unit :func:`_dispatch_fused` packs into device megabatches
     (parallel/shard_score.py). ``program`` is the cached
-    ``(_fused_program)`` triple; chunks sharing it fill consecutive rows of
+    :class:`FusedProgram`; chunks sharing it fill consecutive rows of
     one staging buffer, chunks that resolved a different layout dispatch
     alone. ``table`` feeds the wire's native fill (``hf.alle`` is None:
     ``hf.cols`` holds only the Python-made columns); a complete ``hf``
@@ -696,14 +726,17 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
     or ``jit`` for the other families) and, where NaN is kept, the fill's
     ``feed.float_cells`` / ``feed.nan_cells``; under a mesh plan also
     ``mesh.dispatches``, ``mesh.chunks`` (chunks packed into them),
-    ``mesh.rows`` and ``mesh.padded_rows`` (as the feed's two).
+    ``mesh.rows`` and ``mesh.padded_rows`` (as the feed's two); under the
+    gather walk ``forest.walk_levels`` (the levels each dispatch walks).
+    The model's weights ride every dispatch as the program's first
+    argument, the one device copy the program was built with.
     """
     from variantcalling_tpu.featurize import _bucket
     from variantcalling_tpu.parallel import shard_score
     from variantcalling_tpu.parallel.mesh import data_sharding
 
     first = inputs[0]
-    fn, layout, finalize = first.program
+    fn, layout, finalize, weights, walk_levels = first.program
     mesh = shard_score.mesh_for(plan)
     n_dev = plan.devices
     sharding = data_sharding(mesh, 2) if mesh is not None else None
@@ -759,11 +792,14 @@ def _dispatch_fused(inputs: list[_FusedInputs], plan) -> np.ndarray:
         obs.counter("feed.h2d_arrays").add(len(sent))
         obs.counter("feed.native_fills" if native else "feed.numpy_fills").add(1)
         obs.counter(f"score.dispatches.{first.strategy}").add(1)
+        if walk_levels:
+            obs.counter("forest.walk_levels").add(walk_levels)
         if mesh is not None:
             obs.counter("mesh.dispatches").add(1)
             obs.counter("mesh.rows").add(hi - lo)
             obs.counter("mesh.padded_rows").add(target)
-        call_args = (genome.rows, *sent) if layout.resident else sent
+        call_args = (weights, genome.rows, *sent) if layout.resident \
+            else (weights, *sent)
         # the enqueue; on a first call also trace + lower + cache load or compile
         with stage("dispatch_enqueue", rows=target, waited=False):
             res = _enqueue(fn, (target, shapes), call_args)
@@ -1597,6 +1633,7 @@ def _stream_chunks(args, model, fasta: FastaReader, annotate, blacklist, prof,
     # a cell that silently changed programs reads 0, not nothing
     for name in forest_mod.FOREST_STRATEGIES[1:] + ("jit",):
         obs.counter(f"score.dispatches.{name}").add(0)
+    obs.counter("forest.walk_levels").add(0)
     for name in ("dispatches", "chunks", "rows", "padded_rows"):
         obs.counter(f"mesh.{name}").add(0)
     for name in ("in_bytes", "in_blocks", "inflate_shards", "text_bytes_in",
@@ -2375,7 +2412,8 @@ def _run_impl(args) -> int:
             logger.error("%s", e)
             return 2
 
-        model = load_model(args.model_file, args.model_name)
+        with stage("model_load"):
+            model = load_model(args.model_file, args.model_name)
         fasta = FastaReader(args.reference_file)
         annotate = {_interval_name(p): bedio.read_intervals(p) for p in args.annotate_intervals}
         blacklist = read_blacklist(args.blacklist) if args.blacklist else None
